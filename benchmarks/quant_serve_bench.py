@@ -340,6 +340,9 @@ def _sharded_counters(preset) -> dict:
     tail = env.get("PYTHONPATH", "")
     env["PYTHONPATH"] = src + (os.pathsep + tail if tail else "")
     env["QS_BENCH_PRESET"] = json.dumps(preset)
+    # the child is a count gate on forced host devices; this process may
+    # already hold an accelerator, which a second process cannot open
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=900)
     for line in out.stdout.splitlines():

@@ -49,27 +49,52 @@ def make_importance_step(cfg: ModelConfig, ctx: QuantContext,
                          include_random_pass: bool = True,
                          remat: bool = True) -> Callable:
     """Returns jit-able step(params, opt_state, batch, rng) ->
-    (params, opt_state, metrics). One call = the paper's atomic operation."""
-    n = cfg.n_bits
+    (params, opt_state, metrics). One call = the paper's atomic operation.
 
-    def loss_of(params, batch, bits):
-        return lm.loss_fn(params, cfg, batch, bits, ctx, axes, remat=remat)[0]
+    Each pass differentiates only the leaves ``optimizer.trainable`` keeps
+    (the indicator banks under ``importance_optimizer(freeze_backbone=
+    True)``); the others get zero gradients. The optimizer would zero them
+    anyway, and never forming them keeps the n + 1 weight-sized gradient
+    trees out of device memory."""
+    n = cfg.n_bits
+    mask = optimizer.trainable
+
+    def trainable(params):
+        if mask is None:
+            return params
+        return jax.tree_util.tree_map_with_path(
+            lambda path, x: x if mask(path, x) else None, params)
+
+    def with_frozen(train, params):
+        return jax.tree.map(lambda t, p: p if t is None else t, train, params,
+                            is_leaf=lambda x: x is None)
+
+    def loss_of(train, params, batch, bits):
+        return lm.loss_fn(with_frozen(train, params), cfg, batch, bits, ctx,
+                          axes, remat=remat)[0]
 
     def step(params, opt_state, batch, rng):
-        grads_sum = None
-        losses = []
-        for k in range(n):                         # uniform-bit passes
-            l, g = jax.value_and_grad(loss_of)(params, batch,
+        train = trainable(params)
+
+        def uniform_pass(grads_sum, k):
+            l, g = jax.value_and_grad(loss_of)(train, params, batch,
                                                lm.bits_uniform(cfg, k))
-            losses.append(l)
-            grads_sum = g if grads_sum is None else \
-                jax.tree.map(jnp.add, grads_sum, g)
+            return jax.tree.map(jnp.add, grads_sum, g), l
+
+        # the n uniform-bit passes run as one scanned body: one pass's
+        # activations are live at a time, and the step compiles one pass
+        # instead of n
+        grads_sum, losses = jax.lax.scan(
+            uniform_pass, jax.tree.map(jnp.zeros_like, train), jnp.arange(n))
         if include_random_pass:                    # communication pass
             l_r, g = jax.value_and_grad(loss_of)(
-                params, batch, lm.bits_random(cfg, rng))
+                train, params, batch, lm.bits_random(cfg, rng))
             grads_sum = jax.tree.map(jnp.add, grads_sum, g)
         else:
             l_r = jnp.zeros(())
+        grads_sum = jax.tree.map(
+            lambda g, p: jnp.zeros_like(p) if g is None else g, grads_sum,
+            params, is_leaf=lambda x: x is None)
         # aggregate the n+1 gradients into one atomic update (§3.4):
         # backbone weights receive signal from every pass -> average over
         # all of them. A bank ENTRY is selected by its own uniform pass
@@ -89,7 +114,7 @@ def make_importance_step(cfg: ModelConfig, ctx: QuantContext,
 
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = optim.apply_updates(params, updates)
-        metrics = {"loss_uniform": jnp.stack(losses), "loss_random": l_r}
+        metrics = {"loss_uniform": losses, "loss_random": l_r}
         return params, opt_state, metrics
 
     return step

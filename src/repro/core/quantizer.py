@@ -30,8 +30,13 @@ def round_ste(x: Array) -> Array:
 
 
 def grad_scale(x: Array, scale) -> Array:
-    """Identity in value; gradient multiplied by `scale` (LSQ trick)."""
-    return x * scale + jax.lax.stop_gradient(x - x * scale)
+    """Identity in value; gradient multiplied by `scale` (LSQ trick).
+
+    The value is ``x`` plus an exact zero, so it is bitwise ``x`` whatever
+    ``scale`` rounds to: the same scale factor computed in two graphs (a
+    constant-folded one and a runtime one) yields one quantization grid."""
+    xs = x * scale
+    return jax.lax.stop_gradient(x) + (xs - jax.lax.stop_gradient(xs))
 
 
 def bit_range(b, signed: bool):

@@ -25,7 +25,6 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 Tree = Any
@@ -97,9 +96,9 @@ def psum_matmul(x: jax.Array, w: jax.Array, mesh, axis: str) -> jax.Array:
     def body(xl, wl):
         return _ring_allreduce(xl @ wl, axis, n)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(None, axis), P(axis, None)),
-                     out_specs=P(None, None), check_rep=False)(x, w)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(None, axis), P(axis, None)),
+                         out_specs=P(None, None), check_vma=False)(x, w)
 
 
 def ag_matmul_rotating(x: jax.Array, w: jax.Array, mesh, axis: str) -> jax.Array:
@@ -131,7 +130,7 @@ def ag_matmul_rotating(x: jax.Array, w: jax.Array, mesh, axis: str) -> jax.Array
                 xl = jax.lax.ppermute(xl, axis, perm)
         return out
 
-    out = shard_map(body, mesh=mesh,
-                    in_specs=(P(None, axis), P(None, axis)),
-                    out_specs=P(None, axis), check_rep=False)(x, w)
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=(P(None, axis), P(None, axis)),
+                        out_specs=P(None, axis), check_vma=False)(x, w)
     return out.astype(x.dtype)

@@ -23,16 +23,40 @@ from repro.configs.base import ModelConfig, ShapeSpec
 
 @dataclasses.dataclass(frozen=True)
 class ChipSpec:
-    """Per-chip hardware envelope (defaults approximate a TPU v5e)."""
+    """Per-chip hardware envelope. The defaults are one TPU v5e's published
+    peaks (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s bf16, 16 GB
+    of HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect. The DCN
+    share is a modeling assumption, not a published number."""
     name: str = "tpu-v5e"
     peak_flops: float = 197e12        # bf16 FLOP/s
     hbm_bytes_s: float = 819e9        # HBM bandwidth
-    ici_bytes_s: float = 180e9        # ICI bandwidth (all links)
+    ici_bytes_s: float = 1600e9 / 8   # ICI bandwidth (all links)
     dcn_bytes_s: float = 25e9         # cross-pod DCN, per chip share
-    hbm_bytes: float = 16 * 2**30
+    hbm_bytes: float = 16e9
 
 
-DEFAULT_CHIP = ChipSpec()
+# Envelopes of the chips this code has a source for, keyed by
+# ``jax.Device.device_kind``.
+CHIPS = {"TPU v5 lite": ChipSpec()}
+
+# The chip the dry-run and the CPU-side planners model.
+DEFAULT_CHIP = CHIPS["TPU v5 lite"]
+
+
+def local_chip() -> ChipSpec:
+    """The envelope of the chip this process runs on.
+
+    On a TPU backend the device kind must be in ``CHIPS``: an unknown TPU
+    raises instead of being budgeted as a v5e. Off-TPU there is no device
+    envelope, and planning models the v5e target (``DEFAULT_CHIP``)."""
+    import jax
+    if jax.default_backend() != "tpu":
+        return DEFAULT_CHIP
+    kind = jax.devices()[0].device_kind
+    if kind not in CHIPS:
+        raise ValueError(f"no ChipSpec for TPU device kind {kind!r}; "
+                         f"known: {sorted(CHIPS)}")
+    return CHIPS[kind]
 
 
 def chip_from_table(table: dict, base: ChipSpec = DEFAULT_CHIP) -> ChipSpec:

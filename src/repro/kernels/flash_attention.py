@@ -1,4 +1,5 @@
-"""Flash-attention forward Pallas kernel (TPU target, interpret-validated).
+"""Flash-attention forward Pallas kernel (TPU target; interpret-validated, and
+compiled for a v5e in tests/test_tpu_compile.py).
 
 The §Perf analysis (EXPERIMENTS.md) shows the optimized attention cells are
 bound by per-block probability tiles streaming through HBM — an artifact of
@@ -22,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 DEFAULT_BLOCKS = (512, 512)      # q_block, kv_block
@@ -57,19 +56,21 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
     logits = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) + bias
 
+    # softmax state keeps a trailing unit dim ((qb, 1)) so every value
+    # stays 2-D on the TPU's (sublane, lane) tiles
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, logits.max(axis=-1))
+    m_new = jnp.maximum(m_prev, logits.max(axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(logits - m_new[:, None])          # (qb, kvb) — VMEM only
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+    p = jnp.exp(logits - m_new)                   # (qb, kvb) — VMEM only
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(j == n_kv - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
         lse_ref[0] = (m_ref[...] + jnp.log(l)).astype(lse_ref.dtype)
 
 
@@ -97,18 +98,20 @@ def flash_fwd_pallas(q, k, v, *, causal: bool, window=None,
         ],
         out_specs=[
             pl.BlockSpec((1, q_block, hd), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, q_block), lambda b, i, j: (b, i)),
+            # lse as a (rows, S, 1) column: the block's last two dims are
+            # (q_block, full) — tileable, unlike a (1, q_block) row
+            pl.BlockSpec((1, q_block, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * KV * G, S, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B * KV * G, S), jnp.float32),
+            jax.ShapeDtypeStruct((B * KV * G, S, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((q_block,), jnp.float32),
-            pltpu.VMEM((q_block,), jnp.float32),
+            pltpu.VMEM((q_block, 1), jnp.float32),
+            pltpu.VMEM((q_block, 1), jnp.float32),
             pltpu.VMEM((q_block, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

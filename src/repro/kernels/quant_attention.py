@@ -1,4 +1,5 @@
-"""Fused int8 decode-attention Pallas kernel (TPU target, interpret-validated).
+"""Fused int8 decode-attention Pallas kernel (TPU target; interpret-validated,
+and compiled for a v5e in tests/test_tpu_compile.py).
 
 The serving engine's int8 KV cache stores codes + per-row per-head f32
 scales, but until this kernel the decode step dequantized the *whole* ring
@@ -43,14 +44,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
 DEFAULT_KV_BLOCK = 256
 
 
-def _qdec_kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref, qp_ref,
-                 o_ref, m_ref, l_ref, acc_ref, *, n_kv, window):
+def _qdec_kernel(qp_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref,
+                 o_ref, m_ref, l_ref, acc_ref, *, n_kv, kv_heads, window):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -61,37 +60,53 @@ def _qdec_kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref, qp_ref,
 
     q = q_ref[0]                                   # (G, hd) f32, pre-scaled
     kc = k_ref[0].astype(jnp.float32)              # (kvb, hd) from int8 codes
-    ks = ks_ref[0]                                 # (kvb,) f32 row scales
-    kpos = pos_ref[0]                              # (kvb,) int32 abs position
-    qp = qp_ref[0, 0]                              # scalar int32 query pos
+    ks = ks_ref[0]                                 # (1, kvb) f32 row scales
+    kpos = pos_ref[0]                              # (1, kvb) int32 abs position
+    qp = qp_ref[pl.program_id(0) // kv_heads]      # scalar int32 query pos
 
     # contraction on the CODES; the K-scale folds into the logit columns in
     # VMEM — a zero row (codes 0, eps-floored scale) lands at exactly 0.0
     logits = jax.lax.dot_general(q, kc, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    logits = logits * ks[None, :]
+    logits = logits * ks
     valid = (kpos >= 0) & (kpos <= qp)
     if window is not None:
         valid &= qp - kpos < window
-    logits = logits + jnp.where(valid, 0.0, NEG_INF)[None, :]
+    logits = logits + jnp.where(valid, 0.0, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, logits.max(axis=-1))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(logits - m_new[:, None])           # (G, kvb)
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-    # V-scale folds into the probability row; the second dot runs on codes
-    pv = jax.lax.dot_general(p * vs_ref[0][None, :],
-                             v_ref[0].astype(jnp.float32),
-                             (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
-    m_ref[...] = m_new
+    _online_softmax_step(logits, vs_ref[0], v_ref[0], m_ref, l_ref, acc_ref)
 
     @pl.when(j == n_kv - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        _write_out(o_ref, l_ref, acc_ref)
+
+
+def _online_softmax_step(logits, v_scale, v_codes, m_ref, l_ref, acc_ref):
+    """Fold one (G, kvb) logit block into the (m, l, acc) VMEM state. The
+    state keeps a trailing unit dim ((G, 1)) so every value stays 2-D on
+    the TPU's (sublane, lane) tiles."""
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, logits.max(axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(logits - m_new)                    # (G, kvb)
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+    # V-scale folds into the probability row; the second dot runs on codes
+    pv = jax.lax.dot_general(p * v_scale, v_codes.astype(jnp.float32),
+                             (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * alpha + pv
+    m_ref[...] = m_new
+
+
+def _write_out(o_ref, l_ref, acc_ref):
+    l = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _softmax_scratch(G: int, hd: int):
+    return [pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, hd), jnp.float32)]
 
 
 def decode_attn_quant(q, k_codes, k_scale, v_codes, v_scale, pos_arr, q_pos,
@@ -121,10 +136,12 @@ def decode_attn_quant(q, k_codes, k_scale, v_codes, v_scale, pos_arr, q_pos,
     qf = qf.reshape(B * KV, G, hd)
     kf = k_codes.transpose(0, 2, 1, 3).reshape(B * KV, Sc, hd)
     vf = v_codes.transpose(0, 2, 1, 3).reshape(B * KV, Sc, hd)
-    ks = k_scale.transpose(0, 2, 1).reshape(B * KV, Sc).astype(jnp.float32)
-    vs = v_scale.transpose(0, 2, 1).reshape(B * KV, Sc).astype(jnp.float32)
-    pos2 = jnp.asarray(pos_arr, jnp.int32)
-    qp = jnp.asarray(q_pos, jnp.int32).reshape(B, 1)
+    # scales and positions ride as (rows, 1, Sc): a (1, kvb) block over the
+    # last two dims is then (full, lane-aligned), which Mosaic tiles
+    ks = k_scale.transpose(0, 2, 1).reshape(B * KV, 1, Sc).astype(jnp.float32)
+    vs = v_scale.transpose(0, 2, 1).reshape(B * KV, 1, Sc).astype(jnp.float32)
+    pos3 = jnp.asarray(pos_arr, jnp.int32).reshape(B, 1, Sc)
+    qp = jnp.asarray(q_pos, jnp.int32).reshape(B)
 
     kvb = min(kv_block, Sc)
     pad = (-Sc) % kvb
@@ -132,34 +149,34 @@ def decode_attn_quant(q, k_codes, k_scale, v_codes, v_scale, pos_arr, q_pos,
         # padded slots carry pos -1: masked exactly like evicted slots
         kf = jnp.pad(kf, ((0, 0), (0, pad), (0, 0)))
         vf = jnp.pad(vf, ((0, 0), (0, pad), (0, 0)))
-        ks = jnp.pad(ks, ((0, 0), (0, pad)))
-        vs = jnp.pad(vs, ((0, 0), (0, pad)))
-        pos2 = jnp.pad(pos2, ((0, 0), (0, pad)), constant_values=-1)
+        ks = jnp.pad(ks, ((0, 0), (0, 0), (0, pad)))
+        vs = jnp.pad(vs, ((0, 0), (0, 0), (0, pad)))
+        pos3 = jnp.pad(pos3, ((0, 0), (0, 0), (0, pad)), constant_values=-1)
     n_kv = (Sc + pad) // kvb
 
-    out = pl.pallas_call(
-        functools.partial(_qdec_kernel, n_kv=n_kv, window=window),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B * KV, n_kv),
         in_specs=[
-            pl.BlockSpec((1, G, hd), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, kvb, hd), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, kvb), lambda b, j: (b, j)),
-            pl.BlockSpec((1, kvb, hd), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, kvb), lambda b, j: (b, j)),
-            pl.BlockSpec((1, kvb), lambda b, j, KV=KV: (b // KV, j)),
-            pl.BlockSpec((1, 1), lambda b, j, KV=KV: (b // KV, 0)),
+            pl.BlockSpec((1, G, hd), lambda b, j, qp: (b, 0, 0)),
+            pl.BlockSpec((1, kvb, hd), lambda b, j, qp: (b, j, 0)),
+            pl.BlockSpec((1, 1, kvb), lambda b, j, qp: (b, 0, j)),
+            pl.BlockSpec((1, kvb, hd), lambda b, j, qp: (b, j, 0)),
+            pl.BlockSpec((1, 1, kvb), lambda b, j, qp: (b, 0, j)),
+            pl.BlockSpec((1, 1, kvb), lambda b, j, qp: (b // KV, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, G, hd), lambda b, j: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, G, hd), lambda b, j, qp: (b, 0, 0)),
+        scratch_shapes=_softmax_scratch(G, hd),
+    )
+    out = pl.pallas_call(
+        functools.partial(_qdec_kernel, n_kv=n_kv, kv_heads=KV,
+                          window=window),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, G, hd), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(qf, kf, ks, vf, vs, pos2, qp)
+    )(qp, qf, kf, ks, vf, vs, pos3)
 
     return out.reshape(B, KV, G, hd).reshape(B, 1, H, hd)
 
@@ -182,36 +199,26 @@ def _qdec_paged_kernel(tbl_ref, qp_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
 
     q = q_ref[0]                                   # (G, hd) f32, pre-scaled
     kc = k_ref[0, 0].astype(jnp.float32)           # (ps, hd) from int8 codes
-    ks = ks_ref[0, 0]                              # (ps,) f32 row scales
-    kpos = pos_ref[0]                              # (ps,) int32 abs position
+    ks = ks_ref[0, 0]                              # (1, ps) f32 row scales
+    kpos = pos_ref[0]                              # (1, ps) int32 abs position
     qp = qp_ref[b]                                 # scalar int32 query pos
 
     logits = jax.lax.dot_general(q, kc, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    logits = logits * ks[None, :]
+    logits = logits * ks
     # an unmapped table entry (-1) aliased to physical page 0 by the index
     # map's clip — mask the whole block so it contributes exact zeros
     valid = (tbl_ref[b, j] >= 0) & (kpos >= 0) & (kpos <= qp)
     if window is not None:
         valid &= qp - kpos < window
-    logits = logits + jnp.where(valid, 0.0, NEG_INF)[None, :]
+    logits = logits + jnp.where(valid, 0.0, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, logits.max(axis=-1))
-    alpha = jnp.exp(m_prev - m_new)
-    p_blk = jnp.exp(logits - m_new[:, None])       # (G, ps)
-    l_ref[...] = l_ref[...] * alpha + p_blk.sum(axis=-1)
-    pv = jax.lax.dot_general(p_blk * vs_ref[0, 0][None, :],
-                             v_ref[0, 0].astype(jnp.float32),
-                             (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
-    m_ref[...] = m_new
+    _online_softmax_step(logits, vs_ref[0, 0], v_ref[0, 0], m_ref, l_ref,
+                         acc_ref)
 
     @pl.when(j == n_blocks - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        _write_out(o_ref, l_ref, acc_ref)
 
 
 def decode_attn_quant_paged(q, k_pages, k_scale, v_pages, v_scale, page_pos,
@@ -243,11 +250,15 @@ def decode_attn_quant_paged(q, k_pages, k_scale, v_pages, v_scale, page_pos,
     qf = qf.reshape(B * KV, G, hd)
     kf = k_pages.transpose(0, 2, 1, 3)             # (n_pages, KV, ps, hd)
     vf = v_pages.transpose(0, 2, 1, 3)
-    ks = k_scale.transpose(0, 2, 1).astype(jnp.float32)   # (n_pages, KV, ps)
-    vs = v_scale.transpose(0, 2, 1).astype(jnp.float32)
+    # scales (n_pages, KV, 1, ps) and positions (n_pages, 1, ps): each
+    # block's last two dims are then the full (1, ps) row
+    ks = k_scale.transpose(0, 2, 1).reshape(n_pages, KV, 1, ps).astype(
+        jnp.float32)
+    vs = v_scale.transpose(0, 2, 1).reshape(n_pages, KV, 1, ps).astype(
+        jnp.float32)
     tbl = jnp.asarray(page_table, jnp.int32)
     qp = jnp.asarray(q_pos, jnp.int32)
-    pos = jnp.asarray(page_pos, jnp.int32)
+    pos = jnp.asarray(page_pos, jnp.int32).reshape(n_pages, 1, ps)
 
     def page_of(p, j, tbl_ref):
         # clip unmapped (-1) to physical page 0; the kernel masks the block
@@ -261,31 +272,27 @@ def decode_attn_quant_paged(q, k_pages, k_scale, v_pages, v_scale, page_pos,
             pl.BlockSpec((1, 1, ps, hd),
                          lambda p, j, tbl, qp: (page_of(p, j, tbl),
                                                 p % KV, 0, 0)),
-            pl.BlockSpec((1, 1, ps),
+            pl.BlockSpec((1, 1, 1, ps),
                          lambda p, j, tbl, qp: (page_of(p, j, tbl),
-                                                p % KV, 0)),
+                                                p % KV, 0, 0)),
             pl.BlockSpec((1, 1, ps, hd),
                          lambda p, j, tbl, qp: (page_of(p, j, tbl),
                                                 p % KV, 0, 0)),
-            pl.BlockSpec((1, 1, ps),
+            pl.BlockSpec((1, 1, 1, ps),
                          lambda p, j, tbl, qp: (page_of(p, j, tbl),
-                                                p % KV, 0)),
-            pl.BlockSpec((1, ps),
-                         lambda p, j, tbl, qp: (page_of(p, j, tbl), 0)),
+                                                p % KV, 0, 0)),
+            pl.BlockSpec((1, 1, ps),
+                         lambda p, j, tbl, qp: (page_of(p, j, tbl), 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, G, hd), lambda p, j, tbl, qp: (p, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
-        ],
+        scratch_shapes=_softmax_scratch(G, hd),
     )
     out = pl.pallas_call(
         functools.partial(_qdec_paged_kernel, n_blocks=P, kv_heads=KV,
                           window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, G, hd), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tbl, qp, qf, kf, ks, vf, vs, pos)

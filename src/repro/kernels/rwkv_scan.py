@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 DEFAULT_CHUNK = 32
 
 
@@ -97,7 +95,7 @@ def wkv_pallas(r, k, v, log_w, u, chunk: int = DEFAULT_CHUNK,
         out_specs=pl.BlockSpec((1, chunk, hd), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rb, kb, vb, lwb, ub)

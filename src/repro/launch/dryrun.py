@@ -1,7 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # ^ MUST precede every other import (jax locks device count on first init).
+# The dry-run compiles against 512 placeholder CPU devices; pinning the CPU
+# keeps it off an attached accelerator, which one process at a time may hold.
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this driver:
@@ -100,7 +103,10 @@ def build_cell(cfg, shape, mesh, *, step_kind: str, zero_shard: bool = True,
     if step_kind == "importance":
         opt = importance_mod.importance_optimizer(0.01, freeze_backbone=True)
         opt_shape = jax.eval_shape(opt.init, params_shape)
-        ospecs = type(opt_shape)(P(), pspecs)
+        # momentum exists for the trainable indicator banks only
+        ospecs = type(opt_shape)(P(), jax.tree_util.tree_map_with_path(
+            lambda path, s: s if optim.indicator_only_mask(path, s) else None,
+            pspecs, is_leaf=lambda x: isinstance(x, P)))
         istep = importance_mod.make_importance_step(cfg, ctx, opt, axes,
                                                     remat=remat)
         rng_spec = jax.ShapeDtypeStruct((2,), jnp.uint32)
@@ -190,8 +196,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *,
             t_compile = time.time()
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):     # jax<=0.4.x returns [dict]
-            cost = cost[0]
         txt = compiled.as_text()
         costs = hlo_mod.analyze(txt)
         rep = roofline.report(arch, shape, mesh_label, n_chips, costs, cfg)

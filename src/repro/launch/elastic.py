@@ -229,7 +229,7 @@ class ElasticController:
             pid: roofline.decode_step_cost(
                 cfg, slots, cache_tokens=cache_len, kv_bits=kv_bits,
                 kv_attend=kv_attend, w_bits_total=bank.size_bits[pid],
-                chip=chip or roofline.DEFAULT_CHIP)["step_s"]
+                chip=chip or roofline.local_chip())["step_s"]
             for pid in self.order}
         self.solves = 0
         self.max_solve_ms = 0.0

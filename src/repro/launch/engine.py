@@ -111,7 +111,7 @@ class EngineConfig:
     eos_id: Optional[int] = None  # optional early-stop token id
     state_dtype: Any = jnp.float32
     max_iters: int = 100_000  # hard stop for the host loop
-    chip: roofline.ChipSpec = roofline.DEFAULT_CHIP
+    chip: Optional[roofline.ChipSpec] = None  # None = roofline.local_chip()
     kv_quant: str = "none"  # "none" | "int8" | "fake" (reference numerics)
     kv_layout: str = "ring"  # "ring" | "paged" (pooled pages + prefix reuse)
     page_size: int = 8  # tokens per KV page (paged layout only)
@@ -120,6 +120,9 @@ class EngineConfig:
     trace: bool = True  # record the per-request lifecycle event trace
     health_every: int = 4  # KV-scale drift sample stride (decode steps; 0 off)
     speculate: int = 0  # self-speculative draft length k (0 = off)
+    # keep every request's per-token logits on the host (Completion.logits):
+    # the serving checks hold this engine's own logits to a reference
+    record_logits: bool = False
 
 
 @dataclasses.dataclass
@@ -263,6 +266,7 @@ class _Slot:
         "spec_drafted",
         "spec_accepted",
         "policy_id",
+        "logits",
     )
 
     def __init__(
@@ -288,6 +292,7 @@ class _Slot:
         # serving it to completion (drain-then-swap), so one id covers
         # every token
         self.policy_id = policy_id
+        self.logits: Optional[List[np.ndarray]] = None  # record_logits rows
 
 
 class DecodeEngine:
@@ -310,6 +315,8 @@ class DecodeEngine:
         self.params = params
         self.cfg = cfg
         self.ecfg = ecfg or EngineConfig()
+        if self.ecfg.chip is None:
+            self.ecfg = dataclasses.replace(self.ecfg, chip=roofline.local_chip())
         if adapter is None:
             if self.ecfg.kv_quant != "none" and ctx.kv_quant == "none":
                 ctx = dataclasses.replace(ctx, kv_quant=self.ecfg.kv_quant)
@@ -388,6 +395,11 @@ class DecodeEngine:
                     "speculate > 0 does not support sliding-window archs: "
                     "the ring window overwrites rows a rollback would need"
                 )
+            if self.ecfg.record_logits:
+                raise ValueError(
+                    "record_logits is token-at-a-time only: a speculative "
+                    "round scores its draft tokens in one verify pass"
+                )
         # elastic serving: an ElasticController re-solves the ILP at
         # admission time and this engine hot-swaps the active pre-packed
         # variant between batches (drain-then-swap; _elastic_admission)
@@ -427,9 +439,8 @@ class DecodeEngine:
         # jitted decode resolves the same dispatch at trace time, so a
         # force_decode_attn scope must wrap build AND first run)
         if kv_mode == "int8":
-            from repro.runtime import dispatch as _dispatch
-
-            self.decode_attn_route = _dispatch.resolve_decode_attn()
+            with _dispatch.axes_scope(axes):
+                self.decode_attn_route = _dispatch.resolve_decode_attn()
         else:
             self.decode_attn_route = "fp"
         kv_attend = (
@@ -957,6 +968,9 @@ class DecodeEngine:
         slot = self.slots[idx]
         assert slot is not None
         rid = slot.req.rid
+        logits = slot.logits
+        if logits is not None:
+            logits = np.stack(logits[: slot.req.max_new])
         self.completions[rid] = Completion(
             rid=rid,
             prompt_len=slot.req.prompt_len,
@@ -966,6 +980,7 @@ class DecodeEngine:
             spec_drafted=slot.spec_drafted,
             spec_accepted=slot.spec_accepted,
             policy_id=slot.policy_id,
+            logits=logits,
         )
         m = self.metrics
         m.counter("engine.completed").inc()
@@ -1030,8 +1045,10 @@ class DecodeEngine:
         # one suffix token must run to produce the first token's logits
         shared = list(pool.lookup_prefix(chain[: (plen - 1) // ps]))
         hit_tokens = len(shared) * ps
+        # this slot's reference on the donor's pages, taken BEFORE the
+        # allocation: it may evict LRU prefixes, the one just hit included
+        pool.ref(shared)
         fresh, freed = pool.alloc_with_freed(self._pages_per_slot - len(shared))
-        pool.ref(shared)  # this slot's reference on the donor's pages
         self._clear_freed(freed)
         table_row = shared + fresh
         ts_admit = (
@@ -1091,6 +1108,8 @@ class DecodeEngine:
         self.slots[idx] = _Slot(
             req, first, now, ts_admit, ts_admit + dt, self._active_policy
         )
+        if self.ecfg.record_logits:
+            self.slots[idx].logits = [np.asarray(logits[0], np.float32)]
         m.gauge("engine.slot_occupancy").set(len(self._occupied()))
         if self.trace is not None:
             stamp = (
@@ -1190,6 +1209,8 @@ class DecodeEngine:
         self.slots[idx] = _Slot(
             req, first, now, ts_admit, ts_admit + dt, self._active_policy
         )
+        if self.ecfg.record_logits:
+            self.slots[idx].logits = [np.asarray(logits[0], np.float32)]
         m.gauge("engine.slot_occupancy").set(len(self._occupied()))
         if self.trace is not None:
             stamp = (
@@ -1265,8 +1286,11 @@ class DecodeEngine:
                 "decode_step", ts1 - dt, ts1, slots=len(live), iteration=now
             )
         itl = m.histogram("engine.itl_ms")
+        rows = np.asarray(logits, np.float32) if self.ecfg.record_logits else None
         for i in live:
             s = self.slots[i]
+            if rows is not None:
+                s.logits.append(rows[i])
             s.gen.append(int(nxt[i]))
             s.next_tok = int(nxt[i])
             s.next_pos += 1

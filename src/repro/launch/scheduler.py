@@ -103,6 +103,9 @@ class Completion:
     # policy). Drain-then-swap means a single variant per request — the
     # attribution key for per-variant reference checks
     policy_id: str = ""
+    # (len(tokens), vocab) f32 logits each token was taken from, when the
+    # engine runs with EngineConfig.record_logits (else None)
+    logits: Optional[Any] = None
 
 
 class Scheduler:

@@ -12,10 +12,15 @@ generated tokens are identical, and reports the decode steps saved.
 the policy compiles into a ``repro.runtime.session.QuantizedSession``
 (weights quantized onto the searched per-layer grids, sub-8-bit codes
 bit-packed, int8 KV-cache slots, prompt-length bucketing) and serves
-through the same engine. With ``--smoke`` that path is gated hard: greedy
-tokens must be identical to a reference engine running the fake-quant
-training graph, and measured packed HBM bytes must land within 5% of
-``MPQPolicy.size_bytes``.
+through the same engine. With ``--check`` (implied by ``--smoke``, which
+also shrinks the model) that path is gated hard: greedy tokens must be
+identical to a reference engine running the fake-quant training graph, and
+measured packed HBM bytes must land within 5% of ``MPQPolicy.size_bytes``.
+Where the Pallas routes ran (a TPU), each kernel is first held to its
+``dequant-fp`` route op by op (``runtime.parity``), the token gate serves
+the session again with every route forced to ``dequant-fp``, and the
+logits the measured engine recorded are held within ``DRIFT_BOUND`` of an
+f32 reference traced at matmul precision ``highest``.
 
 ``--mesh <name>`` serves under a real device mesh (``host`` = trivial
 (1,); ``host8`` = 2-way data x 4-way tensor parallel over 8 forced host
@@ -69,6 +74,7 @@ from repro.configs import get_config, smoke_config
 from repro.core.policy import MPQPolicy
 from repro.data import SyntheticLM
 from repro.dist.axes import NO_AXES
+from repro.launch import compile_cache
 from repro.launch.engine import DecodeEngine, EngineConfig
 from repro.launch.scheduler import POLICIES, Request
 from repro.models import lm
@@ -261,7 +267,8 @@ class ServeConfig:
                       schedule: Optional[str] = None,
                       layout: Optional[str] = None,
                       calibrated: bool = True,
-                      speculate: int = 0) -> EngineConfig:
+                      speculate: int = 0,
+                      record_logits: bool = False) -> EngineConfig:
         """An ``EngineConfig`` for one engine of this serving run.
 
         ``kv_quant`` defaults to the packed session's storage mode; a
@@ -272,7 +279,9 @@ class ServeConfig:
         stock envelope, so the smoke's token-identity gate doubles as the
         calibrated-vs-default agreement check. ``speculate`` is opt-in per
         engine (default 0): only the measured spec engine drafts — the
-        reference engines it gates against must stay token-at-a-time."""
+        reference engines it gates against must stay token-at-a-time.
+        ``record_logits`` keeps each request's logits for the kernel-route
+        drift check."""
         kv = self.session_kv if kv_quant is None else kv_quant
         lay = self.kv_layout if layout is None else layout
         if kv != "int8":
@@ -281,7 +290,7 @@ class ServeConfig:
             slots=self.slots, cache_len=self.resolved_cache_len,
             policy=schedule or self.schedule, kv_quant=kv, kv_layout=lay,
             page_size=self.page_size, bucket_prompts=self.bucket,
-            speculate=speculate)
+            speculate=speculate, record_logits=record_logits)
         if calibrated and self.chip is not None:
             ecfg = dataclasses.replace(ecfg, chip=self.chip)
         return ecfg
@@ -628,7 +637,7 @@ def serve_elastic(args, scfg: ServeConfig, cfg, params, ctx, reqs):
     for pid in sorted(per_variant):
         print(f"  {pid}: {len(per_variant[pid])} request(s) "
               f"{sorted(per_variant[pid])}")
-    if args.smoke:
+    if args.check:
         check_trace(eng, "elastic")
         if st.policy_swaps_down < 1:
             raise SystemExit(
@@ -691,7 +700,10 @@ def serve_quantized(args, scfg: ServeConfig, cfg, params, ctx, reqs,
         sess = QuantizedSession(cfg, params, policy, ctx, axes, mode="packed",
                                 kv_quant=kv)
     eng = DecodeEngine(sess.params, cfg, None, ctx, axes,
-                       scfg.engine_config(speculate=scfg.speculate),
+                       scfg.engine_config(speculate=scfg.speculate,
+                                          record_logits=bool(
+                                              args.check
+                                              and not scfg.speculate)),
                        adapter=sess)
     streamer = attach_stream(args, eng)
     eng.submit_all(reqs)
@@ -701,7 +713,7 @@ def serve_quantized(args, scfg: ServeConfig, cfg, params, ctx, reqs,
     # session- not engine-scoped, keeps its own line
     print_stats(f"quantized/{args.schedule}", eng)
     export_obs(args, eng)
-    if args.smoke:
+    if args.check:
         check_trace(eng, "quantized")
         calibration_report(eng, cfg, gate=True)
     # close AFTER the calibration gauge lands, so the final snapshot and
@@ -714,6 +726,10 @@ def serve_quantized(args, scfg: ServeConfig, cfg, params, ctx, reqs,
           f"{s['compression_vs_fp32']:.2f}x smaller than fp32 | "
           f"kv={s['kv_quant']} layout={eng.ecfg.kv_layout} "
           f"decode-attn={eng.decode_attn_route}")
+    print("routes (per trace): " + " ".join(
+        f"{k[len('dispatch.'):]}={v:g}"
+        for k, v in route_counts(eng.metrics).items()))
+    scored = completions
     if scfg.speculate:
         es = eng.stats
         print(f"speculate k={scfg.speculate} draft_bits={scfg.draft_bits}: "
@@ -721,15 +737,18 @@ def serve_quantized(args, scfg: ServeConfig, cfg, params, ctx, reqs,
               f"accepted {es.spec_accepted_tokens} "
               f"(accept rate {es.spec_accept_rate:.2f}) | draft pack "
               f"{sess.draft_bytes()} B on top of {s['packed_bytes']} B")
-        if args.smoke:
+        if args.check:
             # the speculative gate proper: the SAME packed session through
             # a token-at-a-time engine — speculation must change nothing
             # but the step count (greedy acceptance is exact by
             # construction; this catches rollback/verify divergence)
             ns = DecodeEngine(sess.params, cfg, None, ctx, axes,
-                              scfg.engine_config(), adapter=sess)
+                              scfg.engine_config(record_logits=True),
+                              adapter=sess)
             ns.submit_all(reqs)
             ns_out = ns.run()
+            # the kernel-route drift check reads this engine's logits
+            scored = ns_out
             bad = [r.rid for r in completions.values()
                    if ns_out[r.rid].tokens != r.tokens]
             if bad:
@@ -757,13 +776,13 @@ def serve_quantized(args, scfg: ServeConfig, cfg, params, ctx, reqs,
               f"{axes.tp_size} tp shards vs per-chip plan budget "
               f"{budget:.0f} B (all-shardable ideal: size_bytes/tp = "
               f"{ideal:.0f} B)")
-        if args.smoke and s["per_shard_bytes"] > budget * 1.05:
+        if args.check and s["per_shard_bytes"] > budget * 1.05:
             raise SystemExit(
                 f"per-shard packed bytes {s['per_shard_bytes']} exceed the "
                 f"per-chip plan budget {budget:.0f} by more than padding "
                 "(5%) — codes are replicating where the shard plan says "
                 "they shard")
-        if args.smoke:
+        if args.check:
             # device truth, not pack-time metadata: every codes leaf the
             # plan shards must actually BE sharded on the engine's placed
             # params (catches spec-tree / placement regressions that the
@@ -780,10 +799,43 @@ def serve_quantized(args, scfg: ServeConfig, cfg, params, ctx, reqs,
                   f"leaf replicates ({len(packing.packed_leaves(eng.params))}"
                   " packed leaves)")
 
-    if args.smoke or args.compare:
+    if args.check or args.compare:
         # reference: the fake-quant training graph (scanned body) through
         # the same engine; int8 slots reference as quantize-dequantize fp
         bits = lm.bits_from_policy(cfg, policy)
+        kernels = kernel_routes(eng.metrics)
+        if args.check and (kernels or eng.decode_attn_route != "dequant-fp"):
+            # op by op first: cheap, and free of the cascade that blurs the
+            # end-to-end drift below
+            from repro.runtime import parity
+            ops = parity.kernel_parity(
+                sess, ctx, layout=eng.ecfg.kv_layout, slots=scfg.slots,
+                cap=scfg.resolved_cache_len, page_size=scfg.page_size,
+                verify_len=scfg.speculate + 1 if scfg.speculate else 0)
+            print("kernel routes vs dequant-fp, op by op (max |d| / max |ref|"
+                  "): " + " ".join(f"{k}={v:.3g}" for k, v in ops.items()))
+            bad = {k: v for k, v in ops.items()
+                   if not v <= parity.PARITY_BOUND}
+            if bad:
+                raise SystemExit(
+                    f"kernel routes disagree with dequant-fp beyond "
+                    f"{parity.PARITY_BOUND}: {bad}")
+            print(f"{len(ops)} kernel ops within {parity.PARITY_BOUND} of "
+                  "dequant-fp")
+        fp_out = completions
+        if kernels or tie_bound() > 0:
+            # the measured run took the int32 MXU matmul kernels, which are
+            # exact but not bitwise equal to an fp einsum, or it ran where
+            # fp graphs round apart: the identity gate serves the same
+            # session, on the same layout, again with every route forced to
+            # dequant-fp, and the measured run is held to a logit bound
+            with dispatch.force_impl("dequant-fp"), \
+                    dispatch.force_decode_attn("dequant-fp"):
+                fp_eng = DecodeEngine(sess.params, cfg, None, ctx, axes,
+                                      scfg.engine_config(record_logits=True),
+                                      adapter=sess)
+                fp_eng.submit_all(reqs)
+                fp_out = fp_eng.run()
         # calibrated=False: the reference budgets with the default chip,
         # so this token gate is ALSO the calibrated-vs-default agreement
         # check when a --chip-table is loaded
@@ -792,33 +844,201 @@ def serve_quantized(args, scfg: ServeConfig, cfg, params, ctx, reqs,
         ref = DecodeEngine(params, cfg, bits, ctx, NO_AXES, ref_ecfg)
         ref.submit_all(reqs)
         ref_out = ref.run()
-        mismatch = [r.rid for r in completions.values()
+        from repro.launch.engine import LMAdapter
+        scorer = RefScorer(LMAdapter(cfg, bits, dataclasses.replace(
+            ctx, kv_quant="fake" if kv == "int8" else "none")), params,
+            scfg.resolved_cache_len)
+        mismatch = [r.rid for r in fp_out.values()
                     if ref_out[r.rid].tokens != r.tokens]
-        if mismatch:
+        if mismatch and not tie_bound():
             raise SystemExit("packed runtime diverged from the fake-quant "
                              f"reference graph: rids {mismatch}")
-        print("greedy tokens identical with the fake-quant reference graph "
-              f"({len(completions)} requests)")
+        if mismatch:
+            # the reference, stepped along the dequant-fp tokens, must rank
+            # each of them first or within the tie bound of its first
+            tf = scorer.drift(reqs, fp_out)
+            print(f"dequant-fp tokens differ from the reference engine's on "
+                  f"rids {mismatch}; reference teacher-forced along them: "
+                  f"{tf['ties']} near-tie step(s), largest margin "
+                  f"{tf['margin']:.3g} of the reference logit range "
+                  f"{tf['scale']:.4g} (tie bound {tie_bound()}) | max "
+                  f"|dlogit| {tf['max']:.4g} = {tf['ratio']:.3g} of the "
+                  "range")
+            if not (tf["margin"] <= tie_bound()
+                    and tf["ratio"] <= DRIFT_BOUND):
+                raise SystemExit(
+                    "packed runtime diverged from the fake-quant reference "
+                    f"graph beyond a near-tie: rids {mismatch}, margin "
+                    f"{tf['margin']:.3g} (bound {tie_bound()}), drift "
+                    f"{tf['ratio']:.3g} (bound {DRIFT_BOUND})")
+            print("greedy tokens identical with the fake-quant reference "
+                  f"graph but for near-ties ({len(fp_out)} requests, routes "
+                  "forced to dequant-fp)")
+        else:
+            forced = "forced to " if fp_out is not completions else ""
+            print("greedy tokens identical with the fake-quant reference "
+                  f"graph ({len(fp_out)} requests, routes {forced}"
+                  "dequant-fp)")
         if scfg.chip is not None:
             print(f"chip-table {scfg.chip_table}: calibrated prefill chunk "
                   f"{eng.prefill_chunk} vs default {ref.prefill_chunk} — "
                   "tokens identical, only the budget differs")
         ratio = s["packed_vs_policy"]
-        if args.smoke and abs(ratio - 1.0) > 0.05:
+        if args.check and abs(ratio - 1.0) > 0.05:
             raise SystemExit(
                 f"packed HBM bytes {s['packed_bytes']} off policy "
                 f"accounting {s['policy_bytes']:.0f} by more than 5% "
                 f"(x{ratio:.3f})")
-        if args.smoke:
+        if args.check:
             print(f"packed HBM bytes within 5% of MPQPolicy.size_bytes "
                   f"(x{ratio:.3f})")
+        if args.check and kernels:
+            drift = scorer.drift(reqs, scored)
+            print("kernel routes vs f32 reference (matmul precision "
+                  f"'highest'): first divergent token position "
+                  f"{drift['first_divergence']} | max |dlogit| per step "
+                  + " ".join(f"{x:.3g}" for x in drift["per_step"])
+                  + f" | max {drift['max']:.4g} = {drift['ratio']:.3g} "
+                  f"of the reference logit range {drift['scale']:.4g}")
+            if not drift["ratio"] <= DRIFT_BOUND:
+                raise SystemExit(
+                    f"kernel-route logits drift {drift['ratio']:.3g} of "
+                    f"the reference logit range, above the {DRIFT_BOUND} "
+                    "bound")
+            print(f"kernel-route logit drift within {DRIFT_BOUND} of the "
+                  "reference logit range")
     return eng, completions
+
+
+# Largest kernel-route logit drift from the f32 reference, as a fraction of
+# the reference's largest |logit|. int32 accumulation rounds where f32 does
+# not, so an activation can land on the neighbouring code, and with random
+# weights such flips cascade through every later layer: 0.096 at 28 layers
+# of the smoke width on the CPU (kernels interpreted), 0.106 on a v5e at
+# full width. A fault planted in the decode-attention kernel reads only
+# 0.145 to 0.211 on the CPU, too close to the sound readings for a bound
+# between them to hold across seeds and policies, so this bound catches
+# gross errors only. ``runtime.parity`` holds each kernel to its fp route
+# op by op, where a sound kernel reads ~1e-7 and those faults 0.25 to 1.4.
+DRIFT_BOUND = 0.25
+
+
+def kernel_routes(registry) -> list:
+    """The Pallas matmul routes counted in ``registry``: int32-exact, and so
+    not bitwise equal to the fp einsum of the reference graph."""
+    return [name for name, n in route_counts(registry).items()
+            if name.startswith("dispatch.route.pallas-") and n > 0]
+
+
+def route_counts(registry) -> dict:
+    """Every ``dispatch.*`` route counter of one engine epoch (counts are
+    per trace, not per executed step)."""
+    return {name: registry.value(name)
+            for name in sorted(getattr(registry, "_metrics", {}))
+            if name.startswith("dispatch.") and name.count(".") == 2
+            and not name.startswith("dispatch.act_reuse")}
+
+
+def tie_bound() -> float:
+    """Largest margin, as a fraction of the reference logit range, by which
+    a dequant-fp token may trail the reference's first choice. XLA:CPU
+    evaluates the packed and the fake-quant graphs bitwise alike, so there
+    it is 0 and the token gate is exact identity. A TPU rounds two
+    equivalent graphs apart (packed vs fake-quant weights, paged chunked vs
+    one-shot prefill, flash vs one softmax): an activation then lands on
+    the neighbouring code and the flip cascades, so on a v5e at Qwen3-0.6B
+    width their logits differ by 0.07 to 0.16 of the range, at the default
+    matmul precision and at 'highest' alike, and a token flips where the
+    reference's first two logits are closer than that. The flips seen
+    there trailed by 0.013 of the range, and by at most 0.051 (the drift at
+    the flip bounds it)."""
+    return 0.0 if jax.default_backend() == "cpu" else 0.15
+
+
+class RefScorer:
+    """``ref_adapter`` traced at matmul precision 'highest' and
+    teacher-forced along a run's own tokens: the prefill's last-position
+    logits, then one decode step per generated token but the last.
+    Requests with one prompt length run as one batch; the jitted steps are
+    shared by every run scored."""
+
+    def __init__(self, ref_adapter, ref_params, cache_len):
+        self.adapter, self.params = ref_adapter, ref_params
+        self._prefill = jax.jit(lambda p, t: ref_adapter.prefill(
+            p, {"tokens": t}, prefill_cap=cache_len))
+        self._decode = jax.jit(ref_adapter.decode)
+
+    def _run(self, group, toks):
+        # toks: (n, steps) forced tokens; rows past their own length carry
+        # filler whose logits are dropped
+        logits, st = self._prefill(self.params, jnp.asarray(
+            np.stack([r.tokens for r in group]), jnp.int32))
+        st = self.adapter.state_per_slot(st)
+        rows = [logits]
+        plen = group[0].prompt_len
+        for t in range(toks.shape[1] - 1):
+            logits, st = self._decode(
+                self.params, jnp.asarray(toks[:, t:t + 1]),
+                jnp.full((len(group),), plen + t, jnp.int32), st)
+            rows.append(logits)
+        return np.asarray(jnp.stack(rows, axis=1))   # (n, steps, V)
+
+    def drift(self, reqs, completions):
+        """Drift of the logits each completion recorded (its engine ran
+        with ``record_logits``) from the reference along its tokens.
+        ``first_divergence`` is the earliest step at which the reference's
+        argmax differs from the completion's token (None if it never
+        does); ``margin`` is the largest lead of the reference's argmax
+        over the completion's token, and ``ties`` counts the steps where
+        it leads at all, both as fractions of the range ``scale``."""
+        per_step, first, scale = None, None, 0.0
+        lead, ties = 0.0, 0
+        by_len = {}
+        for req in reqs:
+            by_len.setdefault(req.prompt_len, []).append(req)
+        for group in by_len.values():
+            gen = [completions[r.rid].tokens for r in group]
+            steps = max(len(g) for g in gen)
+            toks = np.zeros((len(group), steps), np.int32)
+            for i, g in enumerate(gen):
+                toks[i, :len(g)] = g
+            with jax.default_matmul_precision("highest"):
+                want = self._run(group, toks)
+            for i, r in enumerate(group):
+                n = len(gen[i])
+                got = completions[r.rid].logits
+                d = np.abs(got[:n] - want[i, :n]).max(axis=-1)
+                if per_step is None or n > len(per_step):
+                    d, per_step = (per_step if per_step is not None
+                                   else np.zeros(0)), d
+                per_step[:len(d)] = np.maximum(per_step[:len(d)], d)
+                w = want[i, :n]
+                scale = max(scale, float(np.abs(w).max()))
+                gap = w.max(axis=-1) - w[np.arange(n), gen[i]]
+                lead = max(lead, float(gap.max()))
+                ties += int((gap > 0).sum())
+                diverged = np.nonzero(w.argmax(axis=-1)
+                                      != np.asarray(gen[i]))[0]
+                if diverged.size:
+                    first = int(diverged[0]) if first is None \
+                        else min(first, int(diverged[0]))
+        worst = float(per_step.max())
+        scale = scale or float("nan")
+        return {"per_step": per_step.tolist(), "max": worst, "scale": scale,
+                "ratio": worst / scale, "first_divergence": first,
+                "margin": lead / scale, "ties": ties}
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="limpq-demo")
-    ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--smoke", action="store_true",
+                    help="shrink the arch to its smoke config, cap the "
+                         "request set, and run the --check gates")
+    ap.add_argument("--check", action="store_true",
+                    help="run the smoke gates (token identity, byte "
+                         "accounting, kernel-route logit drift) at the "
+                         "size given, without shrinking the model")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", "--batch", type=int, default=4, dest="slots")
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -905,6 +1125,8 @@ def main(argv=None):
     ap.add_argument("--uniform-bits", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    args.check = args.check or args.smoke
+    compile_cache.enable()
 
     if args.write_demo_policy:
         # layer names depend on the config size, so the policy must be
@@ -966,10 +1188,9 @@ def main(argv=None):
         forced = None if scfg.decode_attn == "auto" else scfg.decode_attn
         with dispatch.force_decode_attn(forced):
             if scfg.elastic:
-                serve_elastic(args, scfg, cfg, params, ctx, reqs)
-            else:
-                serve_quantized(args, scfg, cfg, params, ctx, reqs, axes)
-        return
+                return serve_elastic(args, scfg, cfg, params, ctx, reqs)
+            return serve_quantized(args, scfg, cfg, params, ctx, reqs,
+                                   axes)
 
     if axes.enabled and jax.default_backend() != "tpu":
         # fake-quant fp serving has no packed-codes gather, so off-TPU it
@@ -1001,7 +1222,7 @@ def main(argv=None):
     # obs artifacts + gates come from THIS measured epoch, before the
     # --compare reset below starts a fresh registry/trace
     export_obs(args, eng)
-    if args.smoke:
+    if args.check:
         check_trace(eng, args.schedule)
         calibration_report(eng, cfg, gate=True)
     finish_stream(args, eng, streamer)
@@ -1030,7 +1251,7 @@ def main(argv=None):
             print(f"chip-table {scfg.chip_table}: calibrated prefill chunk "
                   f"{eng.prefill_chunk} vs default {fixed.prefill_chunk} — "
                   "tokens identical, only the budget differs")
-        if args.smoke and args.stagger and saved <= 0:
+        if args.check and args.stagger and saved <= 0:
             raise SystemExit("continuous batching saved no decode steps on a "
                              "staggered schedule")
     elif args.compare:

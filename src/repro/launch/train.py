@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import time
 
 import jax
@@ -31,6 +32,7 @@ from repro.core import importance as imp
 from repro.core.policy import MPQPolicy
 from repro.data import SyntheticLM
 from repro.dist.axes import NO_AXES
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.models.quant_layers import QuantContext, fp_context
 
@@ -56,6 +58,7 @@ def main(argv=None):
     ap.add_argument("--no-freeze-backbone", action="store_true")
     ap.add_argument("--save-indicators", default=None)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     rng = jax.random.PRNGKey(args.seed)
@@ -80,8 +83,12 @@ def main(argv=None):
         lr = args.lr if args.lr is not None else 0.01
         opt = imp.importance_optimizer(
             lr, freeze_backbone=not args.no_freeze_backbone)
+        # remat: each pass's backward re-runs its layer forward instead of
+        # keeping every layer's fake-quantized weights live — without it a
+        # full-width step outgrows one 16 GB chip at batch 1
         step_fn = jax.jit(imp.make_importance_step(cfg, ctx, opt, NO_AXES,
-                                                   remat=False))
+                                                   remat=True),
+                          donate_argnums=(0, 1))
     else:
         lr = args.lr if args.lr is not None else 3e-3
         opt = optim.adamw(optim.cosine_warmup(lr, args.steps // 20 + 1,
@@ -114,15 +121,26 @@ def main(argv=None):
         if args.mode == "importance":
             srng, sub = jax.random.split(srng)
             params, opt_state, m = step_fn(params, opt_state, batch, sub)
-            loss = float(jnp.mean(m["loss_uniform"]))
+            # one loss per uniform-bit pass, then the random pass
+            losses = [float(x) for x in m["loss_uniform"]]
+            losses.append(float(m["loss_random"]))
+            loss = sum(losses[:-1]) / len(losses[:-1])
         else:
             params, opt_state, m = step_fn(params, opt_state, batch)
-            loss = float(m["loss"])
+            losses = [float(m["loss"])]
+            loss = losses[0]
         dt = time.time() - t0
+        if not all(math.isfinite(x) for x in losses):
+            raise SystemExit(f"step {step}: non-finite loss {losses}")
         if wd.observe(dt):
             print(f"[watchdog] step {step} straggled: {dt:.2f}s")
         if step % args.log_every == 0 or step == args.steps - 1:
-            print(f"step {step:5d}  loss {loss:.4f}  {dt*1e3:7.1f} ms")
+            per_pass = ""
+            if args.mode == "importance":
+                per_pass = "  passes [" + " ".join(
+                    f"{x:.4f}" for x in losses) + "]"
+            print(f"step {step:5d}  loss {loss:.4f}  {dt*1e3:7.1f} ms"
+                  + per_pass)
         if mgr and (step + 1) % args.ckpt_every == 0:
             mgr.save(step, params, meta={"arch": cfg.name, "mode": args.mode})
     if mgr:

@@ -142,9 +142,6 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool,
 # recomputation, expressed in pure JAX (the Pallas analog on real TPUs
 # shares the same schedule).
 
-USE_PALLAS_FWD_ON_TPU = True
-
-
 def _flash_fwd_lse(qr, k, v, *, causal, window, q_block, kv_block):
     """Forward with per-row logsumexp. qr pre-scaled (B,S,KV,G,hd).
     Returns (out (B,S,KV,G,hd) f32, lse (B,KV,G,S) f32).
@@ -153,10 +150,12 @@ def _flash_fwd_lse(qr, k, v, *, causal, window, q_block, kv_block):
     (repro.kernels.flash_attention): probability tiles stay in VMEM instead
     of streaming through HBM — the fix for the dominant memory-roofline
     term of the attention train cells (EXPERIMENTS.md §Perf). The pure-JAX
-    scan below is the CPU/dry-run path and the numerical oracle.
+    scan below is the CPU/dry-run path, the fallback for a sequence that
+    does not tile into kv blocks (``runtime.dispatch.resolve_flash_fwd``
+    counts which one ran), and the numerical oracle.
     """
-    if USE_PALLAS_FWD_ON_TPU and jax.default_backend() == "tpu" \
-            and qr.shape[1] % kv_block == 0:
+    from repro.runtime import dispatch
+    if dispatch.resolve_flash_fwd(qr.shape[1], kv_block) == "pallas":
         from repro.kernels import flash_attention as _fa
         return _fa.flash_fwd_pallas(qr, k, v, causal=causal, window=window,
                                     q_block=q_block, kv_block=kv_block)

@@ -138,7 +138,28 @@ def qeinsum(eqn: str, x: Array, p, bits, ctx: QuantContext) -> Array:
     a_idx = None if bits is None else bits["a"]
     xq = _maybe_quant_a(x, p, a_idx, ctx)
     wq = _maybe_quant_w(p, w_idx, ctx)
-    return jnp.einsum(eqn, xq, wq)
+    return row_einsum(eqn, xq, wq)
+
+
+def row_einsum(eqn: str, x: Array, w: Array) -> Array:
+    """``jnp.einsum`` for a projection whose output rows must not depend on
+    how many rows ride along.
+
+    XLA strips a one-row activation down to a vector-matrix product, which
+    XLA:CPU (and only the CPU backend) runs through a GEMV kernel that sums
+    in another order than its matrix kernel. A one-slot decode step would
+    then write KV rows an ulp away from the rows a multi-token speculative
+    verify writes for the same tokens. On the CPU a single row is padded to
+    two along x's leading axis and sliced back, so every row count takes
+    the matrix kernel; other backends run the einsum as written."""
+    ins, out = eqn.split("->")
+    lead = ins.split(",")[0][:1]
+    if (jax.default_backend() != "cpu" or x.ndim < 2
+            or x.size != x.shape[-1] or "." in eqn or lead not in out):
+        return jnp.einsum(eqn, x, w)
+    pad = jnp.concatenate([x, jnp.zeros_like(x)], axis=0)
+    y = jnp.einsum(eqn, pad, w)
+    return jax.lax.slice_in_dim(y, 0, 1, axis=out.index(lead))
 
 
 def qeinsum_pinned(eqn: str, x: Array, p, ctx: QuantContext,

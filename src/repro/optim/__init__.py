@@ -23,6 +23,9 @@ Schedule = Callable[[Array], Array]      # step -> lr
 class Optimizer(NamedTuple):
     init: Callable[[Any], Any]
     update: Callable[[Any, Any, Any], Tuple[Any, Any]]
+    # (path, leaf) -> bool for the leaves this optimizer moves; None = all.
+    # A step may skip forming gradients for the others (``masked`` sets it)
+    trainable: Optional[Callable[[Any, Any], bool]] = None
 
 
 # ---------------------------------------------------------------------------
@@ -155,22 +158,26 @@ def indicator_only_mask(path, leaf) -> bool:
 
 
 def masked(opt: Optimizer, trainable: Callable) -> Optimizer:
-    """Zero updates (and skip state) for leaves where trainable() is False."""
+    """Zero updates for leaves where trainable() is False. The inner
+    optimizer sees only the trainable leaves (the frozen ones are None
+    holes), so frozen leaves carry no optimizer state, never enter global-
+    norm clipping, and cost no device memory beyond their zero update."""
+
+    def split(tree):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, x: x if trainable(path, x) else None, tree)
 
     def init(params):
-        return opt.init(params)
+        return opt.init(split(params))
 
     def update(grads, state, params):
-        grads = jax.tree_util.tree_map_with_path(
-            lambda path, g: g if trainable(path, g) else jnp.zeros_like(g),
-            grads)
-        updates, state = opt.update(grads, state, params)
-        updates = jax.tree_util.tree_map_with_path(
-            lambda path, u: u if trainable(path, u) else jnp.zeros_like(u),
-            updates)
+        updates, state = opt.update(split(grads), state, split(params))
+        updates = jax.tree.map(
+            lambda u, p: jnp.zeros_like(p) if u is None else u, updates,
+            params, is_leaf=lambda x: x is None)
         return updates, state
 
-    return Optimizer(init, update)
+    return Optimizer(init, update, trainable)
 
 
 def apply_updates(params, updates):

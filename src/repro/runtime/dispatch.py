@@ -32,11 +32,14 @@ megatron eqn splits the *contraction*:
     y = sum_s  x_s @ dequant(codes_s)        (s = shard)
 
 each shard dequantizes its K-slab and computes a partial product, and the
-cross-shard partial-sum reduce happens in fp. That split is
-order-independent — hence still exact — whenever the per-shard partial is
+cross-shard partial-sum reduce happens in fp. That split would be
+order-independent — hence still exact — if the per-shard partial were
 accumulated in integers (the int8/int4 MXU kernel routes: int32 partials,
-fp only at the final scale), so on the kernel routes the eqn split is the
-execution plan. The fp fallback cannot use it and stay bitwise: fp MACs
+fp only at the final scale). But a Pallas kernel cannot be partitioned
+automatically, and the kernel routes have no shard_map plan yet, so a
+forward bound to a multi-device mesh resolves every routed op to its fp
+route (``_partitioned``). The fp route cannot use the split and stay
+bitwise: fp MACs
 reassociate under the split (measured: ~5e-5 per matmul, which the next
 layer's quantization grid amplifies into full code-step jumps). So in the
 ``dequant-fp`` route each shard still dequantizes only its own slab, but
@@ -54,6 +57,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.quantizer import fake_quant, lsq_grad_scale_factor
+from repro.models.quant_layers import row_einsum
 from repro.runtime.packing import PackedLinear
 
 Array = jax.Array
@@ -114,6 +118,9 @@ ROUTES = RouteTable({
     "matmul": ("dequant-fp", "pallas-int8", "pallas-w4"),
     "decode_attn": ("fused", "fused-interpret", "dequant-fp"),
     "kv_layout": ("ring", "paged"),
+    # the flash-attention forward of prefill and training: the Pallas
+    # kernel, or the jnp online-softmax scan it falls back to
+    "flash_fwd": ("pallas", "jnp-scan"),
     # how decode tokens are produced: plain target decode, or
     # self-speculative (the low-bit draft policy proposes, the searched
     # target policy verifies — launch/engine._spec_round)
@@ -162,6 +169,14 @@ def metrics_scope(registry):
         yield
     finally:
         _METRICS.pop()
+
+
+def _partitioned() -> bool:
+    """True inside a forward bound to a multi-device mesh (``axes_scope``):
+    the Pallas routes need a shard_map plan there, which does not exist
+    yet, so every routed op takes its fp route."""
+    axes = _AXES[-1]
+    return axes is not None and axes.mesh.size > 1
 
 
 def _count_route(family: str, route: str) -> None:
@@ -227,8 +242,25 @@ def resolve_decode_attn(backend: Optional[str] = None) -> str:
     route = ROUTES.forced("decode_attn")
     if route is None:
         backend = backend or jax.default_backend()
-        route = "fused" if backend == "tpu" else "dequant-fp"
+        fused = backend == "tpu" and not _partitioned()
+        route = "fused" if fused else "dequant-fp"
     _count_route("decode_attn", route)
+    return route
+
+
+def resolve_flash_fwd(seq_len: int, kv_block: int,
+                      backend: Optional[str] = None) -> str:
+    """Route for the flash-attention forward: the Pallas kernel on a TPU
+    when the sequence tiles into ``kv_block`` blocks, else the jnp scan.
+    Counted like the other routes, so a TPU prefill whose length does not
+    tile shows up as ``dispatch.flash_fwd.jnp-scan``."""
+    route = ROUTES.forced("flash_fwd")
+    if route is None:
+        backend = backend or jax.default_backend()
+        tiles = seq_len % kv_block == 0
+        pallas = backend == "tpu" and tiles and not _partitioned()
+        route = "pallas" if pallas else "jnp-scan"
+    _count_route("flash_fwd", route)
     return route
 
 
@@ -381,8 +413,8 @@ def _impl_dequant_fp(eqn: str, x: Array, pl: PackedLinear, ctx) -> Array:
         # produces wrong slabs (only when the chain stays internal to a
         # larger jit; any materialization hides it)
         w = jax.lax.optimization_barrier(pl.dequant(ctx.compute_dtype))
-        return jnp.einsum(eqn, xq, w)
-    return jnp.einsum(eqn, xq, pl.dequant(ctx.compute_dtype))
+        return row_einsum(eqn, xq, w)
+    return row_einsum(eqn, xq, pl.dequant(ctx.compute_dtype))
 
 
 def _scalar_scale(pl: PackedLinear) -> Array:
@@ -449,7 +481,7 @@ def resolve(eqn: str, pl: PackedLinear, backend: Optional[str] = None) -> str:
     if forced is not None:
         return forced
     backend = backend or jax.default_backend()
-    if backend != "tpu":
+    if backend != "tpu" or _partitioned():
         return "dequant-fp"
     return kernel_eligible(eqn, pl) or "dequant-fp"
 

@@ -1,7 +1,7 @@
 """Continuous-batching engine: token-identity with the fixed-batch path,
 strictly-fewer decode steps on staggered schedules, and the slot
 admission/eviction invariants (no leaks, no KV mixing) under random
-arrival/finish schedules (hypothesis, stub-compatible)."""
+arrival/finish schedules (hypothesis)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -210,13 +210,13 @@ def packed_setup():
                             compute_dtype=jnp.float32)
     policy = MPQPolicy.uniform(lm.enumerate_qlayers(cfg), 4)
 
-    def build(layout, cache_len=29):
+    def build(layout, cache_len=29, **ecfg):
         sess = QuantizedSession(cfg, params, policy, ctx, mode="packed",
                                 kv_quant="int8")
         eng = DecodeEngine(sess.params, cfg, None, ctx, NO_AXES,
                            EngineConfig(slots=2, cache_len=cache_len,
                                         kv_quant="int8", kv_layout=layout,
-                                        page_size=8), adapter=sess)
+                                        page_size=8, **ecfg), adapter=sess)
         return sess, eng
 
     return dict(cfg=cfg, params=params, ctx=ctx, build=build)
@@ -239,19 +239,25 @@ def test_paged_engine_token_identical_and_saves_prefill(packed_setup):
     reqs = [mk(0, 5), mk(1, 3, 1), mk(2, 7, 2),
             Request(rid=3, tokens=rng.integers(1, 400, size=9).astype(
                 np.int32), max_new=4, arrival=2)]
-    toks, stats = {}, {}
+    toks, stats, logits = {}, {}, {}
     from repro.runtime import dispatch
     for layout in ("ring", "paged"):
-        _, eng = packed_setup["build"](layout)
+        _, eng = packed_setup["build"](layout, record_logits=True)
         with dispatch.force_decode_attn("dequant-fp"):
             eng.submit_all(reqs)
             out = eng.run()
         toks[layout] = {r.rid: out[r.rid].tokens for r in reqs}
+        logits[layout] = {r.rid: out[r.rid].logits for r in reqs}
         stats[layout] = eng.stats
         if layout == "paged":
             eng.pool.check()            # no page leaked after the drain
             assert all(s is None for s in eng.slots)
     assert toks["paged"] == toks["ring"]
+    for r in reqs:   # the logits each token was taken from, bit for bit
+        got = logits["paged"][r.rid]
+        assert got.shape[0] == len(toks["paged"][r.rid])
+        assert list(got.argmax(-1)) == toks["paged"][r.rid]
+        np.testing.assert_array_equal(got, logits["ring"][r.rid])
     assert stats["paged"].prefill_flops_saved > 0
     assert stats["ring"].prefill_flops_saved == 0
     assert stats["paged"].prefill_tokens < stats["ring"].prefill_tokens
@@ -318,3 +324,52 @@ def test_roofline_scheduler_hook():
     fast_hbm = roofline.ChipSpec(name="x", hbm_bytes_s=8 * 819e9)
     assert roofline.suggest_prefill_chunk(
         cfg, 8, cache_tokens=2048, chip=fast_hbm) <= chunk
+
+
+def test_chip_envelope_by_device_kind(monkeypatch):
+    """Off-TPU the planners model the v5e table entry; on a TPU the
+    envelope is looked up by device kind and an unknown kind raises."""
+    from repro.dist import roofline
+
+    assert roofline.local_chip() is roofline.DEFAULT_CHIP
+    assert roofline.CHIPS["TPU v5 lite"] is roofline.DEFAULT_CHIP
+    assert roofline.DEFAULT_CHIP.ici_bytes_s == 1600e9 / 8
+
+    class _Dev:
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+    assert roofline.local_chip() is roofline.CHIPS["TPU v5 lite"]
+    _Dev.device_kind = "TPU v9 imaginary"
+    with pytest.raises(ValueError, match="no ChipSpec"):
+        roofline.local_chip()
+
+
+def test_flash_fwd_route_is_counted_and_resolved_from_shape():
+    """The flash forward's fallback to the jnp scan is a counted route:
+    Pallas only on a TPU, for a sequence that tiles into kv blocks, and
+    outside a multi-device mesh."""
+    from repro.dist.axes import MeshAxes
+    from repro.obs.metrics import MetricsRegistry
+    from repro.runtime import dispatch
+
+    class _Mesh:
+        size = 4
+
+    reg = MetricsRegistry()
+    with dispatch.metrics_scope(reg):
+        assert dispatch.resolve_flash_fwd(2048, 512, backend="tpu") == "pallas"
+        assert dispatch.resolve_flash_fwd(2000, 512,
+                                          backend="tpu") == "jnp-scan"
+        assert dispatch.resolve_flash_fwd(2048, 512,
+                                          backend="cpu") == "jnp-scan"
+        with dispatch.axes_scope(MeshAxes(mesh=_Mesh())):
+            assert dispatch.resolve_flash_fwd(2048, 512,
+                                              backend="tpu") == "jnp-scan"
+            assert dispatch.resolve_decode_attn("tpu") == "dequant-fp"
+        with dispatch.force_route("flash_fwd", "jnp-scan"):
+            assert dispatch.resolve_flash_fwd(2048, 512,
+                                              backend="tpu") == "jnp-scan"
+    assert reg.value("dispatch.flash_fwd.pallas") == 1
+    assert reg.value("dispatch.flash_fwd.jnp-scan") == 4

@@ -38,8 +38,6 @@ def test_matches_xla_cost_analysis_on_unrolled():
             for s in [(64, 128), (128, 256), (256, 64)]]
     comp = jax.jit(jax.grad(f, argnums=(1, 2))).lower(*args).compile()
     ca = comp.cost_analysis()
-    if isinstance(ca, list):        # jax<=0.4.x returns [dict]
-        ca = ca[0]
     mine = hlo.analyze(comp.as_text())
     np.testing.assert_allclose(mine.flops, ca["flops"], rtol=1e-6)
     # bytes: XLA's fusion choices vary slightly between runs; agreement

@@ -27,6 +27,7 @@ from repro.checkpoint import CheckpointManager
 from repro.data import SyntheticLM
 from repro.dist import sharding
 from repro.dist.axes import NO_AXES
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.models.quant_layers import QuantContext
 
@@ -46,7 +47,7 @@ p_ref, _, m_ref = step_ref(params, opt.init(params), batch)
 loss_ref = float(m_ref["loss"])
 
 # ---- sharded: 2-way data x 4-way model --------------------------------------
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 axes = sharding.make_axes_for(cfg, mesh, shard_seq=False)
 pspecs = sharding.param_specs(cfg, params, axes)
 bspecs = sharding.batch_specs(cfg, batch, axes)
@@ -77,7 +78,7 @@ ckdir = tempfile.mkdtemp()
 mgr = CheckpointManager(ckdir)
 mgr.save(0, p_new, blocking=True)
 
-mesh2 = jax.make_mesh((4, 2), ("data", "model"))      # reshaped topology
+mesh2 = make_mesh((4, 2), ("data", "model"))      # reshaped topology
 axes2 = sharding.make_axes_for(cfg, mesh2, shard_seq=False)
 pspecs2 = sharding.param_specs(cfg, params, axes2)
 flat_specs = {}

@@ -75,3 +75,17 @@ def test_masked_freeze():
 def test_global_norm_empty_and_scalar():
     assert float(optim.global_norm({})) == 0.0
     np.testing.assert_allclose(float(optim.global_norm(jnp.asarray(3.0))), 3.0)
+
+
+def test_masked_keeps_no_state_for_frozen_leaves():
+    """Frozen leaves carry no optimizer state and stay out of the clipped
+    global norm: only the trainable leaf's gradient is clipped."""
+    opt = optim.masked(optim.sgd(1.0, momentum=0.9, clip_norm=1.0),
+                       lambda path, leaf: optim.path_str(path).endswith("s_w"))
+    p = {"w": jnp.ones((4,)), "s_w": jnp.asarray([1.0])}
+    state = opt.init(p)
+    assert state.momentum["w"] is None
+    g = {"w": jnp.full((4,), 100.0), "s_w": jnp.asarray([0.5])}
+    updates, _ = opt.update(g, state, p)
+    np.testing.assert_array_equal(np.asarray(updates["w"]), 0.0)
+    np.testing.assert_allclose(float(updates["s_w"][0]), -0.5, rtol=1e-6)

@@ -52,11 +52,14 @@ def test_random_workload_never_leaks(max_pages, seed, n_pages):
             chain[j] = chain[j - 1] + chain[j]
         shared = list(pool.lookup_prefix(chain))
         need = n - len(shared)
+        # pin the hit before allocating, as the engine does: allocation
+        # may evict LRU prefixes, the one just hit included
+        pool.ref(shared)
         try:
             fresh, _ = pool.alloc_with_freed(need)
         except RuntimeError:
+            pool.release(shared)
             continue            # pool genuinely full of live slots: skip
-        pool.ref(shared)
         pages = shared + fresh
         pool.register_prefix(chain, pages)
         live[next_slot] = pages

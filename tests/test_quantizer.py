@@ -100,3 +100,16 @@ def test_init_scales():
     s = qz.init_scale_from_stats(w, 7)
     np.testing.assert_allclose(s, 2 * 2.0 / np.sqrt(7), rtol=1e-6)
     np.testing.assert_allclose(qz.init_scale_same(4), 0.1 / 4)
+
+
+def test_grad_scale_is_bitwise_identity_in_value():
+    """The LSQ grad-scale wrapper returns x exactly, whatever the factor
+    rounds to — two graphs that compute the factor differently (one
+    constant-folded, one at run time) still quantize on one grid."""
+    r = np.random.default_rng(0)
+    x = jnp.asarray(r.uniform(1e-4, 1.0, size=512), jnp.float32)
+    for g in (1e-3, 0.0731, 1 / 3.0, 0.999):
+        y = qz.grad_scale(x, jnp.float32(g))
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
+        dy = jax.grad(lambda v: jnp.sum(qz.grad_scale(v, g)))(x)
+        np.testing.assert_allclose(np.asarray(dy), g, rtol=1e-6)

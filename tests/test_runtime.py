@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
+from jax.sharding import PartitionSpec as P
 
 from repro.configs import smoke_config
 from repro.core.policy import MPQPolicy
@@ -148,8 +149,10 @@ def test_packed_specs_shard_every_code_leaf():
     for name, s in leaves.items():
         assert any(e is not None for e in tuple(s.codes)), (name, s.codes)
     # column-parallel scale shards, row-parallel scale replicates
-    assert tuple(leaves["sites/000/wq"].scale) == (("model",),)
-    assert tuple(leaves["sites/000/wo"].scale) == (None,)
+    # PartitionSpec normalizes a singleton axis tuple (("model",) reads as
+    # "model"), so compare specs, not their raw entry tuples
+    assert P(*leaves["sites/000/wq"].scale) == P(("model",))
+    assert P(*leaves["sites/000/wo"].scale) == P(None)
     # per-shard accounting: every leaf sharded 4-ways, dims all divide
     per_shard = sess.packed_bytes(per_shard=True)
     assert per_shard * 4 == sess.packed_bytes()
@@ -347,7 +350,7 @@ def test_quant_cache_state_plumbing():
         body = str(getattr(path[0], "key", "")) == "body"
         slot_dim = 1 if body else 0
         if leaf.ndim >= 2 + slot_dim:                # per-slot leaf
-            assert entries[slot_dim] == axes.dp
+            assert P(entries[slot_dim]) == P(axes.dp)
 
 
 # ===========================================================================
@@ -585,3 +588,19 @@ def test_mixed_policy_qkv_never_share_a_group(serving):
         trio = [sp[n].a_group for n in ("wq", "wk", "wv")]
         named = [t for t in trio if t]
         assert len(named) == len(set(named)), (key, trio)
+
+
+def test_row_einsum_one_row_matches_batched_rows():
+    """A one-row projection gives bitwise the row a multi-row projection
+    gives: the one-slot decode step and the multi-token verify step write
+    the same KV rows."""
+    from repro.models.quant_layers import row_einsum
+
+    r = np.random.default_rng(0)
+    x = jnp.asarray(r.normal(size=(1, 4, 256)), jnp.float32)
+    w = jnp.asarray(r.normal(size=(256, 512)) * 0.1, jnp.float32)
+    f = jax.jit(lambda x, w: row_einsum("bsd,de->bse", x, w))
+    one, many = f(x[:, :1], w), f(x, w)
+    assert one.shape == (1, 1, 512)
+    np.testing.assert_array_equal(np.asarray(one[:, 0]),
+                                  np.asarray(many[:, 0]))

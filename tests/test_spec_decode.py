@@ -317,6 +317,15 @@ def test_spec_guards(setup):
                      EngineConfig(slots=2, cache_len=16, kv_quant="int8",
                                   speculate=2), adapter=mono)
 
+    # a speculative round scores its drafts in one verify pass: there is
+    # no per-token logit row to record
+    spec = SpecSession(cfg, params, setup["policy"], ctx, kv_quant="int8")
+    with pytest.raises(ValueError, match="token-at-a-time"):
+        DecodeEngine(spec.params, cfg, None, ctx, NO_AXES,
+                     EngineConfig(slots=2, cache_len=16, kv_quant="int8",
+                                  speculate=2, record_logits=True),
+                     adapter=spec)
+
     from repro.launch.serve import ServeConfig
     ok = ServeConfig(speculate=4, policy_path="searched.json")
     assert ok.engine_config(speculate=ok.speculate).speculate == 4
