@@ -65,6 +65,7 @@ def fake_quant_fwd(v2d, s, qmin: float, qmax: float,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), v2d.dtype),
+        name="fake_quant_fwd",
         interpret=interpret,
     )(vp, s.reshape(1, 1))
     return out[:M, :N]
@@ -94,6 +95,7 @@ def fake_quant_bwd(v2d, s, g2d, qmin: float, qmax: float,
             jax.ShapeDtypeStruct((Mp, Np), v2d.dtype),
             jax.ShapeDtypeStruct(grid, jnp.float32),
         ],
+        name="fake_quant_bwd",
         interpret=interpret,
     )(vp, s.reshape(1, 1), gp)
     return dv[:M, :N], ds

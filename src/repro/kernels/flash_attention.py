@@ -113,6 +113,7 @@ def flash_fwd_pallas(q, k, v, *, causal: bool, window=None,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_fwd_pallas",
         interpret=interpret,
     )(qf, kf, vf)
 

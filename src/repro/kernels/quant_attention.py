@@ -175,6 +175,7 @@ def decode_attn_quant(q, k_codes, k_scale, v_codes, v_scale, pos_arr, q_pos,
         out_shape=jax.ShapeDtypeStruct((B * KV, G, hd), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="decode_attn_quant",
         interpret=interpret,
     )(qp, qf, kf, ks, vf, vs, pos3)
 
@@ -294,6 +295,7 @@ def decode_attn_quant_paged(q, k_pages, k_scale, v_pages, v_scale, page_pos,
         out_shape=jax.ShapeDtypeStruct((B * KV, G, hd), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="decode_attn_quant_paged",
         interpret=interpret,
     )(tbl, qp, qf, kf, ks, vf, vs, pos)
 

@@ -109,6 +109,7 @@ def quant_matmul_w4(x_q, w_p, s_x, s_w, *, k=None, blocks=DEFAULT_BLOCKS,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        name="quant_matmul_w4",
         interpret=interpret,
     )(x_q, w_p, s_x.reshape(1, 1), s_w.reshape(1, 1))
     return out[:M, :N]
@@ -143,6 +144,7 @@ def quant_matmul(x_q, w_q, s_x, s_w, blocks=DEFAULT_BLOCKS,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        name="quant_matmul",
         interpret=interpret,
     )(x_q, w_q, s_x.reshape(1, 1), s_w.reshape(1, 1))
     return out[:M, :N]

@@ -97,6 +97,7 @@ def wkv_pallas(r, k, v, log_w, u, chunk: int = DEFAULT_CHUNK,
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="wkv_pallas",
         interpret=interpret,
     )(rb, kb, vb, lwb, ub)
     return y.reshape(B, H, S, hd).transpose(0, 2, 1, 3)

@@ -476,6 +476,7 @@ class DecodeEngine:
         self._act_reuse_base = getattr(adapter, "act_quant_reused", 0)
         self.slots: List[Optional[_Slot]] = [None] * self.ecfg.slots
         self.completions: Dict[int, Completion] = {}
+        self._submitted: Dict[int, float] = {}  # rid -> submit time (TTFT)
         self.axes = axes
         self._mesh = axes.mesh if axes.enabled else None
         self._param_shardings = None
@@ -908,6 +909,7 @@ class DecodeEngine:
         )
         self.slots = [None] * self.ecfg.slots
         self.completions = {}
+        self._submitted = {}
         self._act_reuse_base = getattr(self.adapter, "act_quant_reused", 0)
         self.state = self._fresh_state()
         self._set_cache_gauges()
@@ -933,6 +935,7 @@ class DecodeEngine:
                 "results)"
             )
         self.scheduler.submit(req)
+        self._submitted[req.rid] = time.perf_counter()
 
     def submit_all(self, reqs) -> None:
         for r in reqs:
@@ -1031,6 +1034,15 @@ class DecodeEngine:
         if not self.scheduler.hold_round:
             self._finish(idx, now)
 
+    def _admit(self, req: Request, idx: int, now: int) -> None:
+        """Prefill ``req`` into slot ``idx``: its first token is in hand
+        when this returns."""
+        with obs_trace.span("engine.admit", self.trace):
+            if self._paged:
+                self._admit_paged(req, idx, now)
+            else:
+                self._admit_ring(req, idx, now)
+
     def _admit_paged(self, req: Request, idx: int, now: int) -> None:
         """Paged admission: longest registered page-aligned prefix becomes
         a page-table remap (no recompute, attended via COW-refcounted
@@ -1054,113 +1066,44 @@ class DecodeEngine:
         ts_admit = (
             self.trace.now() if self.trace is not None else time.perf_counter()
         )
-        t0 = time.perf_counter()
-        self.state = self._map_slot(
-            self.state,
-            jnp.asarray(idx, jnp.int32),
-            jnp.asarray(np.asarray(table_row, np.int32)),
-        )
         chunk_len = max(ps, self.prefill_chunk // ps * ps)
-        first_arr = None
-        for start in range(hit_tokens, plen, chunk_len):
-            n = min(chunk_len, plen - start)
-            chunk = np.zeros((1, chunk_len), np.int32)
-            chunk[0, :n] = toks[start : start + n]
-            qpos = np.full((chunk_len,), -1, np.int32)
-            qpos[:n] = np.arange(start, start + n, dtype=np.int32)
-            logits, self.state = self._append(
-                self.params,
-                jnp.asarray(chunk),
-                jnp.asarray(qpos),
-                jnp.asarray(idx, jnp.int32),
-                jnp.asarray(n - 1, jnp.int32),
+        with obs_trace.span("engine.launch", self.trace):
+            t0 = time.perf_counter()
+            self.state = self._map_slot(
                 self.state,
+                jnp.asarray(idx, jnp.int32),
+                jnp.asarray(np.asarray(table_row, np.int32)),
             )
-            first_arr = jnp.argmax(logits[0], -1)
+            first_arr = None
+            for start in range(hit_tokens, plen, chunk_len):
+                n = min(chunk_len, plen - start)
+                chunk = np.zeros((1, chunk_len), np.int32)
+                chunk[0, :n] = toks[start : start + n]
+                qpos = np.full((chunk_len,), -1, np.int32)
+                qpos[:n] = np.arange(start, start + n, dtype=np.int32)
+                logits, self.state = self._append(
+                    self.params,
+                    jnp.asarray(chunk),
+                    jnp.asarray(qpos),
+                    jnp.asarray(idx, jnp.int32),
+                    jnp.asarray(n - 1, jnp.int32),
+                    self.state,
+                )
+                first_arr = jnp.argmax(logits[0], -1)
+            jax.block_until_ready((first_arr, self.state))
+            dt = time.perf_counter() - t0
         self._prefill_shapes.add(chunk_len)
-        jax.block_until_ready((first_arr, self.state))
-        dt = time.perf_counter() - t0
-        first = int(first_arr)
         # register this prompt's own complete-page chains: the next prompt
         # sharing them prefills only its suffix
         k_full = plen // ps
         pool.register_prefix(chain[:k_full], table_row[:k_full])
         self._slot_pages[idx] = table_row
-        m = self.metrics
-        m.counter("engine.t_prefill_s").inc(dt)
-        m.counter("engine.prefill_calls").inc()
-        m.counter("engine.prefill_tokens").inc(plen - hit_tokens)
-        m.counter("engine.admitted").inc()
-        if hit_tokens:
-            m.counter("engine.prefix_hit_tokens").inc(hit_tokens)
-            m.counter("engine.prefill_flops_saved").inc(
-                hit_tokens * self._flops_per_token
-            )
-        m.gauge("engine.prefill_compiles").set(len(self._prefill_shapes))
-        m.gauge("engine.kv_unique_pages").set(pool.unique_pages_in_use)
-        self._set_pool_gauges()
-        m.gauge("engine.act_quant_reused").set(
-            getattr(self.adapter, "act_quant_reused", 0) - self._act_reuse_base
+        self._admitted(
+            req, idx, now, first_arr, logits, ts_admit, dt,
+            plen - hit_tokens, hit_pages=len(shared),
         )
-        m.histogram("engine.prefill_ms").observe(dt * 1e3)
-        m.histogram("engine.ttft_ms").observe(dt * 1e3)
-        obs_health.attribute_latency(m, "matmul", self._matmul_route(), dt)
-        self.slots[idx] = _Slot(
-            req, first, now, ts_admit, ts_admit + dt, self._active_policy
-        )
-        if self.ecfg.record_logits:
-            self.slots[idx].logits = [np.asarray(logits[0], np.float32)]
-        m.gauge("engine.slot_occupancy").set(len(self._occupied()))
-        if self.trace is not None:
-            stamp = (
-                {"policy": self._active_policy} if self._active_policy else {}
-            )
-            track = obs_trace.req_track(req.rid)
-            self.trace.instant(
-                "admit",
-                track=track,
-                ts=ts_admit,
-                rid=req.rid,
-                slot=idx,
-                prompt_len=plen,
-                prefix_hit_tokens=hit_tokens,
-                iteration=now,
-            )
-            if hit_tokens:
-                # a remap is NOT a prefill: the explicit event carries what
-                # the page-table hit skipped so reconcile can tell a shared
-                # prefix from a suspiciously fast prefill span
-                self.trace.instant(
-                    "prefix_hit",
-                    track=track,
-                    ts=ts_admit,
-                    rid=req.rid,
-                    pages_reused=len(shared),
-                    tokens=hit_tokens,
-                    flops_saved=hit_tokens * self._flops_per_token,
-                )
-            self.trace.span(
-                "prefill",
-                ts_admit,
-                ts_admit + dt,
-                track=track,
-                rid=req.rid,
-                tokens=plen - hit_tokens,
-            )
-            self.trace.instant(
-                "first_token",
-                track=track,
-                ts=ts_admit + dt,
-                rid=req.rid,
-                token=first,
-                **stamp,
-            )
-        if req.max_new == 1 or first == self.ecfg.eos_id:
-            self._mark_done(idx, now)
 
-    def _admit(self, req: Request, idx: int, now: int) -> None:
-        if self._paged:
-            return self._admit_paged(req, idx, now)
+    def _admit_ring(self, req: Request, idx: int, now: int) -> None:
         toks = np.asarray(req.tokens, np.int32)
         plen = req.prompt_len
         if self._bucket:
@@ -1175,42 +1118,73 @@ class DecodeEngine:
                 {k: jnp.asarray(v)[None] for k, v in req.extra_inputs.items()}
             )
         ts_admit = self.trace.now() if self.trace is not None else time.perf_counter()
-        t0 = time.perf_counter()
-        if self._bucket:
-            logits, row = self._prefill(
-                self.params, inputs, jnp.asarray(plen, jnp.int32)
+        with obs_trace.span("engine.launch", self.trace):
+            t0 = time.perf_counter()
+            if self._bucket:
+                logits, row = self._prefill(
+                    self.params, inputs, jnp.asarray(plen, jnp.int32)
+                )
+            else:
+                logits, row = self._prefill(self.params, inputs)
+            row = self.adapter.state_per_slot(row)
+            self.state = self._insert(
+                self.state, row, jnp.asarray(idx, jnp.int32)
             )
-        else:
-            logits, row = self._prefill(self.params, inputs)
+            first_arr = jnp.argmax(logits[0], -1)
+            # fence the FULL output tree (sampled token AND the inserted
+            # cache state), so the stamp covers device work, not dispatch
+            # latency
+            jax.block_until_ready((first_arr, self.state))
+            dt = time.perf_counter() - t0
         self._prefill_shapes.add(int(toks.shape[-1]))
-        row = self.adapter.state_per_slot(row)
-        self.state = self._insert(self.state, row, jnp.asarray(idx, jnp.int32))
-        first_arr = jnp.argmax(logits[0], -1)
-        # fence the FULL output tree (sampled token AND the inserted cache
-        # state), so the stamp covers device work, not dispatch latency
-        jax.block_until_ready((first_arr, self.state))
-        dt = time.perf_counter() - t0
-        first = int(first_arr)
+        # the prefill span counts the bucketed (padded) tokens it ran
+        self._admitted(
+            req, idx, now, first_arr, logits, ts_admit, dt, plen,
+            span_tokens=int(toks.shape[-1]),
+        )
+
+    def _admitted(self, req, idx, now, first_arr, logits, ts_admit, dt,
+                  prefill_tokens, hit_pages=0, span_tokens=None) -> None:
+        """The host side of an admission once its prefill launch is fenced:
+        the first token's copy-back, the slot, counters and trace events.
+        TTFT runs from ``submit`` to the first token in hand, so the queue
+        wait counts; ``engine.prefill_ms`` is the fenced launch alone.
+        ``hit_pages`` shared prefix pages were remapped, not prefilled."""
+        hit_tokens = hit_pages * self.ecfg.page_size
+        with obs_trace.span("engine.sample", self.trace):
+            first = int(first_arr)
+            row = (
+                np.asarray(logits[0], np.float32)
+                if self.ecfg.record_logits
+                else None
+            )
+        t_first = time.perf_counter()
         m = self.metrics
         m.counter("engine.t_prefill_s").inc(dt)
         m.counter("engine.prefill_calls").inc()
-        m.counter("engine.prefill_tokens").inc(plen)
+        m.counter("engine.prefill_tokens").inc(prefill_tokens)
         m.counter("engine.admitted").inc()
+        if hit_tokens:
+            m.counter("engine.prefix_hit_tokens").inc(hit_tokens)
+            m.counter("engine.prefill_flops_saved").inc(
+                hit_tokens * self._flops_per_token
+            )
         m.gauge("engine.prefill_compiles").set(len(self._prefill_shapes))
+        if self._paged:
+            m.gauge("engine.kv_unique_pages").set(self.pool.unique_pages_in_use)
+            self._set_pool_gauges()
         m.gauge("engine.act_quant_reused").set(
             getattr(self.adapter, "act_quant_reused", 0) - self._act_reuse_base
         )
         m.histogram("engine.prefill_ms").observe(dt * 1e3)
-        # the first token is sampled from the prefill logits, so TTFT for an
-        # admitted request IS the fenced prefill duration (queue wait is the
-        # scheduler's ledger, not the engine's)
-        m.histogram("engine.ttft_ms").observe(dt * 1e3)
+        t_submit = self._submitted.pop(req.rid, t_first - dt)
+        m.histogram("engine.ttft_ms").observe((t_first - t_submit) * 1e3)
         obs_health.attribute_latency(m, "matmul", self._matmul_route(), dt)
         self.slots[idx] = _Slot(
             req, first, now, ts_admit, ts_admit + dt, self._active_policy
         )
-        if self.ecfg.record_logits:
-            self.slots[idx].logits = [np.asarray(logits[0], np.float32)]
+        if row is not None:
+            self.slots[idx].logits = [row]
         m.gauge("engine.slot_occupancy").set(len(self._occupied()))
         if self.trace is not None:
             stamp = (
@@ -1223,16 +1197,30 @@ class DecodeEngine:
                 ts=ts_admit,
                 rid=req.rid,
                 slot=idx,
-                prompt_len=plen,
+                prompt_len=req.prompt_len,
                 iteration=now,
+                **({"prefix_hit_tokens": hit_tokens} if self._paged else {}),
             )
+            if hit_tokens:
+                # a remap is NOT a prefill: the explicit event carries what
+                # the page-table hit skipped so reconcile can tell a shared
+                # prefix from a suspiciously fast prefill span
+                self.trace.instant(
+                    "prefix_hit",
+                    track=track,
+                    ts=ts_admit,
+                    rid=req.rid,
+                    pages_reused=hit_pages,
+                    tokens=hit_tokens,
+                    flops_saved=hit_tokens * self._flops_per_token,
+                )
             self.trace.span(
                 "prefill",
                 ts_admit,
                 ts_admit + dt,
                 track=track,
                 rid=req.rid,
-                tokens=int(toks.shape[-1]),
+                tokens=prefill_tokens if span_tokens is None else span_tokens,
             )
             self.trace.instant(
                 "first_token",
@@ -1246,68 +1234,90 @@ class DecodeEngine:
             self._mark_done(idx, now)
 
     def _decode_step(self, now: int) -> None:
-        n = self.ecfg.slots
-        toks = np.zeros((n, 1), np.int32)
-        pos = np.full((n,), -1, np.int32)
-        live: List[int] = []
-        for i, s in enumerate(self.slots):
-            if s is not None and not s.done:
-                toks[i, 0] = s.next_tok
-                pos[i] = s.next_pos
-                live.append(i)
-        t0 = time.perf_counter()
-        logits, self.state = self._decode(
-            self.params, jnp.asarray(toks), jnp.asarray(pos), self.state
-        )
-        nxt_arr = jnp.argmax(logits, -1)
-        # fence the FULL output tree (next tokens AND the appended cache
-        # state), so the stamp covers device work, not dispatch latency
-        jax.block_until_ready((nxt_arr, self.state))
-        dt = time.perf_counter() - t0
-        nxt = np.asarray(nxt_arr)
-        m = self.metrics
-        m.counter("engine.t_decode_s").inc(dt)
-        m.counter("engine.decode_steps").inc()
-        m.counter("engine.slot_steps").inc(len(live))
-        m.counter("engine.padded_slot_steps").inc(len(self._occupied()))
-        m.gauge("engine.act_quant_reused").set(
-            getattr(self.adapter, "act_quant_reused", 0) - self._act_reuse_base
-        )
-        m.histogram("engine.decode_step_ms").observe(dt * 1e3)
-        obs_health.attribute_latency(m, "decode_attn", self.decode_attn_route, dt)
-        # KV-scale drift: sampled host-side from the already-fenced state
-        # (materialized write-time scales), so the jitted graph never sees it
-        he = self.ecfg.health_every
-        if he and int(m.value("engine.decode_steps")) % he == 0:
-            self._kv_drift.publish(m, self._kv_drift.update(self.state))
-        ts1 = self.trace.now() if self.trace is not None else time.perf_counter()
-        if self.trace is not None:
-            self.trace.span(
-                "decode_step", ts1 - dt, ts1, slots=len(live), iteration=now
-            )
-        itl = m.histogram("engine.itl_ms")
-        rows = np.asarray(logits, np.float32) if self.ecfg.record_logits else None
-        for i in live:
-            s = self.slots[i]
-            if rows is not None:
-                s.logits.append(rows[i])
-            s.gen.append(int(nxt[i]))
-            s.next_tok = int(nxt[i])
-            s.next_pos += 1
-            itl.observe((ts1 - s.ts_last_token) * 1e3)
-            s.ts_last_token = ts1
-            if self.trace is not None:
-                self.trace.instant(
-                    "token",
-                    track=obs_trace.req_track(s.req.rid),
-                    ts=ts1,
-                    rid=s.req.rid,
-                    token=int(nxt[i]),
-                    iteration=now,
-                    **({"policy": s.policy_id} if s.policy_id else {}),
+        span, rec = obs_trace.span, self.trace
+        with span("engine.decode", rec):
+            n = self.ecfg.slots
+            toks = np.zeros((n, 1), np.int32)
+            pos = np.full((n,), -1, np.int32)
+            live: List[int] = []
+            for i, s in enumerate(self.slots):
+                if s is not None and not s.done:
+                    toks[i, 0] = s.next_tok
+                    pos[i] = s.next_pos
+                    live.append(i)
+            with span("engine.launch", rec):
+                t0 = time.perf_counter()
+                logits, self.state = self._decode(
+                    self.params, jnp.asarray(toks), jnp.asarray(pos), self.state
                 )
-            if len(s.gen) >= s.req.max_new or nxt[i] == self.ecfg.eos_id:
-                self._mark_done(i, now)
+                nxt_arr = jnp.argmax(logits, -1)
+                # fence the FULL output tree (next tokens AND the appended
+                # cache state), so the stamp covers device work, not
+                # dispatch latency
+                jax.block_until_ready((nxt_arr, self.state))
+                dt = time.perf_counter() - t0
+            with span("engine.sample", rec):
+                nxt = np.asarray(nxt_arr)
+                rows = (
+                    np.asarray(logits, np.float32)
+                    if self.ecfg.record_logits
+                    else None
+                )
+        self._kv_drift_sample()
+        with span("engine.bookkeeping", rec):
+            m = self.metrics
+            m.counter("engine.t_decode_s").inc(dt)
+            m.counter("engine.decode_steps").inc()
+            m.counter("engine.slot_steps").inc(len(live))
+            m.counter("engine.padded_slot_steps").inc(len(self._occupied()))
+            m.gauge("engine.act_quant_reused").set(
+                getattr(self.adapter, "act_quant_reused", 0)
+                - self._act_reuse_base
+            )
+            m.histogram("engine.decode_step_ms").observe(dt * 1e3)
+            obs_health.attribute_latency(
+                m, "decode_attn", self.decode_attn_route, dt
+            )
+            ts1 = rec.now() if rec is not None else time.perf_counter()
+            if rec is not None:
+                rec.span(
+                    "decode_step", ts1 - dt, ts1, slots=len(live), iteration=now
+                )
+            itl = m.histogram("engine.itl_ms")
+            for i in live:
+                s = self.slots[i]
+                if rows is not None:
+                    s.logits.append(rows[i])
+                s.gen.append(int(nxt[i]))
+                s.next_tok = int(nxt[i])
+                s.next_pos += 1
+                itl.observe((ts1 - s.ts_last_token) * 1e3)
+                s.ts_last_token = ts1
+                if rec is not None:
+                    rec.instant(
+                        "token",
+                        track=obs_trace.req_track(s.req.rid),
+                        ts=ts1,
+                        rid=s.req.rid,
+                        token=int(nxt[i]),
+                        iteration=now,
+                        **({"policy": s.policy_id} if s.policy_id else {}),
+                    )
+                if len(s.gen) >= s.req.max_new or nxt[i] == self.ecfg.eos_id:
+                    self._mark_done(i, now)
+
+    def _kv_drift_sample(self) -> None:
+        """KV-scale drift, every ``health_every``-th decode launch: sampled
+        host-side from the already-fenced state (materialized write-time
+        scales), so the jitted graph never sees it. Runs before the
+        launch is counted, so the stride counts this launch."""
+        he = self.ecfg.health_every
+        steps = int(self.metrics.value("engine.decode_steps")) + 1
+        if he and steps % he == 0:
+            with obs_trace.span("engine.kv_drift", self.trace):
+                self._kv_drift.publish(
+                    self.metrics, self._kv_drift.update(self.state)
+                )
 
     # -- self-speculative decode --------------------------------------------
     def _spec_draft_body(self, steps: int, p, tok, pos, state):
@@ -1383,12 +1393,15 @@ class DecodeEngine:
         if fn is None:
 
             def round_fn(tp, dp, tok, pos, remaining, state):
-                drafts, state = self._spec_draft_body(
-                    steps, dp, tok, pos, state
-                )
-                return self._spec_verify_fn(
-                    tp, tok, drafts, pos, remaining, state
-                )
+                # the scopes keep draft and verify apart in a device trace
+                with jax.named_scope("spec_draft"):
+                    drafts, state = self._spec_draft_body(
+                        steps, dp, tok, pos, state
+                    )
+                with jax.named_scope("spec_verify"):
+                    return self._spec_verify_fn(
+                        tp, tok, drafts, pos, remaining, state
+                    )
 
             fn = jax.jit(round_fn, donate_argnums=(5,))
             self._spec_fused_jits[steps] = fn
@@ -1414,155 +1427,170 @@ class DecodeEngine:
         )
         if k < 1:
             return self._decode_step(now)
-        n = self.ecfg.slots
-        toks = np.zeros((n, 1), np.int32)
-        pos = np.full((n,), -1, np.int32)
-        remaining = np.zeros((n,), np.int32)
-        for i in live:
-            s = self.slots[i]
-            toks[i, 0] = s.next_tok
-            pos[i] = s.next_pos
-            remaining[i] = s.req.max_new - len(s.gen)
-        m = self.metrics
-        t0 = time.perf_counter()
-        if self.trace is not None:
-            # two launches, fenced between, so the draft/verify phase
-            # spans carry measured durations; acceptance, truncation and
-            # rollback still run inside the verify launch
-            drafts, self.state = self._spec_draft(k)(
-                self.draft_params,
-                jnp.asarray(toks),
-                jnp.asarray(pos),
-                self.state,
-            )
-            jax.block_until_ready((drafts, self.state))
-            t_draft = time.perf_counter() - t0
-            targets, acc_arr, emit_arr, self.state = self._spec_verify(
-                self.params,
-                jnp.asarray(toks),
-                drafts,
-                jnp.asarray(pos),
-                jnp.asarray(remaining),
-                self.state,
-            )
-        else:
-            # traceless fast path: the whole round is ONE dispatch
-            t_draft = 0.0
-            targets, acc_arr, emit_arr, self.state = self._spec_fused(k)(
-                self.params,
-                self.draft_params,
-                jnp.asarray(toks),
-                jnp.asarray(pos),
-                jnp.asarray(remaining),
-                self.state,
-            )
-        jax.block_until_ready((targets, acc_arr, emit_arr, self.state))
-        dt = time.perf_counter() - t0
-        t_np = np.asarray(targets)
-        a_np = np.asarray(acc_arr)
-        e_np = np.asarray(emit_arr)
-        emits: Dict[int, List[int]] = {}
-        accepted_total = 0
-        for i in live:
-            s = self.slots[i]
-            accepted_total += int(a_np[i])
-            s.spec_drafted += k
-            s.spec_accepted += int(a_np[i])
-            m.histogram("spec.accept_len").observe(float(a_np[i]))
-            emit = [int(x) for x in t_np[i, : e_np[i]]]
-            emits[i] = emit
-            s.gen.extend(emit)
-            s.next_tok = emit[-1]
-            s.next_pos += len(emit)
-        m.counter("engine.t_decode_s").inc(dt)
-        m.counter("engine.decode_steps").inc()
-        m.counter("engine.slot_steps").inc(len(live))
-        m.counter("engine.padded_slot_steps").inc(len(self._occupied()))
-        m.counter("spec.rounds").inc()
-        m.counter("spec.draft_tokens").inc(k * len(live))
-        m.counter("spec.accepted_tokens").inc(accepted_total)
-        m.gauge("engine.act_quant_reused").set(
-            getattr(self.adapter, "act_quant_reused", 0) - self._act_reuse_base
-        )
-        m.histogram("engine.decode_step_ms").observe(dt * 1e3)
-        obs_health.attribute_latency(m, "decode_attn", self.decode_attn_route, dt)
-        he = self.ecfg.health_every
-        if he and int(m.value("engine.decode_steps")) % he == 0:
-            self._kv_drift.publish(m, self._kv_drift.update(self.state))
-        ts1 = self.trace.now() if self.trace is not None else time.perf_counter()
-        if self.trace is not None:
-            self.trace.span(
-                "decode_step", ts1 - dt, ts1, slots=len(live), iteration=now
-            )
-            self.trace.span(
-                "spec_draft",
-                ts1 - dt,
-                ts1 - dt + t_draft,
-                slots=len(live),
-                k=k,
-                iteration=now,
-            )
-            self.trace.span(
-                "spec_verify_phase",
-                ts1 - dt + t_draft,
-                ts1,
-                slots=len(live),
-                iteration=now,
-            )
-            self.trace.instant(
-                "spec_verify",
-                ts=ts1,
-                drafted=k * len(live),
-                accepted=accepted_total,
-                emitted=sum(len(e) for e in emits.values()),
-                iteration=now,
-            )
-        itl = m.histogram("engine.itl_ms")
-        for i in live:
-            s = self.slots[i]
-            itl.observe((ts1 - s.ts_last_token) * 1e3)
-            s.ts_last_token = ts1
-            if self.trace is not None:
-                for tkn in emits[i]:
-                    self.trace.instant(
-                        "token",
-                        track=obs_trace.req_track(s.req.rid),
-                        ts=ts1,
-                        rid=s.req.rid,
-                        token=tkn,
-                        iteration=now,
-                        **({"policy": s.policy_id} if s.policy_id else {}),
+        span, rec = obs_trace.span, self.trace
+        with span("engine.decode", rec):
+            n = self.ecfg.slots
+            toks = np.zeros((n, 1), np.int32)
+            pos = np.full((n,), -1, np.int32)
+            remaining = np.zeros((n,), np.int32)
+            for i in live:
+                s = self.slots[i]
+                toks[i, 0] = s.next_tok
+                pos[i] = s.next_pos
+                remaining[i] = s.req.max_new - len(s.gen)
+            with span("engine.launch", rec):
+                t0 = time.perf_counter()
+                if rec is not None:
+                    # two launches, fenced between, so the draft/verify
+                    # phase spans carry measured durations; acceptance,
+                    # truncation and rollback still run inside the verify
+                    # launch
+                    drafts, self.state = self._spec_draft(k)(
+                        self.draft_params,
+                        jnp.asarray(toks),
+                        jnp.asarray(pos),
+                        self.state,
                     )
-            if (
-                len(s.gen) >= s.req.max_new
-                or s.next_tok == self.ecfg.eos_id
-            ):
-                self._mark_done(i, now)
+                    jax.block_until_ready((drafts, self.state))
+                    t_draft = time.perf_counter() - t0
+                    targets, acc_arr, emit_arr, self.state = self._spec_verify(
+                        self.params,
+                        jnp.asarray(toks),
+                        drafts,
+                        jnp.asarray(pos),
+                        jnp.asarray(remaining),
+                        self.state,
+                    )
+                else:
+                    # traceless fast path: the whole round is ONE dispatch
+                    t_draft = 0.0
+                    targets, acc_arr, emit_arr, self.state = self._spec_fused(
+                        k
+                    )(
+                        self.params,
+                        self.draft_params,
+                        jnp.asarray(toks),
+                        jnp.asarray(pos),
+                        jnp.asarray(remaining),
+                        self.state,
+                    )
+                jax.block_until_ready((targets, acc_arr, emit_arr, self.state))
+                dt = time.perf_counter() - t0
+            with span("engine.sample", rec):
+                t_np = np.asarray(targets)
+                a_np = np.asarray(acc_arr)
+                e_np = np.asarray(emit_arr)
+        self._kv_drift_sample()
+        with span("engine.bookkeeping", rec):
+            m = self.metrics
+            emits: Dict[int, List[int]] = {}
+            accepted_total = 0
+            for i in live:
+                s = self.slots[i]
+                accepted_total += int(a_np[i])
+                s.spec_drafted += k
+                s.spec_accepted += int(a_np[i])
+                m.histogram("spec.accept_len").observe(float(a_np[i]))
+                emit = [int(x) for x in t_np[i, : e_np[i]]]
+                emits[i] = emit
+                s.gen.extend(emit)
+                s.next_tok = emit[-1]
+                s.next_pos += len(emit)
+            m.counter("engine.t_decode_s").inc(dt)
+            m.counter("engine.decode_steps").inc()
+            m.counter("engine.slot_steps").inc(len(live))
+            m.counter("engine.padded_slot_steps").inc(len(self._occupied()))
+            m.counter("spec.rounds").inc()
+            m.counter("spec.draft_tokens").inc(k * len(live))
+            m.counter("spec.accepted_tokens").inc(accepted_total)
+            m.gauge("engine.act_quant_reused").set(
+                getattr(self.adapter, "act_quant_reused", 0) - self._act_reuse_base
+            )
+            m.histogram("engine.decode_step_ms").observe(dt * 1e3)
+            obs_health.attribute_latency(m, "decode_attn", self.decode_attn_route, dt)
+            ts1 = rec.now() if rec is not None else time.perf_counter()
+            if rec is not None:
+                rec.span(
+                    "decode_step", ts1 - dt, ts1, slots=len(live), iteration=now
+                )
+                rec.span(
+                    "spec_draft",
+                    ts1 - dt,
+                    ts1 - dt + t_draft,
+                    slots=len(live),
+                    k=k,
+                    iteration=now,
+                )
+                rec.span(
+                    "spec_verify_phase",
+                    ts1 - dt + t_draft,
+                    ts1,
+                    slots=len(live),
+                    iteration=now,
+                )
+                rec.instant(
+                    "spec_verify",
+                    ts=ts1,
+                    drafted=k * len(live),
+                    accepted=accepted_total,
+                    emitted=sum(len(e) for e in emits.values()),
+                    iteration=now,
+                )
+            itl = m.histogram("engine.itl_ms")
+            for i in live:
+                s = self.slots[i]
+                itl.observe((ts1 - s.ts_last_token) * 1e3)
+                s.ts_last_token = ts1
+                if rec is not None:
+                    for tkn in emits[i]:
+                        rec.instant(
+                            "token",
+                            track=obs_trace.req_track(s.req.rid),
+                            ts=ts1,
+                            rid=s.req.rid,
+                            token=tkn,
+                            iteration=now,
+                            **({"policy": s.policy_id} if s.policy_id else {}),
+                        )
+                if (
+                    len(s.gen) >= s.req.max_new
+                    or s.next_tok == self.ecfg.eos_id
+                ):
+                    self._mark_done(i, now)
 
     # -- main loop ----------------------------------------------------------
     def step(self, now: int) -> bool:
         """One engine iteration: release a drained round (fixed policy),
         admit per policy, then decode. Returns False when there is nothing
         left to do."""
+        with obs_trace.span("engine.step", self.trace):
+            return self._step(now)
+
+    def _step(self, now: int) -> bool:
+        span, rec = obs_trace.span, self.trace
         if self.scheduler.hold_round:
             occ = self._occupied()
             if occ and all(self.slots[i].done for i in occ):
-                for i in occ:
-                    self._finish(i, now)
+                with span("engine.bookkeeping", rec):
+                    for i in occ:
+                        self._finish(i, now)
         if self.scheduler.has_pending():
-            if self.elastic is not None:
-                self._elastic_admission(now)
-            # paged KV: hand the scheduler the pool's worst-case obtainable
-            # pages so it defers (FIFO) rather than letting an admission
-            # race the pool into exhaustion mid-prefill
-            picks = self.scheduler.admit(
-                now,
-                self._free(),
-                len(self._occupied()),
-                page_budget=self.pool.available_count if self._paged else None,
-                page_need=self._pages_per_slot if self._paged else 0,
-                hold=self._swap_decision is not None,
-            )
+            with span("engine.schedule", rec):
+                if self.elastic is not None:
+                    self._elastic_admission(now)
+                # paged KV: hand the scheduler the pool's worst-case
+                # obtainable pages so it defers (FIFO) rather than letting
+                # an admission race the pool into exhaustion mid-prefill
+                picks = self.scheduler.admit(
+                    now,
+                    self._free(),
+                    len(self._occupied()),
+                    page_budget=(
+                        self.pool.available_count if self._paged else None
+                    ),
+                    page_need=self._pages_per_slot if self._paged else 0,
+                    hold=self._swap_decision is not None,
+                )
             for req, idx in picks:
                 self._admit(req, idx, now)
         if any(s is not None and not s.done for s in self.slots):
@@ -1574,10 +1602,11 @@ class DecodeEngine:
             pass  # held round finished at admission: released next tick
         elif not self.scheduler.has_pending():
             return False
-        self.metrics.counter("engine.iterations").inc()
-        self.monitor.check(self.metrics, self.trace)
-        if self.on_step is not None:
-            self.on_step(self.metrics)
+        with span("engine.monitor", rec):
+            self.metrics.counter("engine.iterations").inc()
+            self.monitor.check(self.metrics, self.trace)
+            if self.on_step is not None:
+                self.on_step(self.metrics)
         return True
 
     def run(self) -> Dict[int, Completion]:

@@ -510,34 +510,40 @@ def decode_attention(q: Array, cache, k_new: Array, v_new: Array,
     """
     out_dtype = v_new.dtype
     new = cache.append(k_new, v_new, pos)
-    pos32 = jnp.asarray(pos, jnp.int32)
+    with jax.named_scope("decode_attn"):
+        out = _decode_attend(q, new, jnp.asarray(pos, jnp.int32), window,
+                             k_new.dtype, out_dtype)
+    return out, new
+
+
+def _decode_attend(q: Array, new, pos32: Array, window: Optional[int],
+                   k_dtype, out_dtype) -> Array:
+    """The attend step of ``decode_attention`` over the appended cache, on
+    the resolved route."""
     if isinstance(new, qkv.PagedKVCache):
         from repro.runtime import dispatch
         route = dispatch.resolve_decode_attn()
         if route != "dequant-fp":
             out = _attend_paged_fused(q, new, pos32, window, route)
-            return out.astype(out_dtype), new
+            return out.astype(out_dtype)
         dense = new.gather()
-        k = qkv.dequantize(dense.k, dense.k_scale, k_new.dtype)
+        k = qkv.dequantize(dense.k, dense.k_scale, k_dtype)
         v = qkv.dequantize(dense.v, dense.v_scale, out_dtype)
-        out = _attend_rows(q, k, v, dense.pos, pos32, window)
-        return out, new
+        return _attend_rows(q, k, v, dense.pos, pos32, window)
     if isinstance(new, QuantKVCache):
         from repro.runtime import dispatch
         route = dispatch.resolve_decode_attn()
         if route != "dequant-fp":
             out = _attend_quant_fused(q, new, pos32, window, route)
-            return out.astype(out_dtype), new
-        k = qkv.dequantize(new.k, new.k_scale, k_new.dtype)
+            return out.astype(out_dtype)
+        k = qkv.dequantize(new.k, new.k_scale, k_dtype)
         v = qkv.dequantize(new.v, new.v_scale, out_dtype)
     else:
         k, v = new.k, new.v
     if new.pos.ndim == 2:
-        out = _attend_rows(q, k, v, new.pos, pos32, window)
-    else:
-        out = direct_attention(q, k, v, pos32[None], new.pos, causal=True,
-                               window=window)
-    return out, new
+        return _attend_rows(q, k, v, new.pos, pos32, window)
+    return direct_attention(q, k, v, pos32[None], new.pos, causal=True,
+                            window=window)
 
 
 def verify_attention(q: Array, cache, k_new: Array, v_new: Array,
@@ -560,11 +566,20 @@ def verify_attention(q: Array, cache, k_new: Array, v_new: Array,
     S query positions onto the exact one-token kernel program (S = k + 1,
     small and static) so the whole verify remains one launch.
     """
-    from repro.runtime import dispatch
     out_dtype = v_new.dtype
-    S = q.shape[1]
     pos32 = jnp.asarray(pos, jnp.int32)
     new = cache.append_batch(k_new, v_new, pos32)
+    with jax.named_scope("decode_attn"):
+        out = _verify_attend(q, new, pos32, window, k_new.dtype, out_dtype)
+    return out, new
+
+
+def _verify_attend(q: Array, new, pos32: Array, window: Optional[int],
+                   k_dtype, out_dtype) -> Array:
+    """The attend step of ``verify_attention``: each of the S queries
+    through the single-token primitive of the resolved route."""
+    from repro.runtime import dispatch
+    S = q.shape[1]
     paged = isinstance(new, qkv.PagedKVCache)
     quant = isinstance(new, QuantKVCache)
     route = dispatch.resolve_decode_attn() if (paged or quant) \
@@ -581,17 +596,17 @@ def verify_attention(q: Array, cache, k_new: Array, v_new: Array,
             out = ops.verify_attn_quant(
                 q, new.k, new.k_scale, new.v, new.v_scale, new.pos, pos32,
                 window=window, interpret=interp)
-        return out.astype(out_dtype), new
+        return out.astype(out_dtype)
     dense = new.gather() if paged else new
     assert dense.pos.ndim == 2, "verify_attention is per-slot only"
     if isinstance(dense, QuantKVCache):
-        k = qkv.dequantize(dense.k, dense.k_scale, k_new.dtype)
+        k = qkv.dequantize(dense.k, dense.k_scale, k_dtype)
         v = qkv.dequantize(dense.v, dense.v_scale, out_dtype)
     else:
         k, v = dense.k, dense.v
     outs = [_attend_rows(q[:, j:j + 1], k, v, dense.pos, pos32[:, j], window)
             for j in range(S)]
-    return jnp.concatenate(outs, axis=1), new
+    return jnp.concatenate(outs, axis=1)
 
 
 def append_attention(q: Array, cache, k_new: Array, v_new: Array,

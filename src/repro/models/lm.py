@@ -420,6 +420,13 @@ def _bget(bits, *path):
     return bits
 
 
+def _proj(eqn: str, x: Array, p, bits, key: str, ctx) -> Array:
+    """The projection ``key`` of a layer, under a named scope of its policy
+    key (``wq`` ... ``mlp_wo``), so each of its device ops names its site."""
+    with jax.named_scope(key):
+        return qeinsum(eqn, x, p[key], _bget(bits, key), ctx)
+
+
 def _attn_window(cfg: ModelConfig, kind: str) -> Optional[int]:
     if cfg.family == "hybrid":
         return cfg.local_window or None
@@ -443,7 +450,7 @@ def _attn_sublayer(x, p, bits, cfg: ModelConfig, ctx, axes: MeshAxes, kind: str,
     h = apply_norm(x, p["norm1"], cfg.norm_type, cfg.norm_eps)
     h = axes.shard(h, "dp", "sp", None)
 
-    q = qeinsum("bsd,de->bse", h, p["wq"], _bget(bits, "wq"), ctx)
+    q = _proj("bsd,de->bse", h, p, bits, "wq", ctx)
     q = q.reshape(B, S, H, hd)
 
     if is_cross:
@@ -452,8 +459,8 @@ def _attn_sublayer(x, p, bits, cfg: ModelConfig, ctx, axes: MeshAxes, kind: str,
             new_state = state
         else:
             hk = img_x
-            k = qeinsum("bnd,de->bne", hk, p["wk"], _bget(bits, "wk"), ctx)
-            v = qeinsum("bnd,de->bne", hk, p["wv"], _bget(bits, "wv"), ctx)
+            k = _proj("bnd,de->bne", hk, p, bits, "wk", ctx)
+            v = _proj("bnd,de->bne", hk, p, bits, "wv", ctx)
             k = k.reshape(B, -1, KV, hd)
             v = v.reshape(B, -1, KV, hd)
             k = axes.shard(k, "dp", None, "th", None)
@@ -465,8 +472,8 @@ def _attn_sublayer(x, p, bits, cfg: ModelConfig, ctx, axes: MeshAxes, kind: str,
             q = _qk_rms(q, p["q_norm"], cfg.norm_eps)
         out = attn.cross_attention(q, k, v)
     else:
-        k = qeinsum("bsd,de->bse", h, p["wk"], _bget(bits, "wk"), ctx)
-        v = qeinsum("bsd,de->bse", h, p["wv"], _bget(bits, "wv"), ctx)
+        k = _proj("bsd,de->bse", h, p, bits, "wk", ctx)
+        v = _proj("bsd,de->bse", h, p, bits, "wv", ctx)
         k = k.reshape(B, S, KV, hd)
         v = v.reshape(B, S, KV, hd).astype(ctx.compute_dtype)
         # pin the post-reshape layout to a per-dim spec: the projection
@@ -560,7 +567,7 @@ def _attn_sublayer(x, p, bits, cfg: ModelConfig, ctx, axes: MeshAxes, kind: str,
         out = axes.shard(out, "dp", None, "th", None)
 
     out = out.reshape(B, S, H * hd)
-    out = qeinsum("bse,ed->bsd", out, p["wo"], _bget(bits, "wo"), ctx)
+    out = _proj("bse,ed->bsd", out, p, bits, "wo", ctx)
     if is_cross:
         out = out * jnp.tanh(p["gate_attn"]).astype(out.dtype)
     return x + out, new_state
@@ -570,14 +577,14 @@ def _mlp_sublayer(x, p, bits, cfg: ModelConfig, ctx, axes: MeshAxes,
                   gate_key: Optional[str] = None):
     h = apply_norm(x, p["norm2"], cfg.norm_type, cfg.norm_eps)
     h = axes.shard(h, "dp", "sp", None)
-    hi = qeinsum("bsd,df->bsf", h, p["mlp_wi"], _bget(bits, "mlp_wi"), ctx)
+    hi = _proj("bsd,df->bsf", h, p, bits, "mlp_wi", ctx)
     if cfg.mlp_gated:
-        hg = qeinsum("bsd,df->bsf", h, p["mlp_wg"], _bget(bits, "mlp_wg"), ctx)
+        hg = _proj("bsd,df->bsf", h, p, bits, "mlp_wg", ctx)
         hi = activation(cfg.act)(hg) * hi
     else:
         hi = activation(cfg.act)(hi)
     hi = axes.shard(hi, "dp", None, "tp")
-    out = qeinsum("bsf,fd->bsd", hi, p["mlp_wo"], _bget(bits, "mlp_wo"), ctx)
+    out = _proj("bsf,fd->bsd", hi, p, bits, "mlp_wo", ctx)
     if gate_key is not None:
         out = out * jnp.tanh(p[gate_key]).astype(out.dtype)
     return x + out
@@ -692,6 +699,7 @@ def run_layers(x: Array, params, bits, cfg: ModelConfig, ctx: QuantContext,
     return x, new_states, aux
 
 
+@jax.named_scope("lm_head")
 def lm_head(x: Array, params, cfg: ModelConfig, ctx: QuantContext,
             axes: MeshAxes) -> Array:
     x = apply_norm(x, params["final_norm"], cfg.norm_type, cfg.norm_eps)
@@ -701,8 +709,10 @@ def lm_head(x: Array, params, cfg: ModelConfig, ctx: QuantContext,
         if ctx.enabled:
             qmin, qmax = bit_range(8, True)
             g = lsq_grad_scale_factor(w.size, qmax)
-            w = fake_quant(w.astype(jnp.float32), params["embed"]["s_w8"],
-                           qmin, qmax, grad_scale_factor=g)
+            # the tied head fake-quantizes the whole table in every launch
+            with jax.named_scope("head_fake_quant"):
+                w = fake_quant(w.astype(jnp.float32), params["embed"]["s_w8"],
+                               qmin, qmax, grad_scale_factor=g)
         logits = jnp.einsum("bsd,vd->bsv", x.astype(ctx.compute_dtype),
                             w.astype(ctx.compute_dtype))
     else:

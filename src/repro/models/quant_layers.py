@@ -185,6 +185,9 @@ def embed_lookup_pinned(tokens: Array, p, ctx: QuantContext) -> Array:
     if ctx.enabled:
         qmin, qmax = bit_range(8, True)
         g = lsq_grad_scale_factor(w.size, qmax)
-        w = fake_quant(w.astype(jnp.float32), p["s_w8"], qmin, qmax,
-                       grad_scale_factor=g)
+        # a tied head fake-quantizes the same table: XLA computes it once,
+        # under this scope
+        with jax.named_scope("embed_fake_quant"):
+            w = fake_quant(w.astype(jnp.float32), p["s_w8"], qmin, qmax,
+                           grad_scale_factor=g)
     return jnp.take(w.astype(ctx.compute_dtype), tokens, axis=0)

@@ -20,6 +20,15 @@ Two interchangeable export formats (``serve --trace-out``):
   event fields ride in ``args`` so the two formats round-trip
   losslessly.
 
+Both exports carry ``epoch_unix_ns``, the wall clock (``time.time_ns``) at
+the recorder's epoch. The profiler stamps its host events on the same wall
+clock (an xplane's ``profile_start_time`` plus the event's offset), so
+``unix_ns(ts)`` lays a recorded event over a ``jax.profiler`` trace.
+
+``span(name, recorder)`` is the engine's host span: always a
+``jax.profiler.TraceAnnotation`` (free when no profiler runs), and also a
+recorder span when the engine's trace is on.
+
 ``reconcile`` cross-checks a trace against an ``EngineStats.as_dict()``
 snapshot — the serve smoke's proof that the trace and the counters
 describe the same run (decode-span time within tolerance of
@@ -28,10 +37,13 @@ request closed out in order).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import time
 from typing import Any, Dict, List, Optional, Union
+
+import jax
 
 TRACE_SCHEMA_VERSION = 1
 
@@ -70,14 +82,20 @@ def req_track(rid: int) -> str:
 
 
 class TraceRecorder:
-    """Append-only event recorder with a ``perf_counter`` epoch."""
+    """Append-only event recorder with a ``perf_counter`` epoch, anchored to
+    the wall clock by ``epoch_unix_ns``."""
 
     def __init__(self):
         self.events: List[TraceEvent] = []
         self._epoch = time.perf_counter()
+        self.epoch_unix_ns = time.time_ns()
 
     def now(self) -> float:
         return time.perf_counter() - self._epoch
+
+    def unix_ns(self, ts: float) -> int:
+        """Wall-clock nanoseconds of a recorder timestamp."""
+        return self.epoch_unix_ns + round(ts * 1e9)
 
     def instant(self, name: str, track: str = ENGINE_TRACK,
                 ts: Optional[float] = None, **args) -> TraceEvent:
@@ -97,7 +115,8 @@ class TraceRecorder:
     # -- JSONL ---------------------------------------------------------------
     def to_jsonl(self, path: str) -> None:
         with open(path, "w") as f:
-            f.write(json.dumps({"schema": TRACE_SCHEMA_VERSION}) + "\n")
+            f.write(json.dumps({"schema": TRACE_SCHEMA_VERSION,
+                                "epoch_unix_ns": self.epoch_unix_ns}) + "\n")
             for ev in self.events:
                 f.write(json.dumps(dataclasses.asdict(ev), sort_keys=True)
                         + "\n")
@@ -109,6 +128,7 @@ class TraceRecorder:
             header = json.loads(f.readline())
             if header.get("schema") != TRACE_SCHEMA_VERSION:
                 raise ValueError(f"unknown trace schema {header!r}")
+            rec.epoch_unix_ns = header.get("epoch_unix_ns", rec.epoch_unix_ns)
             for line in f:
                 rec.events.append(TraceEvent(**json.loads(line)))
         return rec
@@ -137,7 +157,8 @@ class TraceRecorder:
         return {"traceEvents": meta + events,
                 "displayTimeUnit": "ms",
                 "metadata": {"schema": TRACE_SCHEMA_VERSION,
-                             "source": "repro.obs.trace"}}
+                             "source": "repro.obs.trace",
+                             "epoch_unix_ns": self.epoch_unix_ns}}
 
     def write_chrome(self, path: str) -> None:
         with open(path, "w") as f:
@@ -153,6 +174,8 @@ class TraceRecorder:
         if not isinstance(obj, dict) or "traceEvents" not in obj:
             raise ValueError("not a Chrome trace: no traceEvents")
         rec = cls()
+        rec.epoch_unix_ns = obj.get("metadata", {}).get("epoch_unix_ns",
+                                                        rec.epoch_unix_ns)
         for ce in obj["traceEvents"]:
             if ce.get("ph") == "M":
                 continue
@@ -169,6 +192,22 @@ class TraceRecorder:
             self.to_jsonl(path)
         else:
             self.write_chrome(path)
+
+
+@contextlib.contextmanager
+def span(name: str, recorder: Optional[TraceRecorder] = None):
+    """A host span named ``name`` on the profiler's clock, recorded in
+    ``recorder`` too when one is given. Pass a static name: it is built on
+    the hot path whether or not a profiler runs."""
+    with jax.profiler.TraceAnnotation(name):
+        if recorder is None:
+            yield
+            return
+        t0 = recorder.now()
+        try:
+            yield
+        finally:
+            recorder.span(name, t0, recorder.now())
 
 
 # ---------------------------------------------------------------------------

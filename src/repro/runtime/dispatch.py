@@ -386,7 +386,8 @@ def _replicate(mesh, a: Array) -> Array:
 
 
 def _impl_dequant_fp(eqn: str, x: Array, pl: PackedLinear, ctx) -> Array:
-    xq = act_fake_quant(x, pl, ctx).astype(ctx.compute_dtype)
+    with jax.named_scope("act_codes"):
+        xq = act_fake_quant(x, pl, ctx).astype(ctx.compute_dtype)
     axes = _AXES[-1]
     if axes is not None and pl.shard_count > 1:
         # Gather the *packed* codes — the cheapest form on the wire, and
@@ -412,9 +413,12 @@ def _impl_dequant_fp(eqn: str, x: Array, pl: PackedLinear, ctx) -> Array:
         # 0.4.37 CPU partitioner re-tiles the packed-stream reshapes and
         # produces wrong slabs (only when the chain stays internal to a
         # larger jit; any materialization hides it)
-        w = jax.lax.optimization_barrier(pl.dequant(ctx.compute_dtype))
+        with jax.named_scope("dequant"):
+            w = jax.lax.optimization_barrier(pl.dequant(ctx.compute_dtype))
         return row_einsum(eqn, xq, w)
-    return row_einsum(eqn, xq, pl.dequant(ctx.compute_dtype))
+    with jax.named_scope("dequant"):
+        w = pl.dequant(ctx.compute_dtype)
+    return row_einsum(eqn, xq, w)
 
 
 def _scalar_scale(pl: PackedLinear) -> Array:
@@ -422,9 +426,11 @@ def _scalar_scale(pl: PackedLinear) -> Array:
 
 
 def _kernel_call(eqn, x, pl, ctx, matmul):
-    xq, s_x = act_codes(x, pl, ctx)
+    with jax.named_scope("act_codes"):
+        xq, s_x = act_codes(x, pl, ctx)
     m2 = xq.reshape(-1, xq.shape[-1])
-    out = matmul(m2, s_x)
+    with jax.named_scope("kernel"):
+        out = matmul(m2, s_x)
     return out.reshape(x.shape[:-1] + (out.shape[-1],)).astype(
         ctx.compute_dtype)
 

@@ -244,8 +244,10 @@ class PackedLinear:
                 and (self.shape[-2] // self.shard_count) % _PACK_MULT[
                     self.layout] != 0)
 
+    @jax.named_scope("unpack")
     def unpack(self) -> Array:
-        """Exact signed integer codes in the weight's original shape."""
+        """Exact signed integer codes in the weight's original shape, under
+        the named scope ``unpack`` (every route's unpack, traced)."""
         n = int(np.prod(self.shape))
         if self.layout == "int8":
             return self.codes

@@ -288,11 +288,14 @@ class QuantizedSession:
             for site in self.sites:
                 key = _site_key(site.gidx)
                 st = None if states is None else states["sites"].get(key)
-                x, st, _ = lm.apply_layer(
-                    site.kind, x, params["sites"][key], self._site_bits[key],
-                    self.cfg, self.ctx, self.compute_axes, mode=mode,
-                    state=st, pos=pos, img_x=img_x, prefill_cap=prefill_cap,
-                    slot=slot)
+                # the scope is the site's policy-key prefix (``L027``), so
+                # a device op's name reads ``L027/mlp_wo/...``
+                with jax.named_scope(f"L{key}"):
+                    x, st, _ = lm.apply_layer(
+                        site.kind, x, params["sites"][key],
+                        self._site_bits[key], self.cfg, self.ctx,
+                        self.compute_axes, mode=mode, state=st, pos=pos,
+                        img_x=img_x, prefill_cap=prefill_cap, slot=slot)
                 new_states["sites"][key] = st
         # trace-time count: quantize ops elided from this compiled graph
         self.act_quant_reused += scope["hits"]

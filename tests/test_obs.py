@@ -246,6 +246,30 @@ def test_engine_stats_snapshot_and_latency(served):
         f"engine.decode_attn_route.{eng.decode_attn_route}") == 1.0
 
 
+def test_engine_ttft_counts_the_queue_wait(served):
+    """TTFT runs from ``submit`` to the first token: with one slot, the
+    second request waits out the first's whole service, which the fenced
+    prefill alone (``engine.prefill_ms``) does not see."""
+    cfg = served["cfg"]
+    ctx = QuantContext.make(cfg.bits, cfg.quant_act_signed,
+                            compute_dtype=jnp.float32)
+    eng = DecodeEngine(served["eng"].params, cfg, lm.bits_uniform(cfg, 4),
+                       ctx, NO_AXES, EngineConfig(slots=1, cache_len=24))
+    reqs = [Request(rid=i, tokens=np.arange(1, 7, dtype=np.int32) + i,
+                    max_new=4) for i in range(2)]
+    eng.submit_all(reqs)
+    eng.run()
+    m = eng.metrics
+    ttft = m.get("engine.ttft_ms").as_dict()
+    prefill = m.get("engine.prefill_ms").as_dict()
+    decode = m.get("engine.decode_step_ms").as_dict()
+    assert m.get("engine.ttft_ms").count == 2
+    # the first request's prefill and three decode launches, then the
+    # second's own prefill
+    assert ttft["max"] >= 2 * prefill["min"] + 3 * decode["min"]
+    assert m.get("engine.ttft_ms").sum > m.get("engine.prefill_ms").sum
+
+
 def test_engine_reset_starts_fresh_epoch(served):
     eng = served["eng"]
     old_stats = eng.stats

@@ -9,6 +9,7 @@ described inside a fixture so that only the worker running this file loads
 the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -98,5 +99,9 @@ def test_kernel_compiles_for_v5e(one_chip, kernel):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     fn, args = _cases(spec)[kernel]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the custom call takes its kernel's name (``pallas_call(name=...)``);
+    # verify attention unrolls onto the decode kernel
+    name = {"verify_attn_quant": "decode_attn_quant"}.get(kernel, kernel)
+    assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call\(", text)
