@@ -125,7 +125,8 @@ def serve_and_check_routes(policy_path, extra):
     eng, completions = serve.main(SERVE + ["--policy", policy_path] + extra)
     routes = serve.route_counts(eng.metrics)
     kernels = [r for r in ("dispatch.route.pallas-int8",
-                           "dispatch.route.pallas-w4") if routes.get(r)]
+                           "dispatch.route.pallas-w4",
+                           "dispatch.route.pallas-planes") if routes.get(r)]
     if not kernels:
         fail(f"no Pallas matmul route ran: {routes}")
     if eng.stats.decode_attn_route != "fused" \

@@ -211,13 +211,12 @@ def packed_specs(cfg: ModelConfig, params, axes: MeshAxes):
     bit metadata stays aux data, outside the spec tree) built from the
     *original* projection's partition rule:
 
-    * ``codes`` shard along the packed counterpart of the weight's
-      tensor-parallel dim — the same dim for the row layouts, axis 0 of
-      the flat stream for ``bitstream``. A leaf is only sharded when it
-      was packed per-shard for this mesh degree (or its layout is
-      byte-per-code / packed off the shard dim, where plain packing is
-      already per-shard exact); anything else replicates rather than
-      splitting a byte mid-shard.
+    * ``codes`` shard along the weight's tensor-parallel dim (every
+      layout packs along the contraction dim and keeps the others). A leaf
+      is only sharded when it was packed per-shard for this mesh degree
+      (or its layout is byte-per-code / packed off the shard dim, where
+      plain packing is already per-shard exact); anything else replicates
+      rather than splitting a byte or a plane block mid-shard.
     * ``scale`` follows the out-dim: sharded for column-parallel layers
       (per-channel ``(out,)``) and expert-parallel stacks (``(E, 1, 1)``),
       replicated for row-parallel ones (their per-channel scale spans the
@@ -246,10 +245,9 @@ def packed_specs(cfg: ModelConfig, params, axes: MeshAxes):
         s_a = _replicate(leaf.s_a.ndim)
         if d is not None and n > 1:
             per_shard = leaf.shard_dim == d and leaf.shard_count == n
-            if leaf.layout == "bitstream":
-                if per_shard:
-                    codes = P(ax)
-            elif per_shard or leaf.codes.shape[d] % n == 0:
+            # plain plane blocks span the whole contraction dim
+            plain_ok = not (leaf.layout == "planes" and d == rank - 2)
+            if per_shard or (plain_ok and leaf.codes.shape[d] % n == 0):
                 codes = _shard_dim(leaf.codes.ndim, d, ax)
             if (leaf.scale.ndim == 1 and d == rank - 1
                     and leaf.scale.shape[0] % n == 0):

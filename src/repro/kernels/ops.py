@@ -72,6 +72,16 @@ def quant_matmul_w4(x_q, w_p, s_x, s_w, *, k=None, blocks=_qmm.DEFAULT_BLOCKS):
                                 interpret=_interpret_default())
 
 
+def quant_matmul_planes(x_q, w_p, s_x, s_w, *, bits: int,
+                        blocks=_qmm.DEFAULT_BLOCKS):
+    """(M,K) int8 x (bits*ceil(K/8),N) uint8 bit planes -> (M,N) f32.
+    The weight's bit planes unpack in the kernel's VMEM prologue."""
+    return _qmm.quant_matmul_planes(x_q, w_p, jnp.asarray(s_x, jnp.float32),
+                                    jnp.asarray(s_w, jnp.float32), bits=bits,
+                                    blocks=blocks,
+                                    interpret=_interpret_default())
+
+
 def quantize_int8(v, s, bits: int = 8):
     """Round v/s to the signed `bits`-wide integer grid, stored as int8."""
     qmax = 2 ** (bits - 1) - 1

@@ -11,6 +11,11 @@ Grid is (M/bm, N/bn, K/bk) with the K dimension sequential ("arbitrary"):
 an f32 VMEM scratch accumulates partial products across K steps and the
 epilogue fires on the last step. 128-aligned tiles keep the MXU full.
 
+Two variants take packed weight bytes and unpack them in VMEM, so HBM sees
+the packed bytes only: ``quant_matmul_w4`` (``nib4``, two codes a byte) and
+``quant_matmul_planes`` (``planes``, b bit planes of 8 codes a byte, for
+b = 3, 5, 6, 7).
+
 Numerics contract (tested): out == (q_x * s_x) @ (q_w * s_w) exactly in f32
 for shapes where K * 127^2 < 2^31 (int32 accumulation, always true here).
 """
@@ -113,6 +118,116 @@ def quant_matmul_w4(x_q, w_p, s_x, s_w, *, k=None, blocks=DEFAULT_BLOCKS,
         interpret=interpret,
     )(x_q, w_p, s_x.reshape(1, 1), s_w.reshape(1, 1))
     return out[:M, :N]
+
+
+def _qmm_planes_kernel(x_ref, *refs, bits, k_steps):
+    """int8 x bit-plane matmul: the weight block arrives as ``bits`` plane
+    blocks of ``(bk/8, bn)`` bytes (``runtime.packing.pack_planes``; bit s
+    of a byte = K-row 8g + s) and unpacks in the VMEM prologue, plane rows
+    regrouped by bit position: unpacked row ``s * bk/8 + g`` is K-row
+    ``8g + s``, the order the wrapper gave the activation's columns."""
+    plane_refs = refs[:bits]
+    sx_ref, sw_ref, o_ref, acc_ref = refs[bits:]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    planes = [r[...].astype(jnp.int32) for r in plane_refs]
+    bk8, bn = planes[0].shape
+    # one (8, bk/8, bn) pass a plane: leading index s takes bit s
+    s = jax.lax.broadcasted_iota(jnp.int32, (8, bk8, bn), 0)
+    u = functools.reduce(jnp.bitwise_or, [((p[None] >> s) & 1) << j
+                                          for j, p in enumerate(planes)])
+    qmin = -(1 << (bits - 1))                    # codes are offset-binary
+    w = (u.reshape(8 * bk8, bn) + qmin).astype(jnp.int8)
+
+    acc_ref[...] += jax.lax.dot_general(
+        x_ref[...], w,
+        (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32,
+    )
+
+    @pl.when(pl.program_id(2) == k_steps - 1)
+    def _epilogue():
+        scale = sx_ref[0, 0] * sw_ref[0, 0]
+        o_ref[...] = acc_ref[...].astype(jnp.float32) * scale
+
+
+def _tile(dim: int, cap: int, quantum: int):
+    """The largest multiple of ``quantum`` up to ``cap`` dividing ``dim``."""
+    t = min(cap, dim) // quantum * quantum
+    while t > 0 and dim % t:
+        t -= quantum
+    return t or None
+
+
+# a TPU tiles one-byte arrays (32, 128): a plane block of bk/8 rows needs bk
+# a multiple of 256, and bn of 128
+_PLANES_QUANTA = (256, 128)
+
+
+def planes_tiles(k: int, n: int, blocks=DEFAULT_BLOCKS):
+    """(bk, bn) of the planes kernel for a (k, n) weight: bk divides K
+    padded to whole 8-row groups, bn divides N, so the plane bytes are never
+    padded per call. Multiples of the TPU's one-byte tile where such tiles
+    exist, else of 8 rows and 1 lane (the interpreter takes any)."""
+    kp = 8 * -(-k // 8)
+    bk = _tile(kp, blocks[2], _PLANES_QUANTA[0]) or _tile(kp, blocks[2], 8)
+    bn = _tile(n, blocks[1], _PLANES_QUANTA[1]) or _tile(n, blocks[1], 1)
+    return bk, bn
+
+
+def planes_tiled(k: int, n: int) -> bool:
+    """True where the planes kernel's tiles for a (k, n) weight fit the
+    TPU's one-byte tile."""
+    bk, bn = planes_tiles(k, n)
+    return bk % _PLANES_QUANTA[0] == 0 and bn % _PLANES_QUANTA[1] == 0
+
+
+def quant_matmul_planes(x_q, w_p, s_x, s_w, *, bits: int,
+                        blocks=DEFAULT_BLOCKS, interpret: bool = False):
+    """x_q: (M, K) int8; w_p: (bits * ceil(K/8), N) uint8 bit planes
+    (``runtime.packing.pack_planes`` layout); scalar scales -> (M, N) f32.
+
+    The same 2-D plane array is every weight operand, one BlockSpec per
+    plane. Within each K tile the activation's columns are regrouped by
+    bit position to match the order the kernel unpacks weight rows in; the
+    weight bytes are used as stored.
+    """
+    M, K = x_q.shape
+    rows, N = w_p.shape
+    kp = 8 * -(-K // 8)
+    assert rows == bits * (kp // 8), (x_q.shape, w_p.shape, bits)
+    bk, bn = planes_tiles(K, N, blocks)
+    bm = min(blocks[0], M)
+    pm = (-M) % bm
+    if pm or kp > K:
+        x_q = jnp.pad(x_q, ((0, pm), (0, kp - K)))   # zero codes
+    Mp = M + pm
+    x_q = x_q.reshape(Mp, kp // bk, bk // 8, 8).swapaxes(2, 3).reshape(
+        Mp, kp)
+    k_steps = kp // bk
+    grid = (Mp // bm, N // bn, k_steps)
+    planes = [pl.BlockSpec((bk // 8, bn),
+                           lambda i, j, kk, b=b: (b * k_steps + kk, j))
+              for b in range(bits)]
+
+    out = pl.pallas_call(
+        functools.partial(_qmm_planes_kernel, bits=bits, k_steps=k_steps),
+        grid=grid,
+        in_specs=[pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk))]
+        + planes + [
+            pl.BlockSpec((1, 1), lambda i, j, kk: (0, 0)),
+            pl.BlockSpec((1, 1), lambda i, j, kk: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((Mp, N), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        name="quant_matmul_planes",
+        interpret=interpret,
+    )(x_q, *([w_p] * bits), s_x.reshape(1, 1), s_w.reshape(1, 1))
+    return out[:M]
 
 
 def quant_matmul(x_q, w_q, s_x, s_w, blocks=DEFAULT_BLOCKS,

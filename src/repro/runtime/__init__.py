@@ -3,12 +3,13 @@
 Compiles a searched ``MPQPolicy`` into a deployable quantized model:
 
 * ``packing``  — quantize weights onto the searched per-layer grid and
-  bit-pack sub-8-bit codes (int4 two-per-byte, int2 four-per-byte, generic
-  bitstream otherwise) with per-channel or per-tensor scales, plus exact
-  unpack. ``PackedLinear`` is the packed param-tree leaf.
+  bit-pack sub-8-bit codes (int4 two-per-byte, int2 four-per-byte, bit
+  planes of eight codes a byte otherwise) with per-channel or per-tensor
+  scales, plus exact unpack. ``PackedLinear`` is the packed param-tree leaf.
 * ``dispatch`` — per-layer kernel registry keyed by bit-width/shape that
-  routes packed matmuls to the Pallas int8/int4 kernels, falling back to
-  an exact dequant-then-fp einsum for shapes the kernels can't tile.
+  routes packed matmuls to the Pallas int8/int4/bit-plane kernels, falling
+  back to an exact dequant-then-fp einsum for shapes the kernels can't
+  tile.
 * ``kv_cache`` — int8 per-slot KV quantization (per-head write-time
   scales) integrated into ``models.attention.decode_attention`` behind the
   ``QuantContext.kv_quant`` flag.

@@ -7,10 +7,15 @@ every call site resolves — at trace time — which execution route serves it:
   ``kernels.quant_matmul.quant_matmul_w4`` directly: the packed bytes are
   the kernel operand and nibbles unpack in the VMEM prologue (HBM never
   sees unpacked codes).
+* ``pallas-planes`` — 3-, 5-, 6- and 7-bit weights in the ``planes``
+  layout feed ``kernels.quant_matmul.quant_matmul_planes`` directly: the
+  bit-plane bytes unpack in its VMEM prologue, as nib4 bytes do in the w4
+  kernel.
 * ``pallas-int8`` — any searched width ≤ 8 lands on a subset of the int8
   grid: codes unpack via XLA, activations quantize on the fly, and the
   matmul runs int8 x int8 -> int32 on the MXU
-  (``kernels.quant_matmul.quant_matmul``).
+  (``kernels.quant_matmul.quant_matmul``). It serves 8-bit and 2-bit
+  weights, and packed layouts the kernels above cannot tile.
 * ``dequant-fp``  — exact fallback for everything the kernels can't tile
   (stacked MoE expert einsums, row-parallel ``(N,K)`` weight orientation,
   per-channel scales, odd contraction dims): dequantize the codes and run
@@ -57,6 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.quantizer import fake_quant, lsq_grad_scale_factor
+from repro.kernels.quant_matmul import planes_tiled
 from repro.models.quant_layers import row_einsum
 from repro.runtime.packing import PackedLinear
 
@@ -115,7 +121,7 @@ class RouteTable:
 
 
 ROUTES = RouteTable({
-    "matmul": ("dequant-fp", "pallas-int8", "pallas-w4"),
+    "matmul": ("dequant-fp", "pallas-int8", "pallas-w4", "pallas-planes"),
     "decode_attn": ("fused", "fused-interpret", "dequant-fp"),
     "kv_layout": ("ring", "paged"),
     # the flash-attention forward of prefill and training: the Pallas
@@ -453,10 +459,20 @@ def _impl_pallas_w4(eqn: str, x: Array, pl: PackedLinear, ctx) -> Array:
                                             k=pl.shape[-2]))
 
 
+def _impl_pallas_planes(eqn: str, x: Array, pl: PackedLinear, ctx) -> Array:
+    from repro.kernels import ops
+    return _kernel_call(
+        eqn, x, pl, ctx,
+        lambda m2, s_x: ops.quant_matmul_planes(m2, pl.codes, s_x,
+                                                _scalar_scale(pl),
+                                                bits=pl.w_bits))
+
+
 REGISTRY: Dict[str, Callable] = {
     "dequant-fp": _impl_dequant_fp,
     "pallas-int8": _impl_pallas_int8,
     "pallas-w4": _impl_pallas_w4,
+    "pallas-planes": _impl_pallas_planes,
 }
 
 
@@ -476,6 +492,11 @@ def kernel_eligible(eqn: str, pl: PackedLinear) -> Optional[str]:
         # the w4 kernel consumes the PLAIN nib4 byte stream; a per-shard
         # re-broken layout (odd per-shard rows) must go through unpack
         return "pallas-w4"
+    if (pl.layout == "planes" and not pl.sharded_layout()
+            and planes_tiled(*pl.shape)):
+        # the planes kernel reads the plane bytes as stored: they must
+        # tile as they are, else the codes unpack in XLA
+        return "pallas-planes"
     if pl.w_bits <= 8:
         return "pallas-int8"
     return None

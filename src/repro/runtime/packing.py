@@ -5,16 +5,21 @@ projection is quantized onto its per-layer b-bit signed grid with the exact
 rounding of the fake-quant training graph (``round(clip(w/s, qmin, qmax))``
 with ``s = max(s, 1e-9)``), and the integer codes are bit-packed so HBM
 holds ``ceil(n * b / 8)`` bytes — matching ``MPQPolicy.size_bytes`` to
-within padding. Three storage layouts:
+within padding. Four storage layouts:
 
-* ``int8``      — b == 8: codes stored as int8 in the weight's own shape.
-* ``nib4``      — b == 4: two codes per byte along the contraction dim
-                  (``codes[k//2, n]``; low nibble = even k). This is the
-                  layout the ``kernels.quant_matmul.quant_matmul_w4``
-                  unpack-in-VMEM prologue consumes directly.
-* ``quad2``     — b == 2: four codes per byte along the contraction dim.
-* ``bitstream`` — any other b (3, 5, 6): little-endian bitstream over the
-                  row-major flattened codes, 1-D uint8.
+* ``int8``   — b == 8: codes stored as int8 in the weight's own shape.
+* ``nib4``   — b == 4: two codes per byte along the contraction dim
+               (``codes[k//2, n]``; low nibble = even k). This is the
+               layout the ``kernels.quant_matmul.quant_matmul_w4``
+               unpack-in-VMEM prologue consumes directly.
+* ``quad2``  — b == 2: four codes per byte along the contraction dim.
+* ``planes`` — any other b (3, 5, 6, 7): bit planes along the contraction
+               dim. Bit ``j`` of the codes of rows ``8g .. 8g+7`` fills byte
+               ``codes[j * ceil(K/8) + g, n]`` (bit ``s`` = row ``8g + s``):
+               ``b`` plane blocks of ``ceil(K/8)`` rows each, one 2-D array
+               for a 2-D weight, N on the lanes. The
+               ``kernels.quant_matmul.quant_matmul_planes`` prologue unpacks
+               it in VMEM.
 
 Codes are stored offset-binary (``u = q - qmin``) so packed bytes are
 unsigned; ``unpack_*`` restores the signed grid exactly (round-trip is
@@ -23,17 +28,15 @@ property-tested in tests/test_runtime.py for odd channel counts).
 Tensor-parallel serving packs *per shard*: ``pack_linear(...,
 shard_dim=d, shard_count=n)`` splits the weight into ``n`` equal shards
 along its original tensor-parallel dim and packs each shard independently
-(each padded to its own byte/word boundary), then concatenates the shard
-layouts back along the packed counterpart of ``d``. The result is
-bit-identical, shard for shard, to packing each shard on its own — so
-sharding ``codes`` over a mesh axis hands every device exactly the packed
-slab it would have produced locally, and per-device HBM is
-``packed_bytes / shard_count`` (``per_shard_bytes``). Only two layouts
-actually change bytes under this: ``nib4``/``quad2`` when the shard dim IS
-the packed contraction dim (row-parallel) and the per-shard row count is
-not a multiple of the codes-per-byte, and ``bitstream`` always (the flat
-stream must break at shard boundaries). Everything else degenerates to the
-plain packing.
+(each padded to its own byte boundary), then concatenates the shard
+layouts back along ``d``. The result is bit-identical, shard for shard, to
+packing each shard on its own — so sharding ``codes`` over a mesh axis
+hands every device exactly the packed slab it would have produced locally,
+and per-device HBM is ``packed_bytes / shard_count`` (``per_shard_bytes``).
+Only a shard dim that IS the packed contraction dim (row-parallel) changes
+bytes: ``nib4``/``quad2`` when the per-shard row count is not a multiple of
+the codes-per-byte, and ``planes`` always (each shard keeps its own plane
+blocks). Everything else degenerates to the plain packing.
 
 Scales are per-channel ``(out,)`` over the weight's last dim. The serving
 session fills them with the trained per-tensor indicator-bank scale
@@ -58,35 +61,7 @@ SCALE_EPS = 1e-9  # fake_quant's scale floor — must match for bit-exactness
 
 
 # ---------------------------------------------------------------------------
-# generic bitstream codec (any bits <= 8)
-# ---------------------------------------------------------------------------
-def pack_codes(q, bits: int, *, signed: bool = True) -> Array:
-    """Bit-pack integer codes ``q`` (values on the `bits`-wide grid) into a
-    little-endian uint8 bitstream of ``ceil(q.size * bits / 8)`` bytes."""
-    if not 1 <= bits <= 8:
-        raise ValueError(f"bits must be in [1, 8], got {bits}")
-    qmin, qmax = bit_range(bits, signed)
-    u = jnp.asarray(q, jnp.int32).reshape(-1) - int(qmin)
-    bitmat = (u[:, None] >> jnp.arange(bits, dtype=jnp.int32)) & 1
-    flat = bitmat.reshape(-1)
-    pad = (-flat.size) % 8
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros((pad,), jnp.int32)])
-    weights = (1 << jnp.arange(8, dtype=jnp.int32))
-    return (flat.reshape(-1, 8) * weights).sum(-1).astype(jnp.uint8)
-
-
-def unpack_codes(codes, bits: int, n: int, *, signed: bool = True) -> Array:
-    """Exact inverse of :func:`pack_codes` -> ``(n,)`` int8 codes."""
-    qmin, _ = bit_range(bits, signed)
-    b = (jnp.asarray(codes, jnp.int32)[:, None] >> jnp.arange(8)) & 1
-    b = b.reshape(-1)[: n * bits].reshape(n, bits)
-    u = (b << jnp.arange(bits, dtype=jnp.int32)).sum(-1)
-    return (u + int(qmin)).astype(jnp.int8)
-
-
-# ---------------------------------------------------------------------------
-# kernel-friendly nibble / crumb layouts (packed along the contraction dim)
+# kernel layouts (packed along the contraction dim, N on the lanes)
 # ---------------------------------------------------------------------------
 def _pad_rows(q: Array, mult: int) -> Array:
     k = q.shape[-2]
@@ -134,12 +109,57 @@ def unpack_quad2(codes: Array, k: int) -> Array:
     return full.reshape(shape)[..., :k, :].astype(jnp.int8)
 
 
+def pack_planes(q: Array, bits: int) -> Array:
+    """Signed ``bits``-wide codes ``(..., K, N)`` -> ``(..., bits *
+    ceil(K/8), N)`` uint8 bit planes (module docstring), offset-binary.
+    Works in bytes on ``(..., K/8, 8, N)`` views: no intermediate is wider
+    than one byte a code."""
+    qmin, _ = bit_range(bits, True)
+    # pad rows hold the grid's zero; bits <= 7 keeps q - qmin inside int8
+    u = (_pad_rows(jnp.asarray(q).astype(jnp.int8), 8) - qmin).astype(
+        jnp.uint8)
+    u = u.reshape(u.shape[:-2] + (-1, 8, u.shape[-1]))   # (..., K8, 8, N)
+    shifts = jnp.arange(8, dtype=jnp.uint8)[:, None]
+    planes = [jnp.sum(((u >> j) & 1) << shifts, axis=-2, dtype=jnp.uint8)
+              for j in range(bits)]
+    return jnp.concatenate(planes, axis=-2)
+
+
+def unpack_planes(codes: Array, bits: int, k: int) -> Array:
+    """Inverse of :func:`pack_planes` -> ``(..., k, N)`` int8 codes."""
+    qmin, _ = bit_range(bits, True)
+    c = jnp.asarray(codes, jnp.uint8)
+    k8 = c.shape[-2] // bits
+    c = c.reshape(c.shape[:-2] + (bits, k8, 1, c.shape[-1]))
+    shifts = jnp.arange(8, dtype=jnp.uint8)[:, None]
+    u = (c[..., 0, :, :, :] >> shifts) & 1             # (..., K8, 8, N)
+    for j in range(1, bits):
+        u = u | (((c[..., j, :, :, :] >> shifts) & 1) << j)
+    u = u.reshape(u.shape[:-3] + (8 * k8, u.shape[-1]))[..., :k, :]
+    return u.astype(jnp.int8) + jnp.int8(qmin)
+
+
 def _layout_for(bits: int) -> str:
-    return {8: "int8", 4: "nib4", 2: "quad2"}.get(bits, "bitstream")
+    return {8: "int8", 4: "nib4", 2: "quad2"}.get(bits, "planes")
 
 
-_PACK_MULT = {"nib4": 2, "quad2": 4}
-_PACK_FN = {"nib4": pack_nib4, "quad2": pack_quad2}
+_PACK_MULT = {"nib4": 2, "quad2": 4, "planes": 8}
+
+
+def _pack(q: Array, layout: str, bits: int) -> Array:
+    if layout == "nib4":
+        return pack_nib4(q)
+    if layout == "quad2":
+        return pack_quad2(q)
+    return pack_planes(q, bits)
+
+
+def _unpack(codes: Array, layout: str, bits: int, k: int) -> Array:
+    if layout == "nib4":
+        return unpack_nib4(codes, k)
+    if layout == "quad2":
+        return unpack_quad2(codes, k)
+    return unpack_planes(codes, bits, k)
 
 
 def _split_shards(q: Array, dim: int, count: int):
@@ -152,17 +172,11 @@ def _split_shards(q: Array, dim: int, count: int):
 
 def _pack_sharded(q: Array, layout: str, bits: int, dim: int,
                   count: int) -> Array:
-    """Pack each of ``count`` shards of ``q`` along ``dim`` independently.
-
-    Per-shard layouts are byte-aligned on their own (``nib4``/``quad2``
-    pad each shard's rows to the codes-per-byte multiple; ``bitstream``
-    gives each shard its own byte-aligned stream), then concatenated along
-    the packed counterpart of ``dim`` — dim itself for the row layouts,
-    axis 0 of the flat stream for ``bitstream``."""
-    shards = _split_shards(q, dim, count)
-    if layout == "bitstream":
-        return jnp.concatenate([pack_codes(s, bits) for s in shards])
-    return jnp.concatenate([_PACK_FN[layout](s) for s in shards], axis=dim)
+    """Pack each of ``count`` shards of ``q`` along ``dim`` independently
+    (each pads its own rows to the layout's row multiple) and concatenate
+    the per-shard layouts along ``dim``."""
+    return jnp.concatenate([_pack(s, layout, bits)
+                            for s in _split_shards(q, dim, count)], axis=dim)
 
 
 # ---------------------------------------------------------------------------
@@ -234,48 +248,37 @@ class PackedLinear:
     def sharded_layout(self) -> bool:
         """True when the codes bytes differ from the plain packing — i.e.
         they are a concatenation of independently packed shard slabs that
-        ``unpack`` must reassemble shard by shard."""
+        ``unpack`` must reassemble shard by shard. Only a row-parallel shard
+        (the contraction dim) can break a layout: ``planes`` always keeps a
+        shard's plane blocks together, ``nib4``/``quad2`` break where a
+        shard's rows do not fill whole bytes."""
         if self.shard_count <= 1 or self.shard_dim is None:
             return False
-        if self.layout == "bitstream":
-            return True
-        d = self.shard_dim % len(self.shape)
-        return (self.layout in _PACK_MULT and d == len(self.shape) - 2
-                and (self.shape[-2] // self.shard_count) % _PACK_MULT[
-                    self.layout] != 0)
+        rank = len(self.shape)
+        if self.layout not in _PACK_MULT or self.shard_dim % rank != rank - 2:
+            return False
+        return self.layout == "planes" or (
+            self.shape[-2] // self.shard_count) % _PACK_MULT[self.layout] != 0
 
     @jax.named_scope("unpack")
     def unpack(self) -> Array:
         """Exact signed integer codes in the weight's original shape, under
         the named scope ``unpack`` (every route's unpack, traced)."""
-        n = int(np.prod(self.shape))
         if self.layout == "int8":
             return self.codes
         if self.sharded_layout():
             return self._unpack_sharded()
-        if self.layout == "nib4":
-            return unpack_nib4(self.codes, self.shape[-2])
-        if self.layout == "quad2":
-            return unpack_quad2(self.codes, self.shape[-2])
-        return unpack_codes(self.codes, self.w_bits, n).reshape(self.shape)
+        return _unpack(self.codes, self.layout, self.w_bits, self.shape[-2])
 
     def _unpack_sharded(self) -> Array:
-        """Inverse of the per-shard packing: split the codes into their
-        ``shard_count`` slabs, unpack each, and concatenate along the
-        original shard dim."""
-        d = (self.shard_dim or 0) % len(self.shape)
-        shard_shape = list(self.shape)
-        shard_shape[d] //= self.shard_count
-        if self.layout == "bitstream":
-            n_s = int(np.prod(shard_shape))
-            slabs = jnp.split(self.codes, self.shard_count)
-            parts = [unpack_codes(s, self.w_bits, n_s).reshape(shard_shape)
-                     for s in slabs]
-            return jnp.concatenate(parts, axis=d)
-        ks = shard_shape[-2]
-        unpack = unpack_nib4 if self.layout == "nib4" else unpack_quad2
+        """Inverse of the per-shard packing along the contraction dim: split
+        the codes into their ``shard_count`` slabs, unpack each, and
+        concatenate."""
+        ks = self.shape[-2] // self.shard_count
         slabs = jnp.split(self.codes, self.shard_count, axis=-2)
-        return jnp.concatenate([unpack(s, ks) for s in slabs], axis=-2)
+        return jnp.concatenate(
+            [_unpack(s, self.layout, self.w_bits, ks) for s in slabs],
+            axis=-2)
 
     def dequant(self, dtype=jnp.float32) -> Array:
         """Dequantized weight — bit-exact with the fake-quant graph when
@@ -357,16 +360,10 @@ def pack_linear(w: Array, w_bits: int, s_w, a_bits: int, s_a, *,
             f"not split into {shard_count} shards")
     if layout == "int8":
         codes = q.astype(jnp.int8)   # byte-per-code: sharding never splits
-    elif sharded and (layout == "bitstream"
-                      or shard_dim % w.ndim == w.ndim - 2):
-        codes = _pack_sharded(q, layout, w_bits, shard_dim % w.ndim,
-                              shard_count)
-    elif layout == "nib4":
-        codes = pack_nib4(q)
-    elif layout == "quad2":
-        codes = pack_quad2(q)
+    elif sharded and shard_dim % w.ndim == w.ndim - 2:
+        codes = _pack_sharded(q, layout, w_bits, w.ndim - 2, shard_count)
     else:
-        codes = pack_codes(q, w_bits)
+        codes = _pack(q, layout, w_bits)
     return PackedLinear(
         codes=codes, scale=scale,
         s_a=jnp.asarray(s_a, jnp.float32),

@@ -30,18 +30,27 @@ from repro.runtime.session import QuantizedSession, summarize
 # packing
 # ===========================================================================
 @settings(max_examples=12, deadline=None)
-@given(st.sampled_from([2, 3, 4, 8]),          # searched bit-widths
+@given(st.sampled_from([3, 5, 6, 7]),          # widths the planes layout takes
        st.integers(1, 19),                     # rows (odd counts included)
        st.integers(1, 11),                     # channels (odd counts)
+       st.sampled_from([(), (3,)]),            # plain or expert-stacked
        st.integers(0, 2 ** 31 - 1))
-def test_pack_unpack_roundtrip(bits, rows, cols, seed):
-    """Property: unpack(pack(q, bits)) == q on the signed grid, any shape."""
+def test_pack_unpack_roundtrip(bits, rows, cols, lead, seed):
+    """Property: unpack(pack(q, bits)) == q on the signed grid, any shape;
+    the planes are one byte array of ``bits * ceil(rows/8)`` rows that
+    keeps the leading axes, exactly ``n * bits / 8`` bytes when the rows
+    fill whole 8-row groups."""
     r = np.random.default_rng(seed)
     qmin, qmax = bit_range(bits, True)
-    q = r.integers(qmin, qmax + 1, size=(rows, cols)).astype(np.int8)
-    back = np.asarray(packing.unpack_codes(
-        packing.pack_codes(q, bits), bits, q.size)).reshape(rows, cols)
-    np.testing.assert_array_equal(back, q)
+    q = r.integers(qmin, qmax + 1, size=lead + (rows, cols)).astype(np.int8)
+    codes = packing.pack_planes(q, bits)
+    assert codes.dtype == jnp.uint8
+    assert codes.shape == lead + (bits * -(-rows // 8), cols)
+    if rows % 8 == 0:
+        assert codes.size * 8 == q.size * bits
+    back = packing.unpack_planes(codes, bits, rows)
+    assert back.dtype == jnp.int8
+    np.testing.assert_array_equal(np.asarray(back), q)
 
 
 @settings(max_examples=8, deadline=None)
@@ -60,7 +69,7 @@ def test_kernel_layout_roundtrip(bits, rows, cols, seed):
     np.testing.assert_array_equal(np.asarray(back), q)
 
 
-@pytest.mark.parametrize("bits", [2, 3, 4, 8])
+@pytest.mark.parametrize("bits", [2, 3, 4, 5, 6, 8])
 def test_pack_linear_matches_fake_quant(bits):
     """Dequantized packed weights == the fake-quant graph's values, bitwise
     (per-tensor trained scale), and storage is ceil(n*bits/8) + padding."""
@@ -72,8 +81,10 @@ def test_pack_linear_matches_fake_quant(bits):
     np.testing.assert_array_equal(np.asarray(pl.dequant()), np.asarray(ref))
     ideal = (w.size * bits + 7) // 8
     assert pl.packed_bytes >= ideal
-    # padding overhead is at most one row of the packed layout
-    assert pl.packed_bytes <= ideal + w.shape[-1] + 1
+    # padding overhead is at most one 8-row group of bit planes (bits * N
+    # bytes), and at most one row of the nib4/quad2 layouts
+    pad = bits * w.shape[-1] if pl.layout == "planes" else w.shape[-1]
+    assert pl.packed_bytes <= ideal + pad
 
 
 @pytest.mark.parametrize("bits", [2, 3, 4, 5, 8])
@@ -92,12 +103,10 @@ def test_shard_aware_packing_bit_identical_per_shard(bits, dim, count):
                                   np.asarray(plain.unpack()))
     np.testing.assert_array_equal(np.asarray(sh.dequant()),
                                   np.asarray(plain.dequant()))
-    axis = 0 if sh.layout == "bitstream" else dim
-    slabs = np.split(np.asarray(sh.codes), count, axis=axis)
+    slabs = np.split(np.asarray(sh.codes), count, axis=dim)
     for slab, ws in zip(slabs, np.split(w, count, axis=dim)):
         indep = packing.pack_linear(ws, bits, s, 6, 0.02)
-        np.testing.assert_array_equal(slab.reshape(-1),
-                                      np.asarray(indep.codes).reshape(-1))
+        np.testing.assert_array_equal(slab, np.asarray(indep.codes))
     assert sh.per_shard_bytes * count == sh.packed_bytes
     assert plain.per_shard_bytes == plain.packed_bytes
 
@@ -115,6 +124,61 @@ def test_sharded_nib4_layout_not_w4_eligible():
     plain = packing.pack_linear(w, 4, np.float32(0.05), 6, 0.02)
     assert not plain.sharded_layout()
     assert dispatch.kernel_eligible("bsd,de->bse", plain) == "pallas-w4"
+
+
+def test_planes_route_from_the_layout():
+    """A plain 2-D planes leaf whose bytes tile takes the bit-plane kernel;
+    one whose K or N does not tile, or whose row-parallel per-shard packing
+    regroups the plane blocks, unpacks in XLA for the int8 kernel; a
+    per-channel scale takes no kernel."""
+    r = np.random.default_rng(0)
+    s = np.float32(0.05)
+
+    def eligible(shape, bits=5, **kw):
+        w = r.normal(size=shape).astype(np.float32)
+        pl = packing.pack_linear(w, bits, s, 6, 0.02, **kw)
+        assert pl.layout == "planes"
+        return dispatch.kernel_eligible("bsd,de->bse", pl)
+
+    assert eligible((256, 128)) == "pallas-planes"
+    assert eligible((512, 384), bits=3) == "pallas-planes"
+    assert eligible((24, 128)) == "pallas-int8"           # K tiles no plane
+    assert eligible((256, 72)) == "pallas-int8"           # N under 128 lanes
+    assert eligible((256, 128), shard_dim=1, shard_count=2) == "pallas-planes"
+    assert eligible((512, 128), shard_dim=0, shard_count=2) == "pallas-int8"
+    assert eligible((256, 128), per_channel=True) is None
+
+
+def test_pack_linear_intermediates_fit_yi9b_width():
+    """Packing a 3-bit 4096 x 11008 weight (yi-9b's MLP) builds no integer
+    intermediate larger than twice the weight's int8 size: the planes codec
+    works in bytes, never on a per-code bit matrix. The float quantize of
+    the float32 weight (w / s, clip, round) is one elementwise pass as large
+    as its input, which XLA fuses; it is bounded by the input's size."""
+    K, N = 4096, 11008
+    w = jax.ShapeDtypeStruct((K, N), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda w: packing.pack_linear(w, 3, 0.05, 8, 0.02))(w)
+
+    def outvars(jx):
+        for eqn in jx.eqns:
+            yield from eqn.outvars
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from outvars(sub)
+
+    int8_size = K * N
+    sizes = []
+    for v in outvars(jaxpr.jaxpr):
+        a = v.aval
+        nbytes = int(np.prod(a.shape)) * a.dtype.itemsize
+        sizes.append(nbytes)
+        if jnp.issubdtype(a.dtype, jnp.floating):
+            assert nbytes <= 4 * int8_size, (a, nbytes)
+        else:
+            assert nbytes <= 2 * int8_size, (a, nbytes)
+    assert max(sizes) >= int8_size      # the walk saw the full-size values
+    codes = jaxpr.out_avals[0]
+    assert codes.shape == (3 * K // 8, N) and codes.dtype == jnp.uint8
 
 
 class _Mesh2x4:
@@ -192,6 +256,27 @@ def test_quant_matmul_w4_packed_equivalence():
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("bits", [3, 5, 6])
+def test_quant_matmul_planes_equivalence(bits):
+    """Interpret-mode bit-plane kernel == quant_matmul on the unpacked
+    codes, bitwise, with a K that fills no whole 8-row group and K tiles
+    regrouped by bit position."""
+    from repro.kernels import ops
+    r = np.random.default_rng(bits)
+    qmin, qmax = bit_range(bits, True)
+    s_x, s_w = np.float32(0.05), np.float32(0.07)
+    for M, K, N, blocks in ((5, 26, 16, (8, 8, 8)),
+                            (3, 40, 24, (8, 8, 16))):
+        xq = jnp.asarray(r.integers(-127, 128, size=(M, K)), jnp.int8)
+        wq = r.integers(qmin, qmax + 1, size=(K, N)).astype(np.int8)
+        wp = packing.pack_planes(wq, bits)
+        out = ops.quant_matmul_planes(xq, wp, s_x, s_w, bits=bits,
+                                      blocks=blocks)
+        ref = ops.quant_matmul(xq, jnp.asarray(wq), s_x, s_w, blocks=blocks)
+        assert out.shape == (M, N)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
 @pytest.fixture(scope="module")
 def qctx():
     return QuantContext.make((2, 3, 4, 5, 6), True,
@@ -249,8 +334,8 @@ def test_dispatch_moe_stacked_fallback(qctx):
 
 
 def test_dispatch_kernel_routes_close(qctx):
-    """Forced Pallas routes (int8 + packed-int4) agree with the fallback to
-    int32-accumulation tolerance."""
+    """Forced Pallas routes (int8, packed-int4, bit planes) agree with the
+    fallback to int32-accumulation tolerance."""
     bits = (2, 3, 4, 5, 6)
     p = qdense_init(jax.random.PRNGKey(5), 16, 12, bits)
     x = jnp.asarray(np.random.default_rng(5).normal(size=(2, 3, 16)),
@@ -258,13 +343,17 @@ def test_dispatch_kernel_routes_close(qctx):
     pl = _packed_from_bank(p, 2, 3, bits, qctx)      # 4-bit -> nib4 layout
     assert pl.layout == "nib4"
     assert dispatch.kernel_eligible("bsd,de->bse", pl) == "pallas-w4"
-    ref = dispatch.packed_qeinsum("bsd,de->bse", x, pl, qctx,
-                                  impl="dequant-fp")
-    for impl in ("pallas-w4", "pallas-int8"):
-        with dispatch.force_impl(impl):
-            got = dispatch.packed_qeinsum("bsd,de->bse", x, pl, qctx)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-5)
+    pl5 = _packed_from_bank(p, 3, 3, bits, qctx)     # 5-bit -> planes layout
+    assert pl5.layout == "planes"
+    for leaf, impls in ((pl, ("pallas-w4", "pallas-int8")),
+                        (pl5, ("pallas-planes", "pallas-int8"))):
+        ref = dispatch.packed_qeinsum("bsd,de->bse", x, leaf, qctx,
+                                      impl="dequant-fp")
+        for impl in impls:
+            with dispatch.force_impl(impl):
+                got = dispatch.packed_qeinsum("bsd,de->bse", x, leaf, qctx)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                       rtol=1e-5, atol=1e-5)
     # off-TPU auto-resolution stays on the exact fallback
     assert dispatch.resolve("bsd,de->bse", pl) == "dequant-fp"
 

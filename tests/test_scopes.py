@@ -68,10 +68,12 @@ def _scoped(names, prefix, scope):
 
 
 @pytest.mark.parametrize("route, bits, inner", [
-    # 3- and 6-bit codes are bitstreams, 4-bit nib4: all unpack via XLA
+    # 3- and 6-bit planes and 4-bit nib4 codes all unpack via XLA here
     ("pallas-int8", (3, 4, 6), ("unpack", "act_codes", "kernel")),
     # nib4 bytes are the kernel's operand: nothing unpacks outside it
     ("pallas-w4", (4,), ("act_codes", "kernel")),
+    # so are the bit planes of 3-, 5- and 6-bit codes
+    ("pallas-planes", (3, 5, 6), ("act_codes", "kernel")),
     ("dequant-fp", (3, 4, 6), ("unpack", "act_codes", "dequant")),
 ])
 def test_decode_ops_name_their_site_and_route_step(tied, route, bits, inner):
@@ -89,7 +91,7 @@ def test_decode_ops_name_their_site_and_route_step(tied, route, bits, inner):
                     continue
                 assert _scoped(names, f"{site}/{proj}", scope), \
                     (site, proj, scope)
-    if route == "pallas-w4":
+    if route in ("pallas-w4", "pallas-planes"):
         assert not any("unpack" in n.split("/") for n in names)
     assert any(n.startswith("lm_head/") for n in names)
     # the tied head fake-quantizes the embedding table in every launch;
@@ -131,6 +133,9 @@ def _kernel_calls():
         "quant_matmul_w4": (
             lambda *a: qmm.quant_matmul_w4(*a, interpret=True),
             [S((8, 256), i8), S((128, 256), u8), S((), f32), S((), f32)]),
+        "quant_matmul_planes": (
+            lambda *a: qmm.quant_matmul_planes(*a, bits=3, interpret=True),
+            [S((8, 256), i8), S((96, 256), u8), S((), f32), S((), f32)]),
         "wkv_pallas": (
             lambda *a: wkv.wkv_pallas(*a, interpret=True),
             [S((1, 64, 2, 64), f32)] * 4 + [S((2, 64), f32)]),

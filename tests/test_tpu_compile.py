@@ -1,6 +1,7 @@
 """The serving and prefill Pallas kernels compile for a TPU v5e at Qwen3-0.6B
 shapes (d_model 1024, d_ff 3072, 16 query / 8 KV heads of 128, 8 decode
-slots over a 2080-token cache, 2048-token prefill).
+slots over a 2080-token cache, 2048-token prefill; the bit-plane matmul at
+the searched policy's 3-bit ``mlp_wo``, 5-bit ``wq`` and 6-bit ``wk``).
 
 Interpret mode runs the kernel bodies but not the TPU compiler, which also
 refuses blocks that do not tile. These tests compile for a described chip
@@ -8,8 +9,11 @@ refuses blocks that do not tile. These tests compile for a described chip
 described inside a fixture so that only the worker running this file loads
 the TPU compiler.
 """
+import functools
 import os
 import re
+import sys
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +77,11 @@ def _cases(spec):
         "quant_matmul_w4": (
             qmm.quant_matmul_w4,
             [spec((SLOTS, D), i8), spec((D // 2, FF), u8), scalar, scalar]),
+        **{f"quant_matmul_planes.w{bits}": (
+            functools.partial(qmm.quant_matmul_planes, bits=bits),
+            [spec((SLOTS, k), i8), spec((bits * k // 8, n), u8), scalar,
+             scalar])
+           for bits, k, n in ((3, FF, D), (5, D, H * HD), (6, D, KV * HD))},
         "decode_attn_quant": (
             qa.decode_attn_quant,
             [spec((SLOTS, 1, H, HD), f32)] + ring_kv + [spec((SLOTS,), i32)]),
@@ -91,17 +100,66 @@ def _cases(spec):
     }
 
 
-@pytest.mark.parametrize("kernel", [
-    "quant_matmul", "quant_matmul_w4", "decode_attn_quant",
-    "decode_attn_quant_paged", "verify_attn_quant", "flash_fwd_pallas"])
-def test_kernel_compiles_for_v5e(one_chip, kernel):
+KERNELS = [
+    "quant_matmul", "quant_matmul_w4", "quant_matmul_planes.w3",
+    "quant_matmul_planes.w5", "quant_matmul_planes.w6", "decode_attn_quant",
+    "decode_attn_quant_paged", "verify_attn_quant", "flash_fwd_pallas"]
+
+
+def _compile(one_chip, kernel):
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     fn, args = _cases(spec)[kernel]
-    text = jax.jit(fn).lower(*args).compile().as_text()
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _name(kernel):
+    """The kernel's ``pallas_call`` name; verify attention unrolls onto the
+    decode kernel."""
+    return {"verify_attn_quant": "decode_attn_quant"}.get(
+        kernel, kernel.split(".")[0])
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+def test_kernel_compiles_for_v5e(one_chip, kernel):
+    text = _compile(one_chip, kernel).as_text()
     assert "tpu_custom_call" in text
-    # the custom call takes its kernel's name (``pallas_call(name=...)``);
-    # verify attention unrolls onto the decode kernel
-    name = {"verify_attn_quant": "decode_attn_quant"}.get(kernel, kernel)
+    # the custom call takes its kernel's name (``pallas_call(name=...)``)
+    name = _name(kernel)
     assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call\(", text)
+
+
+@pytest.fixture(scope="module")
+def harness_kernels():
+    """The chip benchmark's kernel selection (``chipbench/kernels.py``)."""
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench"))
+    import kernels
+    return kernels
+
+
+@pytest.mark.parametrize("kernel", [
+    "quant_matmul", "quant_matmul_w4", "quant_matmul_planes.w3",
+    "quant_matmul_planes.w5", "quant_matmul_planes.w6", "decode_attn_quant",
+    "decode_attn_quant_paged"])
+def test_harness_tells_packed_matmuls_from_attention(one_chip,
+                                                     harness_kernels,
+                                                     kernel):
+    """A trace names a device op by its HLO text with operand shapes: the
+    benchmark counts every packed matmul kernel (an int8 ``(m, k)``
+    activation and one 2-D one-byte weight first) in
+    ``quant_matmul_roofline`` and every other Pallas call as attention."""
+    from jax._src.lib import xla_client as xc
+
+    module = _compile(one_chip, kernel).runtime_executable().hlo_modules()[0]
+    printing = xc._xla.HloPrintOptions()
+    printing.print_operand_shape = True
+    name = _name(kernel)
+    ops = [SimpleNamespace(name=line.strip().removeprefix("ROOT "))
+           for line in module.to_string(printing).splitlines()
+           if re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call\(", line)]
+    assert len(ops) == 1 and harness_kernels.custom(ops[0])
+    matmul = kernel.startswith("quant_matmul")
+    assert harness_kernels.quant_matmul(ops[0]) is matmul
+    assert harness_kernels.attention(ops[0]) is not matmul
