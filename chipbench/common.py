@@ -1,5 +1,6 @@
 """Shared set-up of the chip benchmark: paths, the compile cache, and the
-model configurations read from ``chipbench/configs/<name>.json``.
+configuration files read from ``chipbench/configs/<name>.json`` (each
+served by the architecture module its ``arch`` key names, ``archs.py``).
 
 Importing this module puts the program's ``src`` directory on ``sys.path``
 and points JAX's persistent compilation cache at a fixed directory inside
@@ -35,19 +36,3 @@ def read_json(rel: str):
 def config_file(name: str) -> dict:
     return read_json(os.path.join("chipbench", "configs", f"{name}.json"))
 
-
-def model_config(raw: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-
-    d, h = raw["hidden_size"], raw["num_attention_heads"]
-    return ModelConfig(
-        name=raw["name"], family="dense",
-        n_layers=raw["num_hidden_layers"], d_model=d, n_heads=h,
-        n_kv_heads=raw["num_key_value_heads"],
-        d_ff=raw["intermediate_size"], vocab=raw["vocab_size"],
-        head_dim=raw.get("head_dim", d // h),
-        rope_theta=float(raw["rope_theta"]), qk_norm=bool(raw["qk_norm"]),
-        mlp_gated=True, act=raw["hidden_act"],
-        tie_embeddings=bool(raw["tie_word_embeddings"]),
-        norm_eps=float(raw["rms_norm_eps"]))

@@ -17,6 +17,7 @@ import os
 import sys
 import time
 
+import archs
 import common
 
 STEPS = 3
@@ -43,7 +44,7 @@ def make(name, out_dir):
     from repro.models.quant_layers import QuantContext
 
     raw = common.config_file(name)
-    cfg = common.model_config(raw)
+    cfg = archs.load(raw).model_config(raw)
     dev = jax.devices()[0]
     limit = dev.memory_stats()["bytes_limit"]
     params = jax.jit(lm.init_params, static_argnums=1)(

@@ -1,14 +1,10 @@
-"""Whole-step utilization of decode: the model FLOPs of every token the
-decode launches of the window generated (``counts.decode_flops`` at each
-token's context), over the window's seconds times the bf16 peak."""
-import counts
+"""Whole-step utilization of decode: the model FLOPs of every decode
+launch of the window (the architecture's ``decode_flops`` at its live
+slots' attended rows), over the window's seconds times the bf16 peak."""
 
 
 def read(r):
     if not r.decodes:
         return None
-    d = r.raw
-    per_tok = counts.decode_flops(d, 0)
-    att = counts.decode_flops(d, 1) - per_tok
-    flops = sum(live * per_tok + rows * att for rows, live in r.decodes)
+    flops = sum(r.arch.decode_flops(r.raw, lens) for lens in r.decodes)
     return 100.0 * flops / (r.window_s * r.peaks["bf16_flops"])

@@ -1,15 +1,17 @@
-"""Plain reference of a served configuration, in ``jax.numpy``.
+"""Plain reference of a served configuration, in ``jax.numpy``: the parts
+that every architecture shares.
 
-It imports nothing of the program. It computes the model the configuration
-and its policy file state: a dense GQA decoder with RMS norms, optional
-per-head q/k norms, RoPE (half rotation), a gated SiLU MLP, every searched
-projection's weights and input activations quantized to the policy's bits
-with per-tensor step sizes, the 8-bit pinned embedding and head, and an int8
-KV cache with one step size per token row and head. The cache is modelled by
-quantizing each key and value row once, as it is written.
+It imports nothing of the program. An architecture module
+(``arch/<arch>.py``) writes its layer equations with these pieces and
+scores served sequences with ``teacher_forced``. What every architecture
+here shares: every searched projection's weights and input activations
+quantized to the policy's bits with per-tensor step sizes (``proj``), RMS
+norms, RoPE (half rotation), the 8-bit pinned embedding and head, and an
+int8 KV cache with one step size per token row and head, modelled by
+quantizing each key and value row once, as it is written (``kv_quant``).
 
-The weights are drawn again from the seed, layer by layer (``weights.py``),
-inside one scan, so the whole model is never held at once.
+The weights are drawn again from the seed, layer by layer, inside one scan
+(``lm_rows``), so the whole model is never held at once.
 
 ``precision`` names the precision of every value and matmul. ``float32``
 is the reference proper (matmuls at ``highest``). ``bfloat16``, the
@@ -18,8 +20,6 @@ precision below the configuration's, computes every value in bfloat16;
 operand to float8 (e4m3).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -51,13 +51,13 @@ def rms(x, scale, eps):
     return x * jax.lax.rsqrt(var + eps) * scale
 
 
-def policy_arrays(raw: dict, policy: dict):
-    """(n_layers, 7) weight bits and activation bits in ``weights.PROJ``
+def policy_arrays(raw: dict, policy: dict, projs):
+    """(n_layers, len(projs)) weight bits and activation bits in ``projs``
     order, from the policy file's per-layer maps."""
     n = raw["num_hidden_layers"]
-    wb = [[policy["w_bits"][f"L{i:03d}.{p}"] for p in weights.PROJ]
+    wb = [[policy["w_bits"][f"L{i:03d}.{p}"] for p in projs]
           for i in range(n)]
-    ab = [[policy["a_bits"][f"L{i:03d}.{p}"] for p in weights.PROJ]
+    ab = [[policy["a_bits"][f"L{i:03d}.{p}"] for p in projs]
           for i in range(n)]
     return jnp.asarray(wb, jnp.int32), jnp.asarray(ab, jnp.int32)
 
@@ -83,7 +83,9 @@ class Num:
                           preferred_element_type=out or self.dtype)
 
 
-def _proj(x, p, wbits, abits, num):
+def proj(x, p, wbits, abits, num):
+    """A searched projection: weight and input fake-quantized to their
+    bits with the step sizes of ``p``'s bank."""
     bank = jnp.asarray(weights.BITS, jnp.int32)
     s_w = p["s_w"][jnp.argmax(bank == wbits)]
     s_a = p["s_a"][jnp.argmax(bank == abits)]
@@ -92,7 +94,7 @@ def _proj(x, p, wbits, abits, num):
     return num.einsum("sd,de->se", xq, w)
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     hd = x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
     f = pos.astype(jnp.float32)[:, None] * inv[None, :]
@@ -102,63 +104,29 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _layer(x, p, wb, ab, raw, num):
-    S, d = x.shape
-    H, KV = raw["num_attention_heads"], raw["num_key_value_heads"]
-    hd = raw.get("head_dim", d // H)
-    eps = float(raw["rms_norm_eps"])
-    dtype = num.dtype
-    p = jax.tree.map(lambda a: a.astype(dtype), p)
-    h = rms(x, p["norm1"]["scale"], eps)
-    q = _proj(h, p["wq"], wb[0], ab[0], num).reshape(S, H, hd)
-    k = _proj(h, p["wk"], wb[1], ab[1], num).reshape(S, KV, hd)
-    v = _proj(h, p["wv"], wb[2], ab[2], num).reshape(S, KV, hd)
-    if raw["qk_norm"]:
-        q = rms(q, p["q_norm"], eps)
-        k = rms(k, p["k_norm"], eps)
-    pos = jnp.arange(S)
-    q = _rope(q, pos, float(raw["rope_theta"]))
-    k = kv_quant(_rope(k, pos, float(raw["rope_theta"])))
-    v = kv_quant(v)
-    G = H // KV
-    qg = q.reshape(S, KV, G, hd) * jnp.asarray(hd ** -0.5, dtype)
-    logits = num.einsum("qkgd,skd->kgqs", qg, k, jnp.float32)
-    mask = pos[None, :] <= pos[:, None]
-    logits = jnp.where(mask, logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
-    o = num.einsum("kgqs,skd->qkgd", probs, v).reshape(S, H * hd)
-    x = x + _proj(o, p["wo"], wb[3], ab[3], num)
-    h = rms(x, p["norm2"]["scale"], eps)
-    up = _proj(h, p["mlp_wi"], wb[4], ab[4], num)
-    gate = _proj(h, p["mlp_wg"], wb[5], ab[5], num)
-    return x + _proj(jax.nn.silu(gate) * up, p["mlp_wo"], wb[6], ab[6], num)
-
-
-def _embed_table(p, dtype):
+def embed_table(p, dtype):
     return fake_quant(p["w"].astype(dtype), p["s_w8"], 8.0)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("raw_items", "precision", "n_out"))
-def _gap_rows(seed_key, tokens, start, wb, ab, *, raw_items, precision,
-              n_out):
-    """Logits at positions ``start .. start + n_out`` of one sequence."""
-    raw = dict(raw_items)
-    num = Num(precision)
+def lm_rows(seed_key, tokens, start, wb, ab, raw, num, n_out, draw, apply):
+    """Logits at positions ``start .. start + n_out`` of one sequence: the
+    pinned 8-bit embedding, every layer drawn again by ``draw(key, raw)``
+    and applied by ``apply(x, p, wb, ab, raw, num)`` inside one scan, the
+    final RMS norm and the pinned 8-bit head (tied or not)."""
     dtype = num.dtype
     k_embed, k_head, k_layers = seed_key
     emb = weights.embed(k_embed, raw)
-    x = jnp.take(_embed_table(emb, dtype), tokens, axis=0)
+    x = jnp.take(embed_table(emb, dtype), tokens, axis=0)
 
     def body(x, xs):
         key, wbl, abl = xs
-        return _layer(x, weights.layer(key, raw), wbl, abl, raw, num), None
+        return apply(x, draw(key, raw), wbl, abl, raw, num), None
 
     x, _ = jax.lax.scan(body, x, (k_layers, wb, ab))
     x = rms(x, jnp.ones((x.shape[-1],), dtype), float(raw["rms_norm_eps"]))
     rows = jax.lax.dynamic_slice_in_dim(x, start, n_out, axis=0)
     if raw["tie_word_embeddings"]:
-        logits = num.einsum("sd,vd->sv", rows, _embed_table(emb, dtype),
+        logits = num.einsum("sd,vd->sv", rows, embed_table(emb, dtype),
                             jnp.float32)
     else:
         hp = weights.head(k_head, raw)
@@ -179,10 +147,13 @@ def bucket(need: int, cap: int) -> int:
     return min(b, cap)
 
 
-def score(seed: int, raw: dict, policy: dict, seqs, cap: int, n_out: int,
-          controls=()):
+def teacher_forced(gap_rows, projs, seed: int, raw: dict, policy: dict,
+                   seqs, cap: int, n_out: int, controls=()):
     """Teacher-forced reference over served sequences.
 
+    ``gap_rows(seed_key, tokens, start, wb, ab, *, raw_items, precision,
+    n_out)`` is an architecture's jitted logits at positions ``start ..
+    start + n_out`` of one sequence; ``projs`` orders the policy's bits.
     ``seqs`` is a list of (prompt tokens, served tokens, row indices). For
     each served token the reference reads the gap by which its logit lies
     below the reference's best at that position; for each listed row index
@@ -197,7 +168,7 @@ def score(seed: int, raw: dict, policy: dict, seqs, cap: int, n_out: int,
     import numpy as np
 
     ks = weights.keys(seed, raw["num_hidden_layers"])
-    wb, ab = policy_arrays(raw, policy)
+    wb, ab = policy_arrays(raw, policy, projs)
     raw_items = tuple(sorted((k, v) for k, v in raw.items()
                              if isinstance(v, (int, float, str, bool))))
     out = {"program": [], "rows": []}
@@ -213,7 +184,7 @@ def score(seed: int, raw: dict, policy: dict, seqs, cap: int, n_out: int,
         start = len(prompt) - 1
 
         def rows(precision):
-            return np.asarray(_gap_rows(
+            return np.asarray(gap_rows(
                 ks, jnp.asarray(toks), start, wb, ab, raw_items=raw_items,
                 precision=precision, n_out=n_out))[:n]
 

@@ -4,17 +4,22 @@
 
 Reads the cell from ``BENCHMARK.json`` and finds everything else by name:
 the configuration ``chipbench/configs/<config>.json`` and its policy file,
-the traffic mix ``chipbench/traffic/<traffic>.json``, the per-layer readers
+the architecture module ``chipbench/arch/<arch>.py`` that the
+configuration's ``arch`` key names (``archs.py``), the traffic mix
+``chipbench/traffic/<traffic>.json``, the per-layer readers
 ``chipbench/metrics/<metric>.py``, the correctness limits
 ``chipbench/limits/<cell>.json`` and the peaks ``chipbench/peaks.json``.
 
 It loads, warms up every shape the window uses, measures for ``--seconds``
 and prints one JSON line last on standard output. ``--trace 0`` reports the
 cell's end-to-end metrics; ``--trace 1`` runs the window under the profiler
-and reports the per-layer metrics. After the window the served tokens,
-and the logits of the launches that follow it, are held to the plain
-reference (``reference.py``); each compared number is printed beside its
-limit, last on standard error and last in the JSON line.
+and reports the per-layer metrics, and lists every Pallas kernel of the
+window by name, with its class in the architecture's ``KERNELS``; those
+in no class also go into the JSON line as ``unmapped_kernels``. After the
+window the served tokens, and the logits of the launches that follow it,
+are held to the plain reference (the architecture's ``score``); each
+compared number is printed beside its limit, last on standard error and
+last in the JSON line.
 
 It exits non-zero, printing no result, where JAX finds no TPU or fewer chips
 than the cell asks for.
@@ -34,6 +39,7 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 
+import archs  # noqa: E402
 import common  # noqa: E402
 
 
@@ -70,7 +76,8 @@ class Run:
         self.args, self.bench, self.cell = args, bench, cell
         self.seed = args.seed
         self.raw = raw or common.config_file(cell["config"])
-        self.cfg = common.model_config(self.raw)
+        self.arch = archs.load(self.raw)
+        self.cfg = self.arch.model_config(self.raw)
         if policy_text is None:
             with open(os.path.join(common.ROOT, self.raw["policy"])) as f:
                 policy_text = f.read()
@@ -220,6 +227,7 @@ def measure(run, jax, dev, devices, controls=(), pk=None):
         device["window_s"] = red.window_s
         breakdown = {"device_ops": [list(x) for x in red.top_ops()],
                      "idle_gaps": [list(x) for x in red.top_gaps()]}
+        unmapped = pallas_kernels(red, run.arch.KERNELS)
         reading = impl.Reading(run, cell, red, pk, read["e2e"])
         for m in wanted:
             v = load_reader(m["name"])(reading)
@@ -237,10 +245,29 @@ def measure(run, jax, dev, devices, controls=(), pk=None):
            "failed": late, "metrics": metrics, "device": device}
     if breakdown is not None:
         out["breakdown"] = breakdown
+        out["unmapped_kernels"] = unmapped
     out["checks"] = checks
     if controls:
         out["controls"] = ctl
     return out
+
+
+def pallas_kernels(red, kmap):
+    """Print every Pallas kernel of the window by program and name, with
+    its class in the architecture's ``KERNELS``, calls and seconds. Returns
+    {name: seconds} of those in no class: a kernel that no roofline
+    reads."""
+    import kernels
+
+    unmapped = {}
+    for (mod, name), (cls, calls, secs) in sorted(
+            kernels.by_name(red.ops, kmap).items()):
+        print(f"pallas {mod}: {name} class {cls} calls {calls} "
+              f"seconds {secs!r}", file=sys.stderr)
+        if cls is None:
+            unmapped[name] = unmapped.get(name, 0.0) + secs
+    sys.stderr.flush()
+    return unmapped
 
 
 def emit(out):

@@ -64,7 +64,8 @@ class Cell:
         self.next_rid = 0
         self.it = 0
         self.recording = False
-        self.decodes = []    # per decode launch in the window: [(rows, live)]
+        self.decodes = []    # per decode launch in the window: the rows
+        # that each live slot attended
         self.prefills = []   # per prefill in the window: prompt length
         self.logits = {}     # rid -> {served index: program's logits row}
 
@@ -79,11 +80,9 @@ class Cell:
         from repro.models.quant_layers import QuantContext
         from repro.runtime.session import QuantizedSession
 
-        import weights
-
         run, mix, cfg = self.run, self.mix, self.run.cfg
         with run.phase("weights"):
-            params = weights.model_params(run.seed, run.raw)
+            params = run.arch.model_params(run.seed, run.raw)
             jax.block_until_ready(params)
         with run.phase("pack"):
             qctx = QuantContext.make(cfg.bits, cfg.quant_act_signed,
@@ -153,21 +152,20 @@ class Cell:
             finished = list(eng.completions)
             for rid in finished:
                 seen[rid] = eng.completions.pop(rid).tokens
-            rows = live = 0
+            lens = []
             for rid, toks in seen.items():
                 r = self.served[rid]
                 new = toks[len(r.tokens):]
                 for t in new:
                     if r.tokens:
                         # a decode token: it attended every row before it
-                        rows += len(r.prompt) + len(r.tokens)
-                        live += 1
+                        lens.append(len(r.prompt) + len(r.tokens))
                     elif self.recording:
                         self.prefills.append(len(r.prompt))
                     r.tokens.append(int(t))
                     r.times.append(now)
-            if self.recording and live:
-                self.decodes.append((rows, live))
+            if self.recording and lens:
+                self.decodes.append(lens)
             if finished:
                 done = set(finished)
                 for rid in done:
@@ -334,6 +332,7 @@ class Reading:
 
     def __init__(self, run, cell, red, peaks, e2e):
         self.raw, self.policy, self.mix = run.raw, run.policy, run.mix
+        self.arch = run.arch
         self.client = e2e  # the client's own readings of the window
         self.trace, self.peaks = red, peaks
         self.decodes, self.prefills = cell.decodes, cell.prefills
@@ -382,12 +381,10 @@ def held(checks) -> bool:
 def check(run, sample, lim, controls):
     """Hold what ``sample`` served to the plain reference. Returns the
     numbers compared, each with its limit, and the controls' readings."""
-    import reference
-
     res = {"program": [], "rows": []}
     res.update({c: {"gaps": [], "rows": []} for c in controls})
     if sample:
-        res = reference.score(
+        res = run.arch.score(
             run.seed, run.raw, run.policy,
             [(p, t, sorted(rows)) for p, t, rows in sample],
             run.mix["cache_len"], run.mix["output"]["max"], controls)

@@ -1,6 +1,6 @@
-"""The scope table and the two readers built on it, on a hand-built
-reduction, compiled-program text and host spans. (The traced tiny run in
-``test_run.py`` loads both readers on the CPU.)"""
+"""The scope table and the engine span reader, on hand-built
+compiled-program text and host spans. (The traced tiny run in
+``test_run.py`` loads the reader on the CPU.)"""
 import os
 import sys
 from types import SimpleNamespace
@@ -36,9 +36,6 @@ metadata={op_name="jit(decode)/L000/wq/kernel/quant_matmul/pallas_call"}
 }
 """
 
-POLICY = {"w_bits": {"L000.wq": 5, "L001.mlp_wo": 3}}
-
-
 def op(name, module="jit_decode", dur=0.0):
     return tr.Op(f"%{name} = s32[8]{{0}} {name.split('.')[0]}(%p)", module,
                  0.0, dur)
@@ -59,24 +56,9 @@ def test_table_maps_every_instruction_to_its_scope():
     assert not scopes.under("jit(decode)/L000/wq/unpacked", "unpack")
 
 
-def reading(ops, launches, spans=()):
-    red = tr.Reduction(window=(0.0, 1e9), devices=1, ops=list(ops))
-    return SimpleNamespace(trace=red, cell=None, policy=POLICY,
-                           hist={"engine.decode_step_ms": (1.0, launches)},
-                           spans=list(spans))
-
-
-def test_decode_unpack_ms_sums_the_unpack_scope_per_launch(monkeypatch):
-    read = bench_run.load_reader("decode_unpack_ms")
-    monkeypatch.setattr(scopes, "decode_text", lambda cell: HLO)
-    ops = [op("reshape.7", dur=3e6), op("reshape.7", dur=3e6),
-           op("fusion.2", dur=2e6), op("quant_matmul.1", dur=50e6),
-           op("copy.1", dur=1e6), op("fusion.2", "jit_prefill", 9e6)]
-    # 8 ms of unpack over 2 launches; the prefill program's op is not read
-    assert read(reading(ops, 2)) == pytest.approx(4.0)
-    # a program without the scope (or with no launch) reads nothing
-    assert read(reading([op("copy.1", dur=1e6)], 2)) is None
-    assert read(reading(ops, 0)) is None
+def reading(spans):
+    red = tr.Reduction(window=(0.0, 1e9), devices=1)
+    return SimpleNamespace(trace=red, cell=None, spans=list(spans))
 
 
 SPANS = [
@@ -111,6 +93,6 @@ def test_engine_host_ms_is_a_step_less_its_launches(monkeypatch):
     read = bench_run.load_reader("engine_host_ms")
     monkeypatch.setattr(scopes, "engine_spans", lambda r: r.spans)
     # (100 - 70 + 40 - 35) / 2 steps
-    assert read(reading([], 1, SPANS)) == pytest.approx(17.5)
-    assert read(reading([], 1, [])) is None
+    assert read(reading(SPANS)) == pytest.approx(17.5)
+    assert read(reading([])) is None
 

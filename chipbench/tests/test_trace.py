@@ -70,10 +70,20 @@ def test_every_launch_lands_inside_its_host_step(red):
 def test_kernel_time_is_the_sum_of_its_events(red):
     import kernels
 
-    qmm = [o for o in red.ops if kernels.quant_matmul(o)]
+    # the fixture's Pallas call was recorded before kernels had names: its
+    # instruction is the traced function's, a name in no class of the
+    # dense architecture, and is read by no roofline
+    assert {kernels.kernel_name(o) for o in red.ops} == {"_lambda_", None}
+    assert not any(kernels.quant_matmul(o) or kernels.attention(o)
+                   for o in red.ops)
+    assert kernels.by_name(red.ops, {}) == {
+        ("jit__lambda", "_lambda_"): [None, 3, pytest.approx(43642e-9)]}
+    # a map that names it selects its three launches
+    kmap = {"_lambda_": "matmul"}
+    qmm = [o for o in red.ops if kernels.quant_matmul(o, kmap)]
     assert [o.dur for o in qmm] == [14418.0, 14462.0, 14762.0]
-    assert red.kernel_s(kernels.quant_matmul) == pytest.approx(43642e-9)
-    assert not any(kernels.attention(o) for o in red.ops)
+    assert red.kernel_s(lambda o: kernels.quant_matmul(o, kmap)) == \
+        pytest.approx(43642e-9)
 
 
 def test_gaps_are_named_by_host_spans(red):

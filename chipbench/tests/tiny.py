@@ -14,9 +14,10 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))
 
-import common  # noqa: E402,F401
+import archs  # noqa: E402
+import common  # noqa: E402
 
-PROJ = ("wq", "wk", "wv", "wo", "mlp_wi", "mlp_wg", "mlp_wo")
+PROJ = archs.by_name("dense").PROJ
 PEAKS = {"bf16_flops": 197e12, "int8_ops": 393e12, "hbm_bytes_per_s": 819e9,
          "hbm_bytes": 16e9}
 
@@ -34,11 +35,11 @@ def raw(tied=True, size=SMALL):
                 hidden_act="silu", qk_norm=tied)
 
 
-def policy(layers=2):
+def policy(layers=2, projs=PROJ):
     bits = (2, 3, 4, 5, 6)
     w, a = {}, {}
     for i in range(layers):
-        for j, p in enumerate(PROJ):
+        for j, p in enumerate(projs):
             w[f"L{i:03d}.{p}"] = bits[(i + j) % 5]
             a[f"L{i:03d}.{p}"] = bits[(2 * i + j + 2) % 5]
     return json.dumps({"w_bits": w, "a_bits": a, "meta": {}})
@@ -62,7 +63,10 @@ DEV_LIMIT = 1e-3
 
 
 def run(seconds=3.0, seed=2**33 + 7, tied=True, limit=LIMIT, trace=0,
-        controls=(), size=SMALL, compute="float32"):
+        controls=(), size=SMALL, compute="float32", config=None,
+        policy_text=None):
+    """``config`` and ``policy_text`` stand in for the configuration file
+    and its policy (``raw(tied, size)`` and ``policy()`` by default)."""
     import jax
 
     import run as bench_run
@@ -76,8 +80,9 @@ def run(seconds=3.0, seed=2**33 + 7, tied=True, limit=LIMIT, trace=0,
                for m in bench[kind]] for kind in ("end_to_end", "per_layer")})
     args = argparse.Namespace(workload=cell["name"], seed=seed,
                               seconds=seconds, trace=trace)
-    r = bench_run.Run(args, bench, cell, raw=raw(tied, size),
-                      policy_text=policy(size["num_hidden_layers"]),
+    r = bench_run.Run(args, bench, cell, raw=config or raw(tied, size),
+                      policy_text=policy_text
+                      or policy(size["num_hidden_layers"]),
                       mix=mix(), lim={"gap_max": {"limit": limit},
                                       "dev_ms": {"limit": DEV_LIMIT}},
                       compute=compute)

@@ -1,9 +1,11 @@
-"""Seeded weights for a configuration, made on the device.
+"""Seeded weights, made on the device: the parts that every architecture
+shares.
 
-The benchmark makes its own inputs: the weights are drawn here from
-``--seed`` and handed to the program in its parameter layout. The plain
-reference (``reference.py``) draws each layer again from the same key, so
-it takes nothing that the program made.
+The benchmark makes its own inputs: the weights are drawn from ``--seed``
+and handed to the program in its parameter layout. An architecture module
+(``arch/<arch>.py``) draws its own layers with ``projection`` and builds
+the whole tree with ``lm_params``; the plain reference draws each layer
+again from the same key, so it takes nothing that the program made.
 
 Draws follow the usual statistics init of the repo's models: a projection is
 ``N(0, 1) / sqrt(fan_in)``, its weight step sizes per bit width are
@@ -17,7 +19,6 @@ import jax
 import jax.numpy as jnp
 
 BITS = (2, 3, 4, 5, 6)
-PROJ = ("wq", "wk", "wv", "wo", "mlp_wi", "mlp_wg", "mlp_wo")
 
 
 def root_key(seed: int):
@@ -33,38 +34,17 @@ def keys(seed: int, n_layers: int):
     return ks[0], ks[1], ks[2:]
 
 
-def proj_shapes(raw: dict):
-    d, ff = raw["hidden_size"], raw["intermediate_size"]
-    hd = raw.get("head_dim", d // raw["num_attention_heads"])
-    qd = raw["num_attention_heads"] * hd
-    kvd = raw["num_key_value_heads"] * hd
-    return {"wq": (d, qd), "wk": (d, kvd), "wv": (d, kvd), "wo": (qd, d),
-            "mlp_wi": (d, ff), "mlp_wg": (d, ff), "mlp_wo": (ff, d)}
-
-
 def stat_scale(w, qmax):
     return 2.0 * jnp.mean(jnp.abs(w)) / jnp.sqrt(jnp.float32(qmax))
 
 
-def layer(key, raw: dict):
-    """One decoder layer's float32 weights, in the program's layout."""
-    shapes = proj_shapes(raw)
-    d = raw["hidden_size"]
-    hd = raw.get("head_dim", d // raw["num_attention_heads"])
-    ks = jax.random.split(key, len(PROJ))
-    out = {"norm1": {"scale": jnp.ones((d,), jnp.float32)},
-           "norm2": {"scale": jnp.ones((d,), jnp.float32)}}
-    for k, name in zip(ks, PROJ):
-        fi, fo = shapes[name]
-        w = jax.random.normal(k, (fi, fo), jnp.float32) * fi ** -0.5
-        out[name] = {
-            "w": w,
+def projection(key, fan_in: int, fan_out: int):
+    """One searched projection: its float32 weight and its bank of weight
+    and activation step sizes, one per width of ``BITS``."""
+    w = jax.random.normal(key, (fan_in, fan_out), jnp.float32) * fan_in ** -0.5
+    return {"w": w,
             "s_w": jnp.stack([stat_scale(w, 2 ** (b - 1) - 1) for b in BITS]),
             "s_a": jnp.asarray([0.1 / b for b in BITS], jnp.float32)}
-    if raw["qk_norm"]:
-        out["q_norm"] = jnp.ones((hd,), jnp.float32)
-        out["k_norm"] = jnp.ones((hd,), jnp.float32)
-    return out
 
 
 def embed(key, raw: dict):
@@ -80,9 +60,10 @@ def head(key, raw: dict):
             "s_a8": jnp.asarray(0.1 / 8, jnp.float32)}
 
 
-def model_params(seed: int, raw: dict):
-    """The whole parameter tree in the program's layout (layers stacked
-    on a leading axis), made in one jitted call on the default device."""
+def lm_params(seed: int, raw: dict, layer):
+    """The whole parameter tree in the program's layout, every layer drawn
+    by ``layer(key, raw)`` and stacked on a leading axis, made in one
+    jitted call on the default device."""
     n = raw["num_hidden_layers"]
 
     def build(k_embed, k_head, k_layers):
