@@ -126,7 +126,9 @@ def serve_and_check_routes(policy_path, extra):
     routes = serve.route_counts(eng.metrics)
     kernels = [r for r in ("dispatch.route.pallas-int8",
                            "dispatch.route.pallas-w4",
-                           "dispatch.route.pallas-planes") if routes.get(r)]
+                           "dispatch.route.pallas-planes",
+                           "dispatch.route.pallas-grouped")
+               if routes.get(r)]
     if not kernels:
         fail(f"no Pallas matmul route ran: {routes}")
     if eng.stats.decode_attn_route != "fused" \

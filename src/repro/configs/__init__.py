@@ -79,6 +79,7 @@ def smoke_config(name: str) -> ModelConfig:
             d_ff=64,
             first_dense_layers=cfg.moe.first_dense_layers,
             dense_d_ff=128 if cfg.moe.dense_d_ff else 0,
+            norm_topk=cfg.moe.norm_topk,
         )
         overrides["n_layers"] = 2 + cfg.moe.first_dense_layers
     if cfg.sliding_window:

@@ -23,6 +23,10 @@ class MoEConfig:
     d_ff: int = 0               # per-expert hidden dim
     first_dense_layers: int = 0  # leading layers that stay dense
     dense_d_ff: int = 0         # d_ff used by those dense layers
+    # renormalise the top-k router weights to sum to one (Mixtral); False
+    # keeps the raw softmax probabilities of the chosen experts
+    # (DeepSeekMoE's norm_topk_prob false, routed scaling 1)
+    norm_topk: bool = True
 
 
 @dataclass(frozen=True)

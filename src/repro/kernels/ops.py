@@ -82,6 +82,17 @@ def quant_matmul_planes(x_q, w_p, s_x, s_w, *, bits: int,
                                     interpret=_interpret_default())
 
 
+def quant_matmul_grouped(x_q, w_p, scale, group_sizes, *, layout: str,
+                         bits: int, k: int):
+    """(M,K) int8 rows sorted by expert x a stacked (E, ., N) packed expert
+    weight (``int8``, ``nib4`` or ``planes``) -> (M,N) f32, each row by its
+    own expert's slab and scale. Codes unpack in the kernel's VMEM."""
+    return _qmm.quant_matmul_grouped(
+        x_q, w_p, jnp.asarray(scale, jnp.float32),
+        jnp.asarray(group_sizes, jnp.int32), layout=layout, bits=bits, k=k,
+        interpret=_interpret_default())
+
+
 def quantize_int8(v, s, bits: int = 8):
     """Round v/s to the signed `bits`-wide integer grid, stored as int8."""
     qmax = 2 ** (bits - 1) - 1

@@ -16,6 +16,10 @@ the packed bytes only: ``quant_matmul_w4`` (``nib4``, two codes a byte) and
 ``quant_matmul_planes`` (``planes``, b bit planes of 8 codes a byte, for
 b = 3, 5, 6, 7).
 
+``quant_matmul_grouped`` multiplies rows sorted by expert with a stacked
+``(E, ., N)`` expert weight, each row by its own expert's slab, in the
+``int8``, ``nib4`` or ``planes`` layout, unpacked in VMEM like the two above.
+
 Numerics contract (tested): out == (q_x * s_x) @ (q_w * s_w) exactly in f32
 for shapes where K * 127^2 < 2^31 (int32 accumulation, always true here).
 """
@@ -263,3 +267,147 @@ def quant_matmul(x_q, w_q, s_x, s_w, blocks=DEFAULT_BLOCKS,
         interpret=interpret,
     )(x_q, w_q, s_x.reshape(1, 1), s_w.reshape(1, 1))
     return out[:M, :N]
+
+
+# ---------------------------------------------------------------------------
+# grouped (expert-stacked) matmul
+# ---------------------------------------------------------------------------
+# the unpacked weight block's int32 temporaries stay at or below this many
+# elements (2 MiB each), whatever K is
+_GROUPED_UNPACK = 1 << 19
+
+
+def grouped_rows(m: int, e: int) -> int:
+    """Row tile of the grouped kernel for ``m`` rows over ``e`` experts:
+    the mean rows an expert takes, rounded up to a power of two, within
+    [32, 256] (32 rows is the TPU's one-byte tile)."""
+    mean = -(-m // e)
+    return min(256, max(32, 1 << (mean - 1).bit_length()))
+
+
+def grouped_layout(group_sizes, m: int, bm: int):
+    """Where each of ``m`` expert-sorted rows lands when every expert's rows
+    start on a tile of ``bm`` rows: (destination row (m,), expert of each
+    tile (n_tiles,), tiles used (1,)). Tiles past the used ones repeat the
+    last used tile's expert, so they name no new weight block."""
+    gs = jnp.asarray(group_sizes, jnp.int32)
+    e = gs.shape[0]
+    n_tiles = -(-m // bm) + e
+    tiles = (gs + bm - 1) // bm
+    tile_end = jnp.cumsum(tiles)
+    row_end = jnp.cumsum(gs)
+    r = jnp.arange(m, dtype=jnp.int32)
+    g = jnp.searchsorted(row_end, r, side="right").astype(jnp.int32)
+    g = jnp.minimum(g, e - 1)
+    dest = (tile_end - tiles)[g] * bm + r - (row_end - gs)[g]
+    used = tile_end[-1]
+    t = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32),
+                    jnp.maximum(used - 1, 0))
+    tile_g = jnp.minimum(
+        jnp.searchsorted(tile_end, t, side="right"), e - 1).astype(jnp.int32)
+    return dest, tile_g, used.reshape(1).astype(jnp.int32)
+
+
+def _unpack_block(w, layout: str, bits: int):
+    """A weight block as int8 codes ``(K, bn)``: ``int8`` as stored, ``nib4``
+    bytes of two rows, or ``(bits, K/8, bn)`` planes whose rows come out
+    regrouped by bit position (row ``s * K/8 + g`` is K-row ``8g + s``)."""
+    if layout == "int8":
+        return w
+    if layout == "nib4":
+        wp = w.astype(jnp.int32)
+        k2, bn = wp.shape
+        return jnp.stack([(wp & 0xF) - 8, (wp >> 4) - 8], axis=1).reshape(
+            2 * k2, bn).astype(jnp.int8)
+    _, k8, bn = w.shape
+    s = jax.lax.broadcasted_iota(jnp.int32, (8, k8, bn), 0)
+    u = (w[0].astype(jnp.int32)[None] >> s) & 1
+    for j in range(1, bits):
+        u = u | (((w[j].astype(jnp.int32)[None] >> s) & 1) << j)
+    qmin = -(1 << (bits - 1))                    # codes are offset-binary
+    return (u.reshape(8 * k8, bn) + qmin).astype(jnp.int8)
+
+
+def _qmm_grouped_kernel(tile_g, used, x_ref, w_ref, s_ref, o_ref, *,
+                        layout, bits):
+    i = pl.program_id(1)
+
+    @pl.when(i < used[0])
+    def _tile():
+        w = _unpack_block(w_ref[...], layout, bits)
+        acc = jax.lax.dot_general(
+            x_ref[...], w, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32)
+        o_ref[...] = acc.astype(jnp.float32) * s_ref[tile_g[i]]
+
+
+def quant_matmul_grouped(x_q, w_p, scale, group_sizes, *, layout: str,
+                         bits: int, k: int, interpret: bool = False):
+    """Rows sorted by expert times their experts' packed weights.
+
+    x_q: (M, k) int8 activation codes, expert 0's rows first, then expert
+    1's, ... (``group_sizes`` rows each, summing to M); w_p: the stacked
+    weight, ``(E, k, N)`` int8 codes (``int8``), ``(E, k/2, N)`` nib4 bytes
+    (``nib4``) or ``(E, bits * ceil(k/8), N)`` bit planes (``planes``);
+    scale: (E,) f32, each expert's activation step times its weight step.
+    Returns (M, N) f32: row r is ``(x_q[r] @ codes[g]) * scale[g]`` for its
+    expert g, accumulated in int32.
+
+    Each expert's rows start on a row tile (``grouped_layout``), so a tile
+    is one expert's. The grid runs N tiles outermost and row tiles inside:
+    consecutive tiles of one expert name the same weight block, which is
+    then fetched once, and no tile names an expert without rows. The whole
+    contraction is one block, so a block is one expert's K x bn slab; the
+    expert of each tile and the count of used tiles ride in by scalar
+    prefetch, and tiles past the used ones do nothing.
+    """
+    M, K = x_q.shape
+    E, rows, N = w_p.shape
+    assert K == k, (x_q.shape, k)
+    if layout == "planes":
+        kp = 8 * -(-k // 8)
+        assert rows == bits * (kp // 8), (w_p.shape, bits, k)
+        # activation columns regrouped by bit position, as the planes
+        # unpack orders the weight's rows
+        x_q = jnp.pad(x_q, ((0, 0), (0, kp - k)))
+        x_q = x_q.reshape(M, kp // 8, 8).swapaxes(1, 2).reshape(M, kp)
+        w_p = w_p.reshape(E, bits, kp // 8, N)
+        w_block = (None, bits, kp // 8)
+    else:
+        kp = k
+        assert rows == (k // 2 if layout == "nib4" else k), (w_p.shape, k)
+        w_block = (None, rows)
+    bm = grouped_rows(M, E)
+    bn = _tile(N, max(128, _GROUPED_UNPACK // kp // 128 * 128), 128) or N
+    dest, tile_g, used = grouped_layout(group_sizes, M, bm)
+    n_tiles = tile_g.shape[0]
+    xs = jnp.zeros((n_tiles * bm, kp), jnp.int8).at[dest].set(x_q)
+
+    def x_map(j, i, tg, nu):
+        return (jnp.minimum(i, nu[0] - 1), 0)
+
+    def w_map(j, i, tg, nu):
+        return (tg[i],) + (0,) * (len(w_block) - 1) + (j,)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(N // bn, n_tiles),
+        in_specs=[
+            pl.BlockSpec((bm, kp), x_map),
+            pl.BlockSpec(w_block + (bn,), w_map),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda j, i, tg, nu: (
+            jnp.minimum(i, nu[0] - 1), j)),
+    )
+    out = pl.pallas_call(
+        functools.partial(_qmm_grouped_kernel, layout=layout, bits=bits),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_tiles * bm, N), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 * 2**20),
+        name="quant_matmul_grouped",
+        interpret=interpret,
+    )(tile_g, used, xs, w_p, jnp.asarray(scale, jnp.float32).reshape(E))
+    return out[dest]

@@ -527,14 +527,15 @@ class DecodeEngine:
             return adapter.decode(p, tok, pos, state)
 
         def insert(full, row, slot):
-            def one(path, f, r):
-                seg = str(getattr(path[0], "key", path[0]))
+            def put(seg):
                 axis = 1 if seg == "body" else 0
-                return jax.lax.dynamic_update_slice_in_dim(
+                return lambda f, r: jax.lax.dynamic_update_slice_in_dim(
                     f, r.astype(f.dtype), slot, axis=axis
                 )
 
-            return jax.tree_util.tree_map_with_path(one, full, row)
+            # what the prefill row lacks (the expert-load counter) stays
+            return {**full, **{seg: jax.tree.map(put(seg), full[seg], r)
+                               for seg, r in row.items()}}
 
         def evict(state, slot):
             def one(c):
@@ -1318,6 +1319,32 @@ class DecodeEngine:
                 self._kv_drift.publish(
                     self.metrics, self._kv_drift.update(self.state)
                 )
+
+    def expert_load(self) -> Optional[Dict[str, Any]]:
+        """The decode state's expert-load counter, brought to the host only
+        when asked (never per launch), under an ``engine.moe`` span: per MoE
+        layer the tokens each expert took (``tokens``, (n_moe, E)) and the
+        distinct experts it read (``hits``), summed over every decode launch
+        since the state was made, and the launches (``launches``).
+        ``experts_hit`` is the distinct experts a MoE layer read per launch,
+        the mean over layers and launches; it is published in ``metrics``
+        as ``engine.moe.experts_hit``. None where the state keeps no
+        counter (no MoE layer, or an adapter without one)."""
+        moe = self.state.get("moe") if isinstance(self.state, dict) else None
+        if moe is None:
+            return None
+        with obs_trace.span("engine.moe", self.trace):
+            host = jax.device_get(moe)
+            n = int(host["launches"])
+            hit = float(np.mean(host["hits"])) / n if n else None
+            if hit is not None:
+                self.metrics.gauge(
+                    "engine.moe.experts_hit",
+                    help="distinct experts a MoE layer read per decode launch",
+                ).set(hit)
+        return {"tokens": np.asarray(host["tokens"]),
+                "hits": np.asarray(host["hits"]), "launches": n,
+                "experts_hit": hit}
 
     # -- self-speculative decode --------------------------------------------
     def _spec_draft_body(self, steps: int, p, tok, pos, state):

@@ -607,8 +607,10 @@ def apply_layer(kind: str, x: Array, p, bits, cfg: ModelConfig,
         x, new_st = _attn_sublayer(x, p, bits, cfg, ctx, axes, kind, mode,
                                    state, pos, img_x, prefill_cap, slot)
         h = apply_norm(x, p["norm2"], cfg.norm_type, cfg.norm_eps)
+        # serving routes dropless; the capacity dispatch is training's
         out, aux = moe_mod.moe_ffn(h, p["moe"], cfg.moe, _bget(bits, "moe"),
-                                   ctx, cfg.act, cfg.mlp_gated, axes)
+                                   ctx, cfg.act, cfg.mlp_gated, axes,
+                                   dropless=mode != "train")
         return x + out, new_st, aux
     if kind == "rwkv":
         st = state or (None, None, None)

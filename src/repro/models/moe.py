@@ -1,21 +1,33 @@
-"""Mixture-of-Experts FFN with capacity-bounded gather dispatch.
+"""Mixture-of-Experts FFN: capacity-bounded dispatch for training, dropless
+dispatch for serving.
 
-TPU-idiomatic dispatch (DESIGN.md §3): token-choice top-k routing with
-*per-expert top-C token selection* for capacity enforcement — no sort, no
-giant one-hot dispatch tensors. Each expert gathers its C highest-gate
-tokens into an (E, C, D) buffer (E shards over the model axis for
-fine-grained MoE, C over the data axes), runs dense 128-aligned matmuls,
-and scatter-adds results back. Overflow tokens are dropped exactly like
-capacity-factor dispatch in Mesh-TF/MaxText.
+Training (``moe_ffn``, TPU-idiomatic, DESIGN.md §3): token-choice top-k
+routing with *per-expert top-C token selection* for capacity enforcement —
+no sort, no giant one-hot dispatch tensors. Each expert gathers its C
+highest-gate tokens into an (E, C, D) buffer (E shards over the model axis
+for fine-grained MoE, C over the data axes), runs dense 128-aligned
+matmuls, and scatter-adds results back. Overflow tokens are dropped exactly
+like capacity-factor dispatch in Mesh-TF/MaxText. The capacity applies to
+training only.
+
+Serving (``moe_ffn(..., dropless=True)``: prefill, decode, verify and
+append) drops no token: the (token, expert) pairs are sorted by expert, each
+expert's rows run through its slab of the stacked weights by a grouped
+matmul (``quant_layers.qgrouped``; the ``pallas-grouped`` kernel route on a
+TPU), and a weighted scatter-add combines them. ``load_scope`` collects the
+tokens each expert took, per MoE layer, for the serving session's
+expert-load counter.
 
 Routing (router logits, softmax, top-k) stays in f32 and is NOT quantized
-(DESIGN.md §5 — precision-critical and tiny). Expert matmuls are QLayers:
-one (E, ...) stacked tensor per projection with a shared per-tensor
-indicator bank, activated-MAC BitOps accounting.
+(DESIGN.md §5 — precision-critical and tiny); ``MoEConfig.norm_topk``
+says whether the top-k weights are renormalised. Expert matmuls are
+QLayers: one (E, ...) stacked tensor per projection with a shared
+per-tensor indicator bank, activated-MAC BitOps accounting.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional
+import contextlib
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +35,8 @@ import jax.numpy as jnp
 from repro.configs.base import MoEConfig
 from repro.dist.axes import MeshAxes
 from repro.models.common import activation, dense_init
-from repro.models.quant_layers import QuantContext, qdense_init, qeinsum
+from repro.models.quant_layers import (QuantContext, qdense_init, qeinsum,
+                                      qgrouped)
 
 Array = jax.Array
 
@@ -31,6 +44,36 @@ Array = jax.Array
 # Perf switch (EXPERIMENTS.md §Perf): True = shard-local routing; False =
 # the paper-faithful-baseline global top-C dispatch (G=1).
 GROUP_LOCAL_DISPATCH = True
+
+
+_LOADS: List[Optional[list]] = [None]
+
+
+@contextlib.contextmanager
+def load_scope():
+    """Collect, for one traced forward, each dropless MoE layer's tokens per
+    expert ((E,) int32, in layer order) into the yielded list."""
+    loads: list = []
+    _LOADS.append(loads)
+    try:
+        yield loads
+    finally:
+        _LOADS.pop()
+
+
+def route(xf: Array, w_router: Array, moe: MoEConfig):
+    """Router in f32 (at 'highest' precision, so a TPU does not round its
+    operands to bfloat16): (probs (..., E), top-k weights, top-k experts).
+    The weights are the softmax probabilities of the chosen experts,
+    renormalised to sum to one where ``moe.norm_topk``."""
+    logits = jnp.einsum("...d,de->...e", xf.astype(jnp.float32),
+                        w_router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_w, top_i = jax.lax.top_k(probs, moe.top_k)
+    if moe.norm_topk:
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    return probs, top_w, top_i
 
 
 def round_up(x: int, m: int) -> int:
@@ -64,10 +107,66 @@ def moe_init(rng, d_model: int, moe: MoEConfig, bits, gated: bool,
     return p
 
 
+def _shared(xf: Array, p, moe: MoEConfig, b, ctx: QuantContext, act_fn,
+            gated: bool) -> Array:
+    """The always-on shared experts, one gated MLP of width n_shared * d_ff."""
+    hs = qeinsum("td,df->tf", xf, p["shared_wi"], b("shared_wi"), ctx)
+    if gated:
+        gs = qeinsum("td,df->tf", xf, p["shared_wg"], b("shared_wg"), ctx)
+        hs = act_fn(gs) * hs
+    else:
+        hs = act_fn(hs)
+    return qeinsum("tf,fd->td", hs, p["shared_wo"], b("shared_wo"), ctx)
+
+
+def _moe_dropless(x: Array, p, moe: MoEConfig, bits: Optional[Dict],
+                  ctx: QuantContext, act: str, gated: bool):
+    """Serving dispatch: every (token, expert) pair the router picks is
+    computed. Returns (out (B, S, D), zero aux loss)."""
+    B, S, D = x.shape
+    T = B * S
+    E, K = moe.n_experts, moe.top_k
+    act_fn = activation(act)
+    xf = x.reshape(T, D)
+
+    def b(name):
+        return None if bits is None else bits[name]
+
+    with jax.named_scope("moe_route"):
+        _, top_w, top_i = route(xf, p["router"]["w"], moe)
+    with jax.named_scope("moe_dispatch"):
+        flat = top_i.reshape(T * K)
+        order = jnp.argsort(flat, stable=True)      # pairs sorted by expert
+        row_e = flat[order]
+        tok = order // K
+        sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+        xs = jnp.take(xf, tok, axis=0)              # (T*K, D)
+    with jax.named_scope("moe_experts"):
+        h = qgrouped(xs, p["wi"], b("wi"), sizes, row_e, ctx)
+        if gated:
+            g = qgrouped(xs, p["wg"], b("wg"), sizes, row_e, ctx)
+            h = act_fn(g) * h
+        else:
+            h = act_fn(h)
+        y = qgrouped(h, p["wo"], b("wo"), sizes, row_e, ctx)
+    with jax.named_scope("moe_combine"):
+        w_rows = top_w.reshape(T * K)[order].astype(y.dtype)
+        out = jnp.zeros((T, D), y.dtype).at[tok].add(y * w_rows[:, None])
+    if moe.n_shared:
+        with jax.named_scope("shared_expert"):
+            out = out + _shared(xf, p, moe, b, ctx, act_fn, gated)
+    if _LOADS[-1] is not None:
+        _LOADS[-1].append(sizes)
+    return out.reshape(B, S, D), jnp.zeros((), jnp.float32)
+
+
 def moe_ffn(x: Array, p, moe: MoEConfig, bits: Optional[Dict], ctx: QuantContext,
             act: str, gated: bool, axes: MeshAxes,
-            capacity_factor: float = 1.25):
+            capacity_factor: float = 1.25, dropless: bool = False):
     """x: (B, S, D) -> (out (B, S, D), aux_loss scalar f32).
+
+    ``dropless`` (serving) computes every routed pair (module docstring);
+    otherwise the capacity dispatch below runs (training).
 
     Dispatch is GROUP-LOCAL: tokens are split into `dp_size` groups aligned
     with the data shards and each group routes to per-group expert capacity
@@ -76,6 +175,8 @@ def moe_ffn(x: Array, p, moe: MoEConfig, bits: Optional[Dict], ctx: QuantContext
     single largest collective in the roofline table (EXPERIMENTS.md §Perf).
     Per-shard capacity is the standard Mesh-TF/MaxText semantics.
     """
+    if dropless:
+        return _moe_dropless(x, p, moe, bits, ctx, act, gated)
     B, S, D = x.shape
     T = B * S
     E, K = moe.n_experts, moe.top_k
@@ -96,11 +197,7 @@ def moe_ffn(x: Array, p, moe: MoEConfig, bits: Optional[Dict], ctx: QuantContext
     xf = axes.shard(xf, gdim, tdim, None)
 
     # ---- routing (f32, unquantized), per group -----------------------------
-    logits = jnp.einsum("gtd,de->gte", xf.astype(jnp.float32),
-                        p["router"]["w"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                     # (G, Tg, E)
-    top_w, top_i = jax.lax.top_k(probs, K)                      # (G, Tg, K)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    probs, top_w, top_i = route(xf, p["router"]["w"], moe)    # (G, Tg, .)
     gates = jnp.sum(jax.nn.one_hot(top_i, E, dtype=jnp.float32)
                     * top_w[..., None], axis=2)                 # (G, Tg, E)
 
@@ -151,13 +248,7 @@ def moe_ffn(x: Array, p, moe: MoEConfig, bits: Optional[Dict], ctx: QuantContext
 
     # ---- shared experts (always-on) ---------------------------------------
     if moe.n_shared:
-        hs = qeinsum("td,df->tf", xf, p["shared_wi"], b("shared_wi"), ctx)
-        if gated:
-            gs = qeinsum("td,df->tf", xf, p["shared_wg"], b("shared_wg"), ctx)
-            hs = act_fn(gs) * hs
-        else:
-            hs = act_fn(hs)
-        out = out + qeinsum("tf,fd->td", hs, p["shared_wo"], b("shared_wo"), ctx)
+        out = out + _shared(xf, p, moe, b, ctx, act_fn, gated)
 
     return out.reshape(B, S, D), aux.astype(jnp.float32)
 

@@ -141,6 +141,31 @@ def qeinsum(eqn: str, x: Array, p, bits, ctx: QuantContext) -> Array:
     return row_einsum(eqn, xq, wq)
 
 
+def qgrouped(x: Array, p, bits, group_sizes: Array, row_group: Array,
+             ctx: QuantContext) -> Array:
+    """Quantized grouped matmul of an expert stack: row m of ``x`` (M, K),
+    rows sorted by expert (``group_sizes`` (E,) rows each), times the
+    (K, N) slab of its expert ``row_group[m]`` of the stacked (E, K, N)
+    weight. Each row's activation quantizes at its expert's bank step and
+    each slab at its expert's, so the rows meet exactly the fake-quant
+    operands of a per-expert einsum. A packed weight routes through
+    ``runtime.dispatch.packed_grouped``."""
+    if not isinstance(p, dict):
+        from repro.runtime.dispatch import packed_grouped
+        return packed_grouped(x, p, group_sizes, row_group, ctx)
+    w_idx = None if bits is None else bits["w"]
+    a_idx = None if bits is None else bits["a"]
+    if ctx.enabled and ctx.quantize_acts and a_idx is not None:
+        t = ctx.tables_a
+        s = jnp.take(p["s_a"], a_idx, axis=-1)[row_group][:, None]
+        g = lsq_grad_scale_factor(x.size, jnp.take(t.qmax, a_idx))
+        x = fake_quant(x, s, jnp.take(t.qmin, a_idx).astype(x.dtype),
+                       jnp.take(t.qmax, a_idx).astype(x.dtype),
+                       grad_scale_factor=g)
+    wq = _maybe_quant_w(p, w_idx, ctx)
+    return jax.lax.ragged_dot(x.astype(ctx.compute_dtype), wq, group_sizes)
+
+
 def row_einsum(eqn: str, x: Array, w: Array) -> Array:
     """``jnp.einsum`` for a projection whose output rows must not depend on
     how many rows ride along.

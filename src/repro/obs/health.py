@@ -1,10 +1,13 @@
 """Quantization health: is the packed model actually healthy at serving?
 
-Pure host-side numpy over already-materialized artifacts — the packed
+Read-only passes over already-materialized artifacts — the packed
 weights, their trained scales, and the int8 KV cache's write-time
-scales. Nothing here enters the jitted graph, so greedy-token identity
+scales. Nothing here enters the serving graph, so greedy-token identity
 is untouched by construction; the engine simply *reads* what packing
-and the KV write path already produced.
+and the KV write path already produced. A site's saturation is reduced
+where its weight lives (one small jitted reduction on the device) and
+only two scalars come back: pulling an expert stack of 185M weights to
+the host took seconds a site.
 
 Signals (the ISSUE's signal plane):
 
@@ -31,8 +34,11 @@ Signals (the ISSUE's signal plane):
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.quantizer import bit_range
@@ -48,27 +54,34 @@ UTIL_BUCKETS: Tuple[float, ...] = (
     0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 1.0, 1.1, 1.5, 2.0)
 
 
+@functools.partial(jax.jit, static_argnames=("w_bits",))
+def _saturation(w, s, w_bits: int):
+    """(values whose grid image rounds outside the grid, max |w / s|)."""
+    qmin, qmax = bit_range(w_bits, True)
+    x = w / s
+    sat = jnp.count_nonzero((x > qmax + 0.5) | (x < qmin - 0.5))
+    return sat, jnp.max(jnp.abs(x))
+
+
 def site_health(w, w_bits: int, scale) -> Dict[str, float]:
-    """Saturation + utilization for one packed site, from weight + scale.
+    """Saturation + utilization for one packed site, from weight + scale,
+    in float32 where ``w`` lives.
 
     ``scale`` may be scalar or per-channel over the last dim (the same
     broadcast ``packing.quantize_to_grid`` applies).
     """
-    w = np.asarray(w, np.float64)
-    s = np.maximum(np.asarray(scale, np.float64), SCALE_EPS)
+    w = jnp.asarray(w, jnp.float32)
+    s = jnp.maximum(jnp.asarray(scale, jnp.float32), SCALE_EPS)
     if s.ndim == 1 and w.ndim >= 1 and s.shape[0] == w.shape[-1]:
         s = s.reshape((1,) * (w.ndim - 1) + (-1,))
-    qmin, qmax = bit_range(int(w_bits), True)
-    x = w / s
-    saturated = np.logical_or(x > qmax + 0.5, x < qmin - 0.5)
+    _, qmax = bit_range(int(w_bits), True)
     n = int(w.size)
-    sat_rate = float(np.count_nonzero(saturated)) / n if n else 0.0
-    util = float(np.max(np.abs(x))) / qmax if n else 0.0
+    sat, top = (0, 0.0) if not n else _saturation(w, s, int(w_bits))
     return {
-        "saturation_rate": sat_rate,
-        "scale_utilization": util,
+        "saturation_rate": int(sat) / n if n else 0.0,
+        "scale_utilization": float(top) / qmax if n else 0.0,
         "n_values": n,
-        "n_saturated": int(np.count_nonzero(saturated)),
+        "n_saturated": int(sat),
         "w_bits": int(w_bits),
     }
 

@@ -16,12 +16,20 @@ every call site resolves — at trace time — which execution route serves it:
   matmul runs int8 x int8 -> int32 on the MXU
   (``kernels.quant_matmul.quant_matmul``). It serves 8-bit and 2-bit
   weights, and packed layouts the kernels above cannot tile.
+* ``pallas-grouped`` — a stacked ``(E, K, N)`` expert weight in the grouped
+  form (``GROUPED_EQN``: rows sorted by expert, each row times its own
+  expert's slab, ``packed_grouped``) feeds
+  ``kernels.quant_matmul.quant_matmul_grouped``: ``planes`` and ``nib4``
+  bytes unpack in its VMEM, 8-bit codes go in as they are, and 2-bit
+  ``quad2`` codes unpack to int8 in XLA first. Each row's activation
+  quantizes with its expert's step size; the epilogue scales by its
+  expert's.
 * ``dequant-fp``  — exact fallback for everything the kernels can't tile
-  (stacked MoE expert einsums, row-parallel ``(N,K)`` weight orientation,
-  per-channel scales, odd contraction dims): dequantize the codes and run
-  the same fp einsum as the fake-quant training graph. This route is
-  *bit-exact* with that graph — it is the default off-TPU and what the
-  serve smoke's token-identity gate runs on.
+  (row-parallel ``(N,K)`` weight orientation, per-channel scales, odd
+  contraction dims, any route under a mesh): dequantize the codes and run
+  the same fp einsum (for expert stacks, the same ``ragged_dot``) as the
+  fake-quant graph. This route is *bit-exact* with that graph — it is the
+  default off-TPU and what the serve smoke's token-identity gate runs on.
 
 The Pallas routes are int32-exact per the kernel contract but not bitwise
 equal to an fp einsum, so ``resolve`` only picks them on a TPU backend;
@@ -121,7 +129,8 @@ class RouteTable:
 
 
 ROUTES = RouteTable({
-    "matmul": ("dequant-fp", "pallas-int8", "pallas-w4", "pallas-planes"),
+    "matmul": ("dequant-fp", "pallas-int8", "pallas-w4", "pallas-planes",
+               "pallas-grouped"),
     "decode_attn": ("fused", "fused-interpret", "dequant-fp"),
     "kv_layout": ("ring", "paged"),
     # the flash-attention forward of prefill and training: the Pallas
@@ -352,15 +361,23 @@ def act_fake_quant(x: Array, pl: PackedLinear, ctx) -> Array:
     return out
 
 
-def act_codes(x: Array, pl: PackedLinear, ctx):
-    """Integer activation codes + scale for the int8 kernel routes
-    (per-tensor scale only — kernel-eligible layers are never stacked)."""
+def act_codes(x: Array, pl: PackedLinear, ctx, row_group=None):
+    """Integer activation codes + scale for the int8 kernel routes. A 2-D
+    weight has one per-tensor step size. An expert stack has one a expert
+    (``s_a`` of shape ``(E,)``): each row of ``x`` then quantizes with the
+    step of its expert ``row_group``, and the returned scale is the ``(E,)``
+    floored steps."""
     key, hit = _reuse_lookup(x, pl, "codes")
     if hit is not None:
         return hit
     qmin, qmax = pl.a_range
-    s = jnp.maximum(pl.s_a.reshape(()), 1e-9)
-    q = jnp.clip(jnp.round(x.astype(jnp.float32) / s), qmin, qmax)
+    if row_group is None:
+        s = jnp.maximum(pl.s_a.reshape(()), 1e-9)
+        rows = s
+    else:
+        s = jnp.maximum(jnp.broadcast_to(pl.s_a, pl.shape[:1]), 1e-9)
+        rows = s[row_group][:, None]
+    q = jnp.clip(jnp.round(x.astype(jnp.float32) / rows), qmin, qmax)
     out = (q.astype(jnp.int8), s)
     _reuse_store(key, x, out)
     return out
@@ -369,6 +386,12 @@ def act_codes(x: Array, pl: PackedLinear, ctx):
 # ---------------------------------------------------------------------------
 # eqn analysis
 # ---------------------------------------------------------------------------
+# The grouped form of an expert stack: x (M, K) holds rows sorted by expert,
+# row m takes the (K, N) slab of its expert g; not an einsum, a marker
+# ``packed_grouped`` resolves under.
+GROUPED_EQN = "mk,gkn->mn"
+
+
 def _kernel_form(eqn: str) -> bool:
     """True for ``...k,kn->...n`` einsums — weight is (K, N) with the
     contraction on the activation's last dim (the only orientation the
@@ -468,6 +491,58 @@ def _impl_pallas_planes(eqn: str, x: Array, pl: PackedLinear, ctx) -> Array:
                                                 bits=pl.w_bits))
 
 
+def _grouped_act_fake_quant(x: Array, pl: PackedLinear, row_group, ctx):
+    """``act_fake_quant`` for rows sorted by expert: each row at its own
+    expert's step size (the op chain of ``quant_layers.qgrouped``)."""
+    if not (ctx.enabled and ctx.quantize_acts):
+        return x
+    key, hit = _reuse_lookup(x, pl, "fake")
+    if hit is not None:
+        return hit
+    qmin, qmax = pl.a_range
+    s = jnp.broadcast_to(pl.s_a, pl.shape[:1])[row_group][:, None]
+    g = lsq_grad_scale_factor(x.size, qmax)
+    out = fake_quant(x, s, qmin, qmax, grad_scale_factor=g)
+    _reuse_store(key, x, out)
+    return out
+
+
+def _impl_grouped_dequant_fp(x, pl, group_sizes, row_group, ctx) -> Array:
+    with jax.named_scope("act_codes"):
+        xq = _grouped_act_fake_quant(x, pl, row_group, ctx).astype(
+            ctx.compute_dtype)
+    with jax.named_scope("dequant"):
+        w = pl.dequant(ctx.compute_dtype)
+    return jax.lax.ragged_dot(xq, w, group_sizes)
+
+
+def _impl_pallas_grouped(x, pl, group_sizes, row_group, ctx) -> Array:
+    from repro.kernels import ops
+    with jax.named_scope("act_codes"):
+        xq, s_x = act_codes(x, pl, ctx, row_group)
+    codes, layout = pl.codes, pl.layout
+    if layout == "quad2":
+        codes, layout = pl.unpack(), "int8"
+    with jax.named_scope("kernel"):
+        out = ops.quant_matmul_grouped(
+            xq, codes, s_x * pl.scale.reshape(-1), group_sizes,
+            layout=layout, bits=pl.w_bits, k=pl.shape[-2])
+    return out.astype(ctx.compute_dtype)
+
+
+def packed_grouped(x: Array, pl: PackedLinear, group_sizes, row_group, ctx,
+                   impl: Optional[str] = None) -> Array:
+    """The grouped matmul of an expert stack (``GROUPED_EQN``): ``x`` (M, K)
+    holds rows sorted by expert, ``group_sizes`` (E,) rows each, and
+    ``row_group`` (M,) names each row's expert; returns (M, N). The serving
+    counterpart of ``quant_layers.qgrouped``."""
+    impl = impl or resolve(GROUPED_EQN, pl)
+    _count_route("route", impl)
+    fn = (_impl_pallas_grouped if impl == "pallas-grouped"
+          else _impl_grouped_dequant_fp)
+    return fn(x, pl, group_sizes, row_group, ctx)
+
+
 REGISTRY: Dict[str, Callable] = {
     "dequant-fp": _impl_dequant_fp,
     "pallas-int8": _impl_pallas_int8,
@@ -481,12 +556,19 @@ REGISTRY: Dict[str, Callable] = {
 # ---------------------------------------------------------------------------
 def kernel_eligible(eqn: str, pl: PackedLinear) -> Optional[str]:
     """The Pallas route this (eqn, layer) pair could take, else None."""
-    if len(pl.shape) != 2 or not _kernel_form(eqn):
-        return None
     if pl.per_channel:  # kernel epilogue takes a per-tensor scale (for now)
         return None
     if not pl.a_signed and pl.a_bits > 7:
         return None  # unsigned 8-bit grid (qmax 255) overflows int8 codes
+    if eqn == GROUPED_EQN:
+        # one step size an expert, in the (E, 1, 1) form; the kernel reads
+        # the plain byte layout, so no per-shard packing
+        grouped = (len(pl.shape) == 3 and pl.scale.shape == pl.shape[:1]
+                   + (1, 1) and not pl.sharded_layout()
+                   and (pl.layout != "nib4" or pl.shape[-2] % 2 == 0))
+        return "pallas-grouped" if grouped else None
+    if len(pl.shape) != 2 or not _kernel_form(eqn):
+        return None
     if (pl.layout == "nib4" and pl.shape[-2] % 2 == 0
             and not pl.sharded_layout()):
         # the w4 kernel consumes the PLAIN nib4 byte stream; a per-shard
@@ -506,7 +588,12 @@ def resolve(eqn: str, pl: PackedLinear, backend: Optional[str] = None) -> str:
     """Pick the execution route for one packed matmul (see module doc)."""
     forced = ROUTES.forced("matmul")
     if forced is not None:
-        return forced
+        # a forced kernel route puts an expert stack on its grouped kernel;
+        # a forced grouped route leaves 2-D weights to resolve as usual
+        if eqn == GROUPED_EQN and forced != "dequant-fp":
+            return "pallas-grouped"
+        if forced != "pallas-grouped":
+            return forced
     backend = backend or jax.default_backend()
     if backend != "tpu" or _partitioned():
         return "dequant-fp"

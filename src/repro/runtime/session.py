@@ -62,6 +62,7 @@ from repro.core.quantizer import (
 )
 from repro.dist.axes import NO_AXES, MeshAxes
 from repro.models import lm
+from repro.models import moe as moe_mod
 from repro.models.quant_layers import QuantContext
 from repro.obs import health as obs_health
 from repro.runtime import packing
@@ -155,14 +156,16 @@ class QuantizedSession:
         self.params = self._build_params(params)
 
     # -- construction -------------------------------------------------------
-    def _site_params(self, params, site) -> Dict[str, Any]:
+    def _site_params(self, params, site):
+        """(a private copy of the site's param dicts, the slice that takes
+        a leaf to this site). A scanned body's leaves keep their stack axis
+        until ``take`` slices them: packing slices one projection at a
+        time, so a layer's float weights are never copied whole."""
         seg, idx = site.segment.split(".")
-        sub = params[seg][idx]
+        sub = jax.tree.map(lambda a: a, params[seg][idx])
         if seg == "body":
-            sub = jax.tree.map(lambda a: a[site.unit], sub)
-        else:
-            sub = jax.tree.map(lambda a: a, sub)   # private copy of the dicts
-        return sub
+            return sub, lambda a: a[site.unit]
+        return sub, lambda a: a
 
     def _build_params(self, params) -> Dict[str, Any]:
         from repro.dist import sharding
@@ -182,11 +185,11 @@ class QuantizedSession:
         self._shard_plan: Dict[str, int] = {}
         for site in self.sites:
             key = _site_key(site.gidx)
-            sp = self._site_params(params, site)
+            sp, take = self._site_params(params, site)
             bits_d: Dict[str, Any] = {}
             packed_paths: List[Tuple[str, ...]] = []
             for q in by_site[(site.segment, site.unit)]:
-                leaf = _get_path(sp, q.path)
+                leaf = jax.tree.map(take, _get_path(sp, q.path))
                 w_idx = self._lut[self.policy.w_bits[q.name]]
                 a_idx = self._lut[self.policy.a_bits[q.name]]
                 if self.mode == "packed":
@@ -217,6 +220,10 @@ class QuantizedSession:
                     lm._nest(d, q.path, {"w": w_idx, "a": a_idx})
                     # merged below via bits_d
                     bits_d = _merge(bits_d, d)
+                del leaf   # one projection's float slice at a time
+            sp = jax.tree.map(
+                lambda a: a if packing.is_packed(a) else take(a), sp,
+                is_leaf=packing.is_packed)
             _tag_act_groups(sp, packed_paths, key)
             sites_p[key] = sp
             self._site_bits[key] = bits_d if self.mode == "reference" else None
@@ -281,9 +288,13 @@ class QuantizedSession:
                  slot=None):
         from repro.runtime import dispatch
 
-        new_states = {"sites": {}}
+        # what the state carries beside the sites (the expert-load counter)
+        # passes through every mode; a decode launch adds to it
+        new_states = {k: v for k, v in (states or {}).items() if k != "sites"}
+        new_states["sites"] = {}
         with dispatch.axes_scope(self.axes), \
                 dispatch.metrics_scope(self.metrics), \
+                moe_mod.load_scope() as loads, \
                 dispatch.act_reuse_scope() as scope:
             for site in self.sites:
                 key = _site_key(site.gidx)
@@ -297,6 +308,8 @@ class QuantizedSession:
                         self.compute_axes, mode=mode, state=st, pos=pos,
                         img_x=img_x, prefill_cap=prefill_cap, slot=slot)
                 new_states["sites"][key] = st
+        if mode == "decode" and "moe" in new_states and loads:
+            new_states["moe"] = count_expert_load(new_states["moe"], loads)
         # trace-time count: quantize ops elided from this compiled graph
         self.act_quant_reused += scope["hits"]
         if self.metrics is not None and scope["hits"]:
@@ -349,12 +362,22 @@ class QuantizedSession:
         return logits[:, 0], new_states
 
     def init_state(self, batch, capacity, dtype, per_slot=True, layout=None):
+        """Every site's decode state, and for a model with MoE layers the
+        expert-load counter (``count_expert_load``) under ``"moe"``."""
         kv = "int8" if self.ctx.kv_quant == "int8" else "none"
-        return {"sites": {
+        state = {"sites": {
             _site_key(s.gidx): lm.init_site_state(
                 self.cfg, s.kind, batch, capacity, dtype=dtype,
                 per_slot=per_slot, kv_quant=kv, layout=layout)
             for s in self.sites}}
+        n_moe = sum(s.kind == "moe" for s in self.sites)
+        if n_moe:
+            state["moe"] = {
+                "tokens": jnp.zeros((n_moe, self.cfg.moe.n_experts),
+                                    jnp.int32),
+                "hits": jnp.zeros((n_moe,), jnp.int32),
+                "launches": jnp.zeros((), jnp.int32)}
+        return state
 
     def state_per_slot(self, row):
         return lm.decode_state_per_slot(row)
@@ -382,6 +405,20 @@ class QuantizedSession:
             validate=lambda p: p.validate(lm.enumerate_qlayers(cfg),
                                           bits=cfg.bits))
         return cls(cfg, params, policy, ctx, axes, **kwargs)
+
+
+def count_expert_load(counter, loads):
+    """Add one decode launch to the expert-load counter: ``loads`` holds
+    each MoE layer's tokens per expert ((E,) each, in layer order). The
+    counter keeps, per MoE layer, the tokens each expert took (``tokens``,
+    (n_moe, E)) and the distinct experts it read (``hits``, (n_moe,)), both
+    summed over launches, and the launches (``launches``). It stays on the
+    device with the rest of the decode state."""
+    sizes = jnp.stack(loads)
+    return {"tokens": counter["tokens"] + sizes,
+            "hits": counter["hits"] + jnp.sum(sizes > 0, axis=-1,
+                                              dtype=jnp.int32),
+            "launches": counter["launches"] + 1}
 
 
 def draft_policy(policy: MPQPolicy, qlayers, bits,

@@ -1,7 +1,9 @@
 """The serving and prefill Pallas kernels compile for a TPU v5e at Qwen3-0.6B
 shapes (d_model 1024, d_ff 3072, 16 query / 8 KV heads of 128, 8 decode
 slots over a 2080-token cache, 2048-token prefill; the bit-plane matmul at
-the searched policy's 3-bit ``mlp_wo``, 5-bit ``wq`` and 6-bit ``wk``).
+the searched policy's 3-bit ``mlp_wo``, 5-bit ``wq`` and 6-bit ``wk``), and
+the grouped expert matmul at DeepSeekMoE-16B's 64 experts of 2048 -> 1408
+and 1408 -> 2048, at 3, 4 and 5 bits.
 
 Interpret mode runs the kernel bodies but not the TPU compiler, which also
 refuses blocks that do not tile. These tests compile for a described chip
@@ -26,6 +28,8 @@ from repro.kernels import quant_matmul as qmm
 
 SLOTS, D, FF, H, KV, HD = 8, 1024, 3072, 16, 8, 128
 CACHE, PAGE, PROMPT, VERIFY = 2080, 128, 2048, 5
+# DeepSeekMoE-16B's routed experts: 64 of 2048 -> 1408 -> 2048, 64 rows
+EXPERTS, MOE_D, MOE_FF, MOE_ROWS = 64, 2048, 1408, 64
 
 
 @pytest.fixture(scope="module")
@@ -82,6 +86,14 @@ def _cases(spec):
             [spec((SLOTS, k), i8), spec((bits * k // 8, n), u8), scalar,
              scalar])
            for bits, k, n in ((3, FF, D), (5, D, H * HD), (6, D, KV * HD))},
+        **{f"quant_matmul_grouped.w{bits}.{k}x{n}": (
+            functools.partial(qmm.quant_matmul_grouped, bits=bits, k=k,
+                              layout="nib4" if bits == 4 else "planes"),
+            [spec((MOE_ROWS, k), i8),
+             spec((EXPERTS, k // 2 if bits == 4 else bits * k // 8, n), u8),
+             spec((EXPERTS,), f32), spec((EXPERTS,), i32)])
+           for bits in (3, 4, 5) for k, n in ((MOE_D, MOE_FF),
+                                              (MOE_FF, MOE_D))},
         "decode_attn_quant": (
             qa.decode_attn_quant,
             [spec((SLOTS, 1, H, HD), f32)] + ring_kv + [spec((SLOTS,), i32)]),
@@ -103,7 +115,9 @@ def _cases(spec):
 KERNELS = [
     "quant_matmul", "quant_matmul_w4", "quant_matmul_planes.w3",
     "quant_matmul_planes.w5", "quant_matmul_planes.w6", "decode_attn_quant",
-    "decode_attn_quant_paged", "verify_attn_quant", "flash_fwd_pallas"]
+    "decode_attn_quant_paged", "verify_attn_quant", "flash_fwd_pallas"] + [
+    f"quant_matmul_grouped.w{bits}.{k}x{n}" for bits in (3, 4, 5)
+    for k, n in ((MOE_D, MOE_FF), (MOE_FF, MOE_D))]
 
 
 def _compile(one_chip, kernel):
